@@ -10,13 +10,13 @@ driver over the replicated strategy — data-parallel workers (simulated
 in-process on CPU; mesh shards on real hardware), CGC aggregation over
 per-worker gradients, AdamW, complete (values, opt_state, step)
 checkpoints, deterministic synthetic data. ``--byz K`` makes K workers
-Byzantine to demonstrate the filter on a real model. With a single host
-device the "workers" collapse to one — pass --devices 8 to fork 8 CPU
-devices for true multi-worker DP.
+Byzantine to demonstrate the filter on a real model. With a single
+device the "workers" collapse to one — pass --devices 8 for true
+multi-worker DP: 8 chips, or under JAX_PLATFORMS=cpu 8 forked CPU
+devices.
 """
 import argparse
 import contextlib
-import os
 import time
 
 
@@ -37,15 +37,17 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    from repro.run.facade import force_host_devices
+
     if args.devices > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+        force_host_devices(args.devices)
 
     import jax
 
     from repro.configs.base import ModelConfig
     from repro.data import make_batch_iterator
     from repro.launch.engine import Trainer, TrainerConfig, TrainSettings
+    from repro.launch.mesh import make_host_mesh
     from repro.models import model as M
     from repro.models.nn import count_params, split_params
     from repro.optim import adamw, linear_warmup_cosine
@@ -64,7 +66,12 @@ def main():
 
     mesh = None
     if args.devices > 1:
-        mesh = jax.make_mesh((args.devices,), ("data",))
+        devices = jax.devices()
+        if len(devices) < args.devices:
+            raise SystemExit(f"--devices {args.devices} but jax has "
+                             f"{len(devices)} {devices[0].platform} "
+                             f"device(s)")
+        mesh = make_host_mesh(devices[:args.devices])
 
     opt = adamw(linear_warmup_cosine(args.lr, 20, args.steps),
                 weight_decay=0.01)

@@ -87,6 +87,7 @@ def main(argv=None) -> int:
         return 0
 
     from repro.run import facade
+    facade.enable_compile_cache()
     try:
         if args.command == "train":
             facade.print_train_summary(facade.train(cfg))

@@ -8,29 +8,21 @@ The layer between the pure model/aggregation math and the launch stack:
     moe    (moe_sharding.py) tensor- and expert-parallel MoE
     fsdp   (fsdp.py)        param sharding + blockwise-CGC reduce-scatter
     echo   (echo_dp.py)     coefficient-space optimistic aggregation
-    compat (compat.py)      jax version shims (AbstractMesh, shard_map)
-
-Importing the package installs the jax compat shims (idempotent).
 """
-from . import compat as _compat
-
-_compat.install()
-
-from .compat import abstract_mesh, mesh_axis_sizes               # noqa: E402
-from .context import ShardCtx, make_shard_ctx                     # noqa: E402
-from .sharding import (DEFAULT_RULES, EP_RULES, Rule, spec_for,   # noqa: E402
+from .context import ShardCtx, make_shard_ctx
+from .sharding import (DEFAULT_RULES, EP_RULES, Rule, spec_for,
                        tree_shardings, tree_specs)
-from .collectives import (AGG_FNS, aggregate_pytree_cgc,          # noqa: E402
+from .collectives import (AGG_FNS, aggregate_pytree_cgc,
                           aggregate_pytree_cgc_sum,
                           aggregate_pytree_mean, inject_byzantine,
                           worker_index)
-from .moe_sharding import moe_sharded                             # noqa: E402
-from . import collectives, echo_dp, fsdp                          # noqa: E402
+from .moe_sharding import moe_sharded
+from . import collectives, echo_dp, fsdp
 
 __all__ = [
     "AGG_FNS", "DEFAULT_RULES", "EP_RULES", "Rule", "ShardCtx",
-    "abstract_mesh", "aggregate_pytree_cgc", "aggregate_pytree_cgc_sum",
+    "aggregate_pytree_cgc", "aggregate_pytree_cgc_sum",
     "aggregate_pytree_mean", "collectives", "echo_dp", "fsdp",
-    "inject_byzantine", "make_shard_ctx", "mesh_axis_sizes", "moe_sharded",
+    "inject_byzantine", "make_shard_ctx", "moe_sharded",
     "spec_for", "tree_shardings", "tree_specs", "worker_index",
 ]

@@ -110,9 +110,9 @@ def tree_norm(grads) -> jax.Array:
     """Global L2 norm of a gradient pytree (fp32 accumulation).
 
     The sum of squares dispatches through ``kernels.ops.tree_sq_norm``
-    — on TPU that is the fused Pallas streaming pass
-    (``cgc_clip.row_sq_norms``) instead of a per-leaf jnp reduction
-    chain, so every CGC/echo/FSDP norm in this module rides the kernel
+    — on TPU that is the Pallas streaming pass (``cgc_clip.sq_sum``,
+    one per leaf, reading each in place), so every CGC/echo/FSDP norm
+    in this module rides the kernel
     (backend switch: ``kernels.ops.set_norm_backend`` /
     ``REPRO_NORM_BACKEND``).
     """

@@ -11,8 +11,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional, Tuple
 
-from .compat import mesh_axis_sizes
-
 DATA_AXES_ORDER = ("pod", "data")    # leading axis is the pod-level DP axis
 
 
@@ -33,7 +31,7 @@ class ShardCtx:
         """Product of the data-axis sizes (1 without a mesh)."""
         if self.mesh is None or not self.batch_axes:
             return 1
-        sizes = mesh_axis_sizes(self.mesh)
+        sizes = self.mesh.shape
         n = 1
         for ax in self.batch_axes:
             n *= sizes[ax]
@@ -46,7 +44,7 @@ def make_shard_ctx(mesh, global_batch: int, moe_impl: str = "tp"
     if mesh is None:
         return ShardCtx(mesh=None, batch_axes=(), model_axis=None,
                         moe_impl=moe_impl, global_batch=global_batch)
-    sizes = mesh_axis_sizes(mesh)
+    sizes = mesh.shape
     batch_axes = tuple(a for a in DATA_AXES_ORDER if a in sizes)
     n_workers = 1
     for a in batch_axes:

@@ -14,9 +14,11 @@ per-worker *block* gradient, so the VJP
 
 Per-worker full gradients therefore never materialise — the memory point
 of FSDP survives the robust aggregation. Blockwise clipping is an
-approximation of the replicated trainer's whole-gradient clipping; with
-honest (outlier-free) workers the two agree to a few 1e-4
-(tests/test_dist.py::test_fsdp_matches_replicated_trainer).
+approximation of the replicated trainer's whole-gradient clipping: the
+two agree to rounding when the workers' norms tie (identical worker
+batches), and otherwise by a margin that depends on the data
+(tests/test_dist.py::test_fsdp_matches_replicated_trainer checks both
+that and the blockwise clip itself against a plain jnp reference).
 
 Leaves too small to be worth sharding (< ``MIN_FSDP_ELEMS`` elements, a
 module global so tests can lower it) stay replicated and are aggregated
@@ -34,7 +36,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.cgc import cgc_scales
 from repro.models.nn import Param
 from .collectives import _gather_scalar, tree_norm, worker_index
-from .compat import mesh_axis_sizes
 
 MIN_FSDP_ELEMS = 1 << 16        # below this a leaf stays replicated
 
@@ -48,7 +49,7 @@ def _is_param(x) -> bool:
 
 
 def _dp_total(mesh, dp_axes: Sequence[str]) -> int:
-    sizes = mesh_axis_sizes(mesh)
+    sizes = mesh.shape
     n = 1
     for a in dp_axes:
         n *= sizes[a]

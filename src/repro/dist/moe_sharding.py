@@ -16,10 +16,9 @@ dispatches to when a ShardCtx with a mesh is supplied. Two layouts:
     union of all shards' tokens, and a second all_to_all returns the
     outputs to the tokens' home shards.
 
-Both run as one fully-manual shard_map over the mesh. On jax 0.4.37
-(no partial-manual shard_map) this means ``moe_impl="ep"`` cannot be
-nested inside the trainer's worker shard_map; the direct (pjit-level)
-entry points — serving, prefill, and the dist tests — are unaffected.
+Both run as one fully-manual shard_map over the mesh. Inside the
+trainer's worker shard_map, which is manual over the data axes only,
+the EP region nests over the model axis.
 """
 from __future__ import annotations
 
@@ -31,10 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import nn
-from .compat import install, mesh_axis_sizes
 from .context import ShardCtx
-
-install()
 
 
 def _batch_spec(ctx: ShardCtx):
@@ -82,7 +78,7 @@ def _moe_tp(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
     from repro.models.moe import moe_local
 
     m_ax = ctx.model_axis
-    sizes = mesh_axis_sizes(ctx.mesh)
+    sizes = ctx.mesh.shape
     M = sizes.get(m_ax, 1) if m_ax else 1
     if M > 1 and cfg.moe_d_ff % M:
         raise ValueError(f"tp MoE needs moe_d_ff % model axis == 0 "
@@ -114,7 +110,7 @@ def _moe_ep(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
                                   load_balance_loss, router_topk)
 
     m_ax = ctx.model_axis
-    sizes = mesh_axis_sizes(ctx.mesh)
+    sizes = ctx.mesh.shape
     M = sizes.get(m_ax, 1) if m_ax else 1
     E, k = cfg.num_experts, cfg.top_k
     if M > 1 and E % M:

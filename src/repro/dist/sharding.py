@@ -30,7 +30,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.nn import Param
-from .compat import mesh_axis_sizes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,7 +66,7 @@ def spec_for(shape: Sequence[int], names: Sequence[Optional[str]], mesh,
              rules: Tuple[Rule, ...] = DEFAULT_RULES) -> P:
     """Resolve one array's logical names into a PartitionSpec on ``mesh``."""
     assert len(shape) == len(names), (tuple(shape), tuple(names))
-    sizes = mesh_axis_sizes(mesh)
+    sizes = mesh.shape
     rule_for = {r.logical: (i, r) for i, r in enumerate(rules)}
     entries: list = [None] * len(shape)
     used: set = set()

@@ -9,6 +9,10 @@ VMEM in (n, BLOCK_D) tiles:
                     VMEM accumulator while streaming the tiles;
   ``scale_kernel``  re-stream the tiles, multiplying each row by a
                     per-row scale;
+  ``sq_sum_kernel`` one gradient leaf's sum of squares: full-width row
+                    tiles of the leaf in its own layout, so a model's
+                    gradient tree is normed leaf by leaf with no
+                    concatenated or padded copy of it;
   ``fused_kernel``  the whole round in ONE pallas_call: a (2, d_blocks)
                     grid streams the table twice without ever leaving
                     the device — phase 0 accumulates sq-norms and, on
@@ -35,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 F32 = jnp.float32
 
 DEFAULT_BLOCK_D = 2048
+DEFAULT_SQ_BLOCK_ELEMS = 1 << 19   # 2 MiB of fp32 per sq_sum row tile
 
 
 def _norms_kernel(g_ref, out_ref, acc_ref):
@@ -69,6 +74,46 @@ def row_sq_norms(G: jax.Array, block_d: int = DEFAULT_BLOCK_D,
         interpret=interpret,
     )(G)
     return out[:, 0]
+
+
+def _sq_sum_kernel(x_ref, out_ref, acc_ref, *, rows: int, block_r: int):
+    """Grid (row_blocks,). Accumulate column sums of x*x into acc (1,
+    cols); rows past ``rows`` (the ragged last tile) count as zero."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    blk = x_ref[...].astype(F32)                    # (block_r, cols)
+    if rows % block_r:
+        r = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+        blk = jnp.where(r + i * block_r < rows, blk, 0.0)
+    acc_ref[...] += jnp.sum(blk * blk, axis=0, keepdims=True)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _done():
+        out_ref[...] = acc_ref[...]
+
+
+def sq_sum(X: jax.Array, interpret: bool = False) -> jax.Array:
+    """(rows, cols) -> (1, cols) fp32 column sums of squares.
+
+    Streams row tiles of about ``DEFAULT_SQ_BLOCK_ELEMS`` elements, each
+    the full row width; a ragged last tile is masked in-kernel, so the
+    operand is read in place with no padded copy."""
+    rows, cols = X.shape
+    br = max(32, (DEFAULT_SQ_BLOCK_ELEMS // cols) // 32 * 32)
+    br = rows if rows <= br else br
+    return pl.pallas_call(
+        functools.partial(_sq_sum_kernel, rows=rows, block_r=br),
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[pl.BlockSpec((br, cols), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((1, cols), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, cols), F32),
+        scratch_shapes=[pltpu.VMEM((1, cols), F32)],
+        interpret=interpret,
+    )(X)
 
 
 def _fused_kernel(g_ref, agg_ref, sq_ref, scale_ref, acc_ref, sc_ref, *,

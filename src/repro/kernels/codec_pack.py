@@ -34,6 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -41,6 +42,7 @@ F32 = jnp.float32
 
 ROWS = 32               # sublane count: int8's minimum tile, fine for f32
 DEFAULT_BLOCK_C = 512   # lane tile (multiple of 128)
+INV_127 = np.float32(1.0 / 127.0)
 
 
 def _flat_index(cols: int, i: int, bc: int, shape):
@@ -57,7 +59,9 @@ def _flat_index(cols: int, i: int, bc: int, shape):
 
 
 def _int8_pack_kernel(v_ref, q_ref, scale_ref, amax_ref, s_ref):
-    """Grid (2, c_blocks): phase 0 absmax reduce, phase 1 quantize."""
+    """Grid (2, c_blocks): phase 0 absmax reduce, phase 1 quantize. The
+    scale stays a (1, 1) vector end to end: the TPU compiler refuses
+    scalar stores to VMEM."""
     p = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -73,14 +77,22 @@ def _int8_pack_kernel(v_ref, q_ref, scale_ref, amax_ref, s_ref):
 
     @pl.when((p == 0) & (i == pl.num_programs(1) - 1))
     def _scale():
-        s = jnp.maximum(jnp.max(amax_ref[...]), 1e-30) / 127.0
-        s_ref[0, 0] = s
-        scale_ref[0, 0] = s
+        s = int8_scale(jnp.max(amax_ref[...], axis=0, keepdims=True))
+        s_ref[...] = s
+        scale_ref[...] = s
 
     @pl.when(p == 1)
     def _quantize():
-        q = jnp.round(v_ref[...] / s_ref[0, 0])
+        q = jnp.round(v_ref[...] / s_ref[...])
         q_ref[...] = jnp.clip(q, -127, 127).astype(jnp.int8)
+
+
+def int8_scale(absmax: jax.Array) -> jax.Array:
+    """The int8 codec's scale from the absmax: a multiply by the fp32
+    reciprocal of 127, not a division. XLA rewrites a division by a
+    constant into that multiply under jit but not in eager mode, so a
+    division would round differently depending on where it runs."""
+    return jnp.maximum(absmax, 1e-30) * INV_127
 
 
 def int8_pack(V: jax.Array, block_c: int = DEFAULT_BLOCK_C,
@@ -92,7 +104,9 @@ def int8_pack(V: jax.Array, block_c: int = DEFAULT_BLOCK_C,
         _int8_pack_kernel,
         grid=(2, cols // block_c),
         in_specs=[pl.BlockSpec((ROWS, block_c), lambda p, i: (0, i))],
-        out_specs=[pl.BlockSpec((ROWS, block_c), lambda p, i: (0, i)),
+        # phase 0 parks the int8 block at tile 0, so no unwritten tile
+        # is copied back to HBM before phase 1 fills it
+        out_specs=[pl.BlockSpec((ROWS, block_c), lambda p, i: (0, i * p)),
                    pl.BlockSpec((1, 1), lambda p, i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((ROWS, cols), jnp.int8),
                    jax.ShapeDtypeStruct((1, 1), F32)],
@@ -131,8 +145,8 @@ def int8_unpack(Q: jax.Array, scale: jax.Array,
 def _topk_pack_kernel(v_ref, vals_ref, idx_ref, *, k: int, cols: int,
                       bc: int, kp: int):
     """Grid (c_blocks,). Extract this tile's k largest-|v| candidates
-    (ties -> lowest flat index) into padded (1, kp) rows; slots past k
-    carry idx -1."""
+    (ties -> lowest flat index) into padded (1, 1, kp) rows; slots past
+    k carry idx -1."""
     i = pl.program_id(0)
     blk = v_ref[...]                                  # (ROWS, bc)
     gidx = _flat_index(cols, i, bc, blk.shape)
@@ -148,8 +162,8 @@ def _topk_pack_kernel(v_ref, vals_ref, idx_ref, *, k: int, cols: int,
         vals_row = jnp.where(lane == j, val, vals_row)
         idx_row = jnp.where(lane == j, first, idx_row)
         work = jnp.where(gidx == first, -1.0, work)   # below any |v|
-    vals_ref[...] = vals_row
-    idx_ref[...] = idx_row
+    vals_ref[0] = vals_row
+    idx_ref[0] = idx_row
 
 
 def topk_pack_candidates(V: jax.Array, k: int,
@@ -164,17 +178,21 @@ def topk_pack_candidates(V: jax.Array, k: int,
     assert ROWS * block_c >= k, (block_c, k)
     nblk = cols // block_c
     kp = -(-k // 128) * 128
-    return pl.pallas_call(
+    # one (1, kp) row per tile, kept in its own (1, kp) plane: the TPU
+    # wants a block's last two dims tiled by (8, 128) or equal to the
+    # array's, and a (1, kp) block of an (nblk, kp) array is neither
+    vals, idx = pl.pallas_call(
         functools.partial(_topk_pack_kernel, k=k, cols=cols, bc=block_c,
                           kp=kp),
         grid=(nblk,),
         in_specs=[pl.BlockSpec((ROWS, block_c), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, kp), lambda i: (i, 0)),
-                   pl.BlockSpec((1, kp), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nblk, kp), F32),
-                   jax.ShapeDtypeStruct((nblk, kp), jnp.int32)],
+        out_specs=[pl.BlockSpec((1, 1, kp), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((1, 1, kp), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((nblk, 1, kp), F32),
+                   jax.ShapeDtypeStruct((nblk, 1, kp), jnp.int32)],
         interpret=interpret,
     )(V)
+    return vals[:, 0], idx[:, 0]
 
 
 def _topk_unpack_kernel(vals_ref, idx_ref, out_ref, *, k: int, cols: int,

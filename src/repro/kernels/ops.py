@@ -36,9 +36,8 @@ def _on_tpu() -> bool:
 # Backend switches (DESIGN.md §5): the CGC hot path in
 # dist/collectives.py computes gradient-pytree norms through
 # ``tree_sq_norm`` below, which dispatches through the NORM_BACKENDS
-# registry either to the fused Pallas streaming pass
-# (cgc_clip.row_sq_norms — one kernel over the raveled gradient instead
-# of a per-leaf reduction chain) or to plain jnp; scale_rows and
+# registry either to the Pallas streaming pass (cgc_clip.sq_sum over
+# each leaf in its own layout) or to plain jnp; scale_rows and
 # paged_decode_attention dispatch the same way. Registering a new
 # implementation (e.g. a cuda kernel) makes it selectable by name with
 # no edits here.
@@ -143,31 +142,48 @@ def codec_pack_backend() -> str:
 
 
 @NORM_BACKENDS.register("jnp")
-def _tree_sq_norm_jnp(leaves, block_d: int) -> jax.Array:
+def _tree_sq_norm_jnp(leaves) -> jax.Array:
     return sum(jnp.sum(jnp.square(g.astype(F32))) for g in leaves)
 
 
+_MAX_SQ_COLS = 1 << 15     # widest row a sq_sum tile takes whole
+_SQ_FOLD_COLS = 1024       # row width a wider or 1-D leaf is folded to
+
+
+def _leaf_rows(g: jax.Array) -> jax.Array:
+    """A gradient leaf as a 2-D (rows, cols) view for ``sq_sum``.
+
+    Leading dims merge into the rows, which leaves the TPU's tiled
+    layout of the last two dims, and so the leaf's bytes, in place.
+    Only a leaf whose last dim is too wide for a VMEM row tile is
+    flattened and folded, zero-padded to whole rows."""
+    if g.ndim >= 2 and g.shape[-1] <= _MAX_SQ_COLS:
+        return g.reshape(-1, g.shape[-1])
+    flat = g.reshape(-1)
+    if flat.shape[0] <= _MAX_SQ_COLS:
+        return flat[None, :]
+    return _pad_to(flat, _SQ_FOLD_COLS, 0).reshape(-1, _SQ_FOLD_COLS)
+
+
 @NORM_BACKENDS.register("pallas")
-def _tree_sq_norm_pallas(leaves, block_d: int) -> jax.Array:
-    flat = [g.astype(F32).reshape(-1) for g in leaves]
-    v = jnp.concatenate(flat) if len(flat) > 1 else flat[0]
-    bd = _block_for(v.shape[0], block_d)
-    G = pad_rows(v[None, :], bd)
-    return _cgc.row_sq_norms(G, bd, not _on_tpu())[0]
+def _tree_sq_norm_pallas(leaves) -> jax.Array:
+    interpret = not _on_tpu()
+    return sum(jnp.sum(_cgc.sq_sum(_leaf_rows(g), interpret=interpret))
+               for g in leaves)
 
 
-def tree_sq_norm(tree, block_d: int = 2048) -> jax.Array:
+def tree_sq_norm(tree) -> jax.Array:
     """fp32 sum of squares over every leaf of ``tree`` (or leaf list).
 
-    The "pallas" backend concatenates the raveled leaves into one (1, d)
-    row and streams it through ``cgc_clip.row_sq_norms`` in
-    (8, block_d) VMEM tiles — the fused pass robust aggregation uses at
-    model scale. Safe inside shard_map (interpret mode off-TPU).
+    The "pallas" backend streams each leaf through ``cgc_clip.sq_sum``
+    in its own row layout, one kernel per leaf: the peak memory of the
+    norm is the gradient's own bytes, with no concatenated or padded
+    copy of the tree. Safe inside shard_map (interpret mode off-TPU).
     """
     leaves = jax.tree.leaves(tree)
     if not leaves:
         return jnp.zeros((), F32)
-    return _norm_switch.impl()(leaves, block_d)
+    return _norm_switch.impl()(leaves)
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -367,7 +383,7 @@ class _JnpCodecPack:
     @staticmethod
     def int8_pack(v, block_c):
         v = v.astype(F32)
-        scale = jnp.maximum(jnp.max(jnp.abs(v)), 1e-30) / 127.0
+        scale = _pack.int8_scale(jnp.max(jnp.abs(v)))
         q = jnp.clip(jnp.round(v / scale), -127, 127).astype(jnp.int8)
         return q, scale.astype(F32)
 

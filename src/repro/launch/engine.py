@@ -277,6 +277,14 @@ class _StrategyBase:
             return StepBundle(self.name, worker_fn, ctx, settings,
                               returns_aggregate=ret_agg)
 
+        # The TPU compiler cannot partition a Pallas kernel (the norm and
+        # CGC passes) over an auto axis, even one of size 1. A size-1
+        # axis partitions nothing, so it is made manual with the data
+        # axes, unless a nested EP region shards over it.
+        manual = set(data_axes) | {
+            a for a, size in mesh.shape.items()
+            if size == 1 and not (a == "model" and settings.moe_impl == "ep")}
+
         def stepped(values, opt_state, batch, step, *extra):
             vspecs = self.value_specs(env, values)
             ospecs = self.opt_specs(env, opt_state, vspecs)
@@ -285,8 +293,8 @@ class _StrategyBase:
             out_specs = (vspecs, ospecs, P()) + (
                 (replicated_specs(values),) if ret_agg else ())
             fn = jax.shard_map(worker_fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs,
-                               axis_names=set(data_axes), check_vma=False)
+                               out_specs=out_specs, axis_names=manual,
+                               check_vma=False)
             return fn(values, opt_state, batch, step, *extra)
 
         if self.needs_basis:
@@ -319,18 +327,10 @@ class ReplicatedStrategy(_StrategyBase):
                              f"known: {sorted(AGG_FNS)}")
 
     def prepare(self, cfg, opt, settings, mesh, ctx):
-        data_axes = ctx.batch_axes
         if settings.moe_impl == "ep" and mesh is not None:
             # expert parallelism runs a NESTED shard_map over the model
             # axis (disjoint from the worker's manual data axes): batch
             # is already local, so batch_axes=() inside.
-            from repro.dist.compat import partial_manual_supported
-            if data_axes and not partial_manual_supported():
-                raise ValueError(
-                    "moe_impl='ep' inside the worker shard_map needs "
-                    "partial-manual shard_map (jax >= 0.6); this jax only "
-                    "supports EP at the pjit level (serve/prefill) — use "
-                    "moe_impl='tp' for training")
             inner = ShardCtx(mesh=mesh, batch_axes=(), model_axis="model",
                              moe_impl="ep", remat=settings.remat)
         else:
@@ -612,8 +612,8 @@ class TrainerConfig:
     # aggregates lie in span(basis) and add no information) or "always".
     roll_policy: str = "raw"
     # jax.profiler trace window over the first ``profile_steps`` rounds
-    # of fit() (0 = off), written to ``profile_dir``. Profiler failures
-    # become obs events, never run failures.
+    # of fit() (0 = off), written to ``profile_dir``. A profiler failure
+    # fails the run.
     profile_steps: int = 0
     profile_dir: Optional[str] = None
 
@@ -1036,7 +1036,10 @@ class Trainer:
             else:
                 # optimistic round invalid (Eq. 7 failed, echo slots
                 # faded, or never attempted): fall back to the exact CGC
-                # step and roll the basis with the raw aggregate.
+                # step and roll the basis with the raw aggregate. The
+                # discarded attempt's weights and aggregate are released
+                # first: at published widths they would not fit beside it.
+                v = o = agg = None
                 with obs.span("fallback"):
                     v, o, m, agg = self.fallback_fn(
                         state.values, state.opt_state, batch, step_arr)
@@ -1079,28 +1082,21 @@ class Trainer:
         return new_state, record
 
     def _profiler_window(self, steps_done: int):
-        """Start/stop the jax.profiler trace around the first
-        ``profile_steps`` rounds of this fit(). Never fatal: profiler
-        problems (already tracing, missing backend support) become obs
-        events and the run continues unprofiled."""
+        """Start the jax.profiler trace around the first
+        ``profile_steps`` rounds of this fit(). A profile that was asked
+        for and cannot be taken fails the run: a run that silently
+        carries on unprofiled would look like one that was measured."""
         cfg = self.config
         if not cfg.profile_steps or not cfg.profile_dir:
             return None, 0
-        try:
-            jax.profiler.start_trace(cfg.profile_dir)
-            obs.event("train.profile_start", dir=cfg.profile_dir,
-                      steps=cfg.profile_steps)
-            return True, steps_done + cfg.profile_steps
-        except Exception as e:
-            obs.event("train.profile_error", error=repr(e))
-            return None, 0
+        jax.profiler.start_trace(cfg.profile_dir)
+        obs.event("train.profile_start", dir=cfg.profile_dir,
+                  steps=cfg.profile_steps)
+        return True, steps_done + cfg.profile_steps
 
     def _profiler_stop(self) -> None:
-        try:
-            jax.profiler.stop_trace()
-            obs.event("train.profile_stop", dir=self.config.profile_dir)
-        except Exception as e:
-            obs.event("train.profile_error", error=repr(e))
+        jax.profiler.stop_trace()
+        obs.event("train.profile_stop", dir=self.config.profile_dir)
 
     def fit(self, state: TrainState, batches: Iterator, steps: int
             ) -> Tuple[TrainState, Dict[str, Any]]:

@@ -19,11 +19,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     return jax.make_mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
-    """Tiny mesh over whatever devices exist (CPU tests)."""
-    n = len(jax.devices())
-    data = max(n // model, 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_host_mesh(devices, model: int = 1) -> jax.sharding.Mesh:
+    """(data, model) mesh over ``devices``: a job's chips, or the CPU
+    platform's host devices."""
+    data = max(len(devices) // model, 1)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         devices=devices[:data * model])
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

@@ -12,9 +12,9 @@ one DeprecationWarning, builds the equivalent :class:`repro.run.
 RunConfig` and calls ``repro.run.train`` — the same facade
 ``python -m repro train --config job.json`` runs, so legacy flag
 invocations and config-driven runs execute the same jitted step bit for
-bit (DESIGN.md §8). On CPU-only hosts ``--devices`` forces fake host
-devices (default 8) before jax initialises, so the worker axes exist;
-pass ``--devices 0`` on real accelerators.
+bit (DESIGN.md §8). ``--devices N`` (default 8) is the job's device
+count: the first N chips, or under ``JAX_PLATFORMS=cpu`` N host devices
+set before jax initialises; 0 uses every device.
 """
 from __future__ import annotations
 
@@ -61,12 +61,6 @@ def make_echo_train_step(cfg, opt, settings: TrainSettings, mesh,
 # ---------------------------------------------------------------------------
 
 
-def _force_host_devices(n: int) -> None:
-    """Force n fake host devices — must run before jax backend init."""
-    from repro.run.facade import force_host_devices
-    force_host_devices(n)
-
-
 def config_from_flags(args) -> "run.RunConfig":
     """The flags->RunConfig adapter: one legacy argparse namespace maps
     to exactly the job tree the unified CLI would load, so both paths
@@ -111,8 +105,8 @@ def main(argv=None):
     ap.add_argument("--echo-k", type=int, default=4)
     ap.add_argument("--echo-r", type=float, default=0.9)
     ap.add_argument("--devices", type=int, default=8,
-                    help="force this many fake host devices (0: use the "
-                         "real devices — pass 0 on accelerators)")
+                    help="devices the job uses: the first N chips, or N "
+                         "host devices under JAX_PLATFORMS=cpu (0: all)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--resume", action="store_true")

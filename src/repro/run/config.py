@@ -137,9 +137,9 @@ class ModelSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MeshSpec:
-    """Worker topology. ``devices`` forces that many fake host devices
-    before jax initialises (the CLI path on CPU); 0 uses the real
-    devices."""
+    """Worker topology. ``devices`` is how many devices the job uses:
+    the first N chips, or under ``JAX_PLATFORMS=cpu`` that many host
+    devices set before jax initialises; 0 uses every device."""
 
     devices: int = 8
     moe_impl: str = "tp"
@@ -176,8 +176,8 @@ class TrainSpec:
     resume: bool = False
     metrics_path: Optional[str] = None   # None: <run_dir>/metrics.jsonl
     # jax.profiler trace window: profile the first N fit rounds into
-    # <run_dir>/profile (0 = off). Failures to start the profiler are
-    # recorded as obs events, never fatal.
+    # <run_dir>/profile (0 = off). A profile that cannot be taken fails
+    # the run.
     profile_steps: int = 0
 
 

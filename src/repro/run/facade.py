@@ -19,6 +19,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
+import pathlib
 import warnings
 from typing import Any, Dict, Iterator, List, Optional
 
@@ -65,12 +66,40 @@ def warn_legacy(entrypoint: str, replacement: str) -> None:
 
 
 def force_host_devices(n: int) -> None:
-    """Force n fake host devices — must run before jax backend init."""
+    """Give the CPU platform n host devices, one per worker — must run
+    before jax initialises its backend.
+
+    Only under ``JAX_PLATFORMS=cpu`` (tests, CI, the CLIs on a host
+    without a chip). On any other platform the devices are chips, and a
+    run that cannot reach them fails instead of falling back to fake CPU
+    devices."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         return
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={n}").strip()
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is jax's own and is left as
+    it is. Otherwise the cache lives at one fixed path inside the
+    checkout, ``.jax_cache`` (git-ignored): the directory is part of
+    the cache key, so only a path that never moves is ever hit again.
+    Entry points call this before compiling anything; importing a
+    module never does."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        # <checkout>/src/repro/run/facade.py -> <checkout>/.jax_cache
+        path = str(pathlib.Path(__file__).resolve().parents[3]
+                   / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------
@@ -169,37 +198,47 @@ def _model_setup(cfg: RunConfig):
     return model_cfg
 
 
-def _check_forced_devices(cfg: RunConfig) -> int:
-    """Devices jax actually has; warn when the config asked for a
-    different forced count (backend already initialised, or XLA_FLAGS
-    pre-set) so the run_dir's config.json can't silently misrepresent
-    the worker topology that ran."""
+def _job_devices(cfg: RunConfig) -> list:
+    """The first ``mesh.devices`` devices jax has (all of them for 0).
+
+    Asking for more than exist is an error: the run would otherwise
+    record in its config.json a worker topology that never ran. So is
+    landing on the CPU without ``JAX_PLATFORMS=cpu``: that is jax's
+    fallback when no accelerator came up, not a platform the job chose."""
     import jax
 
-    n_dev = len(jax.devices())
-    if cfg.mesh.devices and n_dev != cfg.mesh.devices:
-        print(f"warning: mesh.devices={cfg.mesh.devices} requested but "
-              f"jax has {n_dev} device(s) — the backend was already "
-              f"initialised (or XLA_FLAGS pre-set), so this run uses "
-              f"{n_dev}; config.json records the request, not the "
-              f"actual count")
-    return n_dev
+    devices = jax.devices()
+    if (devices[0].platform == "cpu"
+            and os.environ.get("JAX_PLATFORMS") != "cpu"):
+        raise RuntimeError(
+            "jax found no accelerator and fell back to the CPU; set "
+            "JAX_PLATFORMS=cpu to run this job on the CPU")
+    want = cfg.mesh.devices or len(devices)
+    if want > len(devices):
+        hint = (" (host devices are set before jax initialises its "
+                "backend, and this process had already initialised it)"
+                if devices[0].platform == "cpu" else "")
+        raise ValueError(f"mesh.devices={want} but jax has {len(devices)} "
+                         f"{devices[0].platform} device(s){hint}")
+    return devices[:want]
 
 
 def _make_mesh(cfg: RunConfig, batch: int, strategy: str,
                needs_workers: bool):
-    """Worker mesh over the (possibly forced) host devices, with the
-    legacy CLI's validation messages."""
+    """Worker mesh over the job's devices, with the legacy CLI's
+    validation messages."""
     from repro.launch.mesh import make_host_mesh
 
     scen = cfg.scenario
-    n_dev = _check_forced_devices(cfg)
-    mesh = make_host_mesh() if n_dev > 1 and batch % n_dev == 0 else None
+    devices = _job_devices(cfg)
+    n_dev = len(devices)
+    mesh = make_host_mesh(devices) if n_dev > 1 and batch % n_dev == 0 \
+        else None
     if mesh is None and needs_workers:
         raise ValueError(
             f"strategy {strategy!r} needs >1 data-parallel workers: set "
-            f"mesh.devices=N (and a train.batch divisible by N), or "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=N")
+            f"mesh.devices=N (and a train.batch divisible by N) on a "
+            f"host with N devices")
     if scen.n_byz and mesh is None:
         raise ValueError(
             "scenario.n_byz needs >1 data-parallel workers: set "
@@ -336,9 +375,11 @@ def train(cfg: RunConfig) -> TrainResult:
             state = trainer.init_state(values)
             it = quad_batches(start=state.step)
         else:
-            params = M.init_params(model_cfg, jax.random.PRNGKey(0))
-            values, _ = split_params(params)
-            state = trainer.init_state(values)
+            # no name keeps the initial values alive: init_state copies
+            # them, and at published widths a second copy of the weights
+            # is what decides whether a step fits the chip
+            state = trainer.init_state(split_params(
+                M.init_params(model_cfg, jax.random.PRNGKey(0)))[0])
             # start=state.step: a resumed run continues the data stream
             # instead of re-consuming batches the checkpointed run saw.
             it = make_batch_iterator(model_cfg, tspec.batch, tspec.seq,
@@ -390,9 +431,9 @@ def serve(cfg: RunConfig) -> ServeResult:
     """Run the serving workload: a seeded synthetic mixed-length request
     trace through :class:`repro.serve.ServeEngine`.
 
-    ``mesh.devices`` forces host devices exactly like the train facade;
+    ``mesh.devices`` picks the devices exactly like the train facade;
     with more than one device the engine runs tensor-parallel over a
-    (data=1, model=n) host mesh (params + page pools sharded by the
+    (data=1, model=n) mesh (params + page pools sharded by the
     logical-axis rules), honouring ``mesh.moe_impl``.
     """
     if cfg.serve is None:
@@ -415,8 +456,9 @@ def serve(cfg: RunConfig) -> ServeResult:
         raise ValueError(f"{cfg.model.arch} is encoder-only: no decode "
                          f"step")
 
-    n_dev = _check_forced_devices(cfg)
-    mesh = make_host_mesh(model=n_dev) if n_dev > 1 else None
+    devices = _job_devices(cfg)
+    mesh = make_host_mesh(devices, model=len(devices)) \
+        if len(devices) > 1 else None
 
     run_dir = make_run_dir(cfg, "serve")
     metrics_path = spec.metrics_path or os.path.join(run_dir,

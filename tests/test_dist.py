@@ -11,14 +11,12 @@ import textwrap
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-# abstract_mesh: version-compat constructor (current jax rejects the
-# positional AbstractMesh((16, 16), ("data", "model")) form).
-from repro.dist import DEFAULT_RULES, EP_RULES, abstract_mesh, spec_for
+from repro.dist import DEFAULT_RULES, EP_RULES, spec_for
 
-MESH1 = abstract_mesh((16, 16), ("data", "model"))
-MESH2 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_rules_basic():
@@ -238,12 +236,22 @@ def test_expert_parallel_matches_local():
 
 
 def test_fsdp_matches_replicated_trainer():
-    """FSDP + blockwise-CGC step == replicated CGC step (no outliers)."""
+    """FSDP + blockwise-CGC step against the replicated CGC step and a
+    plain blockwise-CGC reference.
+
+    Distinct worker rows: the FSDP step equals SGD on a jnp blockwise
+    CGC of the same per-worker gradients (one clip per block: the top-
+    level sharded leaves, each layer's sharded leaves, and the
+    replicated rest), and that clip bites in every block. Identical
+    worker rows: every block norm ties across workers, so blockwise
+    clipping is the whole-gradient clipping and the FSDP step equals
+    the replicated trainer's."""
     _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         import repro.dist.fsdp as F
         F.MIN_FSDP_ELEMS = 1 << 10
         from repro.configs import get_config, reduced
+        from repro.core.cgc import cgc_scales
         from repro.data import make_batch_iterator
         from repro.launch.train import (TrainSettings, make_train_step,
                                         make_fsdp_train_step)
@@ -253,27 +261,77 @@ def test_fsdp_matches_replicated_trainer():
 
         cfg = reduced(get_config("qwen3-0.6b"), layers=2, d_model=256)
         mesh = jax.make_mesh((4, 2), ("data", "model"))
-        opt = sgd(0.05)
+        lr, n, f = 0.05, 4, 1
+        opt = sgd(lr)
         values, _ = split_params(M.init_params(cfg, jax.random.PRNGKey(0)))
         state = opt.init(values)
-        st = TrainSettings(aggregator="cgc", f=1, fsdp=True)
+        st = TrainSettings(aggregator="cgc", f=f, fsdp=True)
         fsdp_step, ctx, (vshard, plan) = make_fsdp_train_step(
             cfg, opt, st, mesh, 8)
         rep_step, _ = make_train_step(
-            cfg, opt, TrainSettings(aggregator="cgc", f=1), mesh, 8)
+            cfg, opt, TrainSettings(aggregator="cgc", f=f), mesh, 8)
+
+        def fsdp(batch):
+            with jax.set_mesh(mesh):
+                vP = jax.device_put(values, vshard)
+                return jax.jit(fsdp_step)(vP, state, batch, jnp.asarray(0))
+
+        def max_diff(xs, ys):
+            return max(float(jnp.max(jnp.abs(
+                a.astype(jnp.float32) - b.astype(jnp.float32))))
+                for a, b in zip(jax.tree.leaves(xs), jax.tree.leaves(ys)))
+
+        # -- distinct rows: FSDP == SGD on a jnp blockwise CGC ----------
         batch = next(make_batch_iterator(cfg, 8, 32, seed=0))
+        v1, _, m1 = fsdp(batch)
+        paths, treedef = jax.tree_util.tree_flatten_with_path(values)
+        dims = jax.tree.flatten(plan, is_leaf=lambda x: x is None)[0]
+        assert len(paths) == len(dims)
+        layered = [p[0].key == "layers" for p, _ in paths]
+        grads = [jax.tree.leaves(jax.grad(
+            lambda v: M.train_loss(v, cfg, jax.tree.map(
+                lambda x: x[2 * w:2 * w + 2], batch))[0])(values))
+            for w in range(n)]
+        G = [jnp.stack([g[i] for g in grads]).astype(jnp.float32)
+             for i in range(len(dims))]          # leaf i: (n, *shape)
+        top = [i for i, d in enumerate(dims) if d is not None
+               and not layered[i]]
+        lay = [i for i, d in enumerate(dims) if d is not None and layered[i]]
+        rest = [i for i, d in enumerate(dims) if d is None]
+        assert top and lay and rest
+        agg = [None] * len(dims)
+        clipped = []
+        for block in (top, rest):                # one scale per worker
+            sq = sum(jnp.sum(G[i] ** 2, axis=tuple(range(1, G[i].ndim)))
+                     for i in block)
+            s = cgc_scales(jnp.sqrt(sq), f)
+            clipped.append(float(jnp.min(s)))
+            for i in block:
+                agg[i] = jnp.tensordot(s, G[i], axes=1)
+        sq = sum(jnp.sum(G[i] ** 2, axis=tuple(range(2, G[i].ndim)))
+                 for i in lay)                   # (n, layers)
+        s = jax.vmap(lambda col: cgc_scales(col, f), 1, 1)(jnp.sqrt(sq))
+        clipped += [float(v) for v in jnp.min(s, axis=0)]
+        for i in lay:
+            agg[i] = jnp.einsum("wl,wl...->l...", s, G[i])
+        assert max(clipped) < 0.999, clipped     # the clip bites
+        want = jax.tree.unflatten(treedef, [
+            (v.astype(jnp.float32) - lr * a).astype(v.dtype)
+            for v, a in zip(jax.tree.leaves(values), agg)])
+        d_ref = max_diff(v1, want)
+        assert d_ref < 1e-5, d_ref
+
+        # -- identical rows: FSDP == the replicated trainer -------------
+        same = jax.tree.map(
+            lambda x: jnp.tile(x[:2], (n,) + (1,) * (x.ndim - 1)), batch)
+        v1, _, m1 = fsdp(same)
         with jax.set_mesh(mesh):
-            vP = jax.device_put(values, vshard)
-            v1, s1, m1 = jax.jit(fsdp_step)(vP, state, batch,
-                                            jnp.asarray(0))
-            v2, s2, m2 = jax.jit(rep_step)(values, state, batch,
-                                           jnp.asarray(0))
+            v2, _, m2 = jax.jit(rep_step)(values, state, same,
+                                          jnp.asarray(0))
         assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-5
-        d = max(float(jnp.max(jnp.abs(
-            a.astype(jnp.float32) - b.astype(jnp.float32))))
-            for a, b in zip(jax.tree.leaves(v1), jax.tree.leaves(v2)))
-        assert d < 5e-4, d
-        print("OK", d)
+        d_rep = max_diff(v1, v2)
+        assert d_rep < 1e-6, d_rep
+        print("OK", d_ref, d_rep, clipped)
     """)
 
 
@@ -324,7 +382,10 @@ def test_echo_dp_optimistic_training():
 
 
 def test_byzantine_resistance_end_to_end():
-    """CGC training under sign-flip beats mean aggregation (loss-wise)."""
+    """CGC training under large-norm attackers beats mean aggregation
+    (loss-wise). Both sides take the same step size: CGC aggregates the
+    paper's filtered sum over n = 8 workers, so its learning rate is the
+    mean's divided by n."""
     _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, reduced
@@ -337,8 +398,8 @@ def test_byzantine_resistance_end_to_end():
         cfg = reduced(get_config("xlstm-125m"), layers=2, d_model=128)
         mesh = jax.make_mesh((8,), ("data",))
 
-        def run(aggregator, f):
-            opt = sgd(0.05)
+        def run(aggregator, f, lr):
+            opt = sgd(lr)
             params = M.init_params(cfg, jax.random.PRNGKey(0))
             values, _ = split_params(params)
             state = opt.init(values)
@@ -355,8 +416,8 @@ def test_byzantine_resistance_end_to_end():
                                           jnp.asarray(s))
             return float(m["loss"])
 
-        loss_cgc = run("cgc", 2)
-        loss_mean = run("mean", 0)
+        loss_cgc = run("cgc", 2, 0.05 / 8)
+        loss_mean = run("mean", 0, 0.05)
         assert np.isfinite(loss_cgc)
         assert loss_cgc < loss_mean or not np.isfinite(loss_mean), (
             loss_cgc, loss_mean)

@@ -258,11 +258,11 @@ def test_strategies_registry_and_bundle_contract():
 
     # FSDP has no replicated aggregate to emit — build must refuse
     import pytest
-    from repro.dist import abstract_mesh
+    from jax.sharding import AbstractMesh
     with pytest.raises(ValueError, match="return_aggregate"):
         STRATEGIES["fsdp"]().build(
             cfg, opt, TrainSettings(fsdp=True, return_aggregate=True),
-            abstract_mesh((8,), ("data",)), 8)
+            AbstractMesh((8,), ("data",)), 8)
     b = STRATEGIES["replicated"]().build(cfg, opt, TrainSettings(), None, 4)
     assert not b.needs_basis and b.value_shardings is None
     values, _ = split_params(M.init_params(cfg, jax.random.PRNGKey(0)))

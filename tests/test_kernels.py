@@ -52,13 +52,18 @@ def test_echo_project_sweep(n, k, d, dtype):
 def test_tree_sq_norm_backend_dispatch():
     """The CGC norm path's backend switch: the fused Pallas pass
     (interpret mode here) matches the plain jnp reduction on a
-    mixed-shape/dtype gradient pytree."""
+    mixed-shape/dtype gradient pytree. "e" spans three row tiles with a
+    ragged, masked last one; "f" is wider than a row tile takes whole,
+    so it is folded and zero-padded."""
     tree = {
         "a": jax.random.normal(KEY, (37, 19)),
         "b": jax.random.normal(jax.random.fold_in(KEY, 1), (301,)),
         "c": jax.random.normal(jax.random.fold_in(KEY, 2), (5,)
                                ).astype(jnp.bfloat16),
         "d": jnp.asarray(2.5),
+        "e": jax.random.normal(jax.random.fold_in(KEY, 3), (1100, 1024)),
+        "f": jax.random.normal(jax.random.fold_in(KEY, 4), (3, 40000)
+                               ).astype(jnp.bfloat16),
     }
     assert ops.norm_backend() in ("jnp", "pallas")
     try:

@@ -3,15 +3,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
-from repro.dist import abstract_mesh
 from repro.launch.roofline import active_params, analyze, fwd_flops_per_token
 from repro.launch.specs import (batch_for, check_applicability, decode_specs,
                                 long_context_variant)
 from repro.models.rope import apply_mrope, apply_rope
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

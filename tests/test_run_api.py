@@ -7,7 +7,7 @@ import os
 import pytest
 
 from repro.run import (DataSpec, MeshSpec, ModelSpec, RunConfig,
-                       ScenarioSpec, TrainSpec, facade)
+                       ScenarioSpec, ServeSpec, TrainSpec, facade)
 
 
 def _quad_cfg(tmp_path, steps=3, name="quad"):
@@ -66,6 +66,93 @@ def test_train_facade_validation_errors(tmp_path):
                                train=TrainSpec(),
                                runs_root=str(tmp_path)))
     assert bad.model is None          # quadratic path needs no model
+
+
+def test_more_devices_than_exist_is_an_error(tmp_path):
+    """A job never runs on fewer devices than its config.json records:
+    this process has one CPU device, so asking for two fails up front."""
+    import dataclasses
+
+    import jax
+    cfg = dataclasses.replace(_quad_cfg(tmp_path), mesh=MeshSpec(devices=2))
+    assert len(jax.devices()) == 1
+    with pytest.raises(ValueError, match="mesh.devices=2 but jax has 1"):
+        facade.train(cfg)
+    with pytest.raises(ValueError, match="mesh.devices=2 but jax has 1"):
+        facade.serve(RunConfig(model=ModelSpec(smoke=True),
+                               mesh=MeshSpec(devices=2),
+                               serve=ServeSpec(requests=1),
+                               runs_root=str(tmp_path)))
+
+
+def test_cpu_fallback_is_an_error(monkeypatch, tmp_path):
+    """This process's jax is on the CPU. Without JAX_PLATFORMS=cpu that
+    reads as an accelerator that never came up, so train and serve
+    refuse to run, with mesh.devices unset as with it set."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        facade.train(_quad_cfg(tmp_path))
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        facade.serve(RunConfig(model=ModelSpec(smoke=True),
+                               mesh=MeshSpec(devices=0),
+                               serve=ServeSpec(requests=1),
+                               runs_root=str(tmp_path)))
+
+
+def test_profile_that_cannot_be_taken_fails_the_run(tmp_path):
+    """A run that asked for a profile either writes one or fails: here
+    another trace already holds the profiler."""
+    import dataclasses
+
+    import jax
+    cfg = _quad_cfg(tmp_path, steps=2)
+    cfg = dataclasses.replace(
+        cfg, train=dataclasses.replace(cfg.train, profile_steps=1))
+    jax.profiler.start_trace(str(tmp_path / "outer"))
+    try:
+        with pytest.raises(RuntimeError, match="already been started"):
+            facade.train(cfg)
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("platforms,forced", [("cpu", True),
+                                              ("tpu", False), ("", False)])
+def test_host_devices_are_forced_only_on_cpu(monkeypatch, platforms,
+                                             forced):
+    """Fake host devices exist only under JAX_PLATFORMS=cpu: elsewhere
+    mesh.devices counts chips, and a chip that cannot be reached must
+    fail rather than turn into CPU devices."""
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    facade.force_host_devices(4)
+    flags = os.environ.get("XLA_FLAGS", "")
+    assert ("xla_force_host_platform_device_count=4" in flags) == forced
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """The cache sits where JAX_COMPILATION_CACHE_DIR says; unset, at one
+    fixed, git-ignored path inside the checkout."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert facade.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = facade.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert facade.enable_compile_cache() == path      # fixed
+        with open(os.path.join(root, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
 
 
 def test_cli_show_and_list(tmp_path, capsys):

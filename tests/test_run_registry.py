@@ -87,7 +87,7 @@ def test_backend_switch_validates_against_registry():
         and "auto" in str(e.value)
     # a newly registered backend becomes selectable with no ops.py edit
     from repro.run.registry import NORM_BACKENDS
-    NORM_BACKENDS.add("test_dummy", lambda leaves, block_d: 0.0)
+    NORM_BACKENDS.add("test_dummy", lambda leaves: 0.0)
     try:
         ops.set_norm_backend("test_dummy")
         assert ops.norm_backend() == "test_dummy"
