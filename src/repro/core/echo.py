@@ -28,6 +28,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import ECHO_PROJECT
+
 
 class EchoDecision(NamedTuple):
     send_echo: jax.Array     # () bool — Eq. 7 holds and span is non-empty
@@ -62,12 +64,13 @@ def project_onto_span(
     (we store gradients as rows, so A = R[mask].T). Returns (x, echo) with
     x zero outside the mask.
     """
-    Rm = R * mask[:, None]
-    b = Rm @ g                                       # A^T g, (n,)
-    G = masked_gram(R, mask, ridge)
-    x = jnp.linalg.solve(G, b)
-    x = x * mask
-    echo = x @ Rm                                    # A x, (d,)
+    with jax.named_scope(ECHO_PROJECT):
+        Rm = R * mask[:, None]
+        b = Rm @ g                                   # A^T g, (n,)
+        G = masked_gram(R, mask, ridge)
+        x = jnp.linalg.solve(G, b)
+        x = x * mask
+        echo = x @ Rm                                # A x, (d,)
     return x, echo
 
 

@@ -40,6 +40,7 @@ code path, jaxpr and all.
 """
 from __future__ import annotations
 
+import contextlib
 import inspect
 from functools import partial
 from typing import Callable, NamedTuple, Optional, Tuple
@@ -49,6 +50,9 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.comm import ChannelState, CommConfig, CommLedger, DEFAULT_COMM
+from repro.obs.scopes import (PROTOCOL_AGGREGATE, PROTOCOL_GRADS,
+                              PROTOCOL_SLOT, PROTOCOL_UPDATE, SLOT_DECIDE,
+                              SLOT_OVERHEAR, SLOT_SERVER)
 
 from . import aggregators as agg_lib
 from .byzantine import AttackPlan
@@ -85,100 +89,104 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     is_byz = byz_mask[i]
     codec, channel = comm.codec, comm.channel
 
-    # --- Worker i decides what to broadcast (lines 14-24) ----------------
-    # One Gram solve serves both the echo decision (Eq. 7) and the
-    # independence test (line 29): project the broadcast vector once.
-    # For honest workers raw_msg == g_i, so the decision is the paper's;
-    # for Byzantine workers every dec field is overridden by the plan.
-    raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
-    x_proj, proj = project_onto_span(st.R, st.rmask, raw_msg, cfg.ridge)
-    dec = echo_decision_from_projection(x_proj, proj, st.rmask, raw_msg,
-                                        cfg.r)
-    honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
-    mode = jnp.where(is_byz, plan.mode[i], honest_mode).astype(jnp.int32)
+    with jax.named_scope(SLOT_DECIDE):
+        # --- Worker i decides what to broadcast (lines 14-24) ----------------
+        # One Gram solve serves both the echo decision (Eq. 7) and the
+        # independence test (line 29): project the broadcast vector once.
+        # For honest workers raw_msg == g_i, so the decision is the paper's;
+        # for Byzantine workers every dec field is overridden by the plan.
+        raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
+        x_proj, proj = project_onto_span(st.R, st.rmask, raw_msg, cfg.ridge)
+        dec = echo_decision_from_projection(x_proj, proj, st.rmask, raw_msg,
+                                            cfg.r)
+        honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
+        mode = jnp.where(is_byz, plan.mode[i], honest_mode).astype(jnp.int32)
 
-    # --- Channel: per-slot fading ----------------------------------------
-    # A faded echo cannot be verified, so the sender retransmits raw
-    # (the paper's reliability assumption); a faded raw still reaches the
-    # server but is NOT overheard, shrinking the shared reference set.
-    chan, faded = channel.fade(st.chan, i)
-    # Jamming (net/attacks.echo_jam): a worker spending its radio on noise
-    # blankets every *other* slot — same observable semantics as a fade
-    # (echoes unverifiable, raws not overheard); the uplink itself is
-    # directional enough to survive, so the server still receives.
-    jammed = jnp.any(plan.jam & byz_mask) & ~is_byz
-    faded = faded | jammed
-    fellback = (mode == MSG_ECHO) & faded
-    mode = jnp.where(fellback, MSG_RAW, mode)
+        # --- Channel: per-slot fading ----------------------------------------
+        # A faded echo cannot be verified, so the sender retransmits raw
+        # (the paper's reliability assumption); a faded raw still reaches the
+        # server but is NOT overheard, shrinking the shared reference set.
+        chan, faded = channel.fade(st.chan, i)
+        # Jamming (net/attacks.echo_jam): a worker spending its radio on noise
+        # blankets every *other* slot — same observable semantics as a fade
+        # (echoes unverifiable, raws not overheard); the uplink itself is
+        # directional enough to survive, so the server still receives.
+        jammed = jnp.any(plan.jam & byz_mask) & ~is_byz
+        faded = faded | jammed
+        fellback = (mode == MSG_ECHO) & faded
+        mode = jnp.where(fellback, MSG_RAW, mode)
 
-    # --- Wire coding ------------------------------------------------------
-    # Receivers see the codec's reconstruction of every float payload.
-    # ``codec.lossless`` is trace-time static: the fp32 default skips the
-    # roundtrips and the ratio recompute entirely, so its jaxpr (and every
-    # value in it) is exactly the pre-comm slot loop.
-    echo_ref = jnp.where(is_byz, plan.echo_ref[i], st.rmask)
-    echo_x = jnp.where(is_byz, plan.echo_x[i], dec.x)
-    ef_row = st.ef[i]
-    if codec.lossless:
-        raw_wire = raw_msg
-        echo_k = jnp.where(is_byz, plan.echo_k[i], dec.k)
-    else:
-        if use_ef:
-            # error feedback (comm.policy.feedback): compensate the raw
-            # payload with this worker's carried residual; what the codec
-            # loses this slot is carried to the next raw transmission.
-            compensated = raw_msg + ef_row
-            raw_wire = codec.roundtrip(compensated)
-            ef_row = compensated - raw_wire
+        # --- Wire coding -----------------------------------------------------
+        # Receivers see the codec's reconstruction of every float payload.
+        # ``codec.lossless`` is trace-time static: the fp32 default skips the
+        # roundtrips and the ratio recompute entirely, so its jaxpr (and every
+        # value in it) is exactly the pre-comm slot loop.
+        echo_ref = jnp.where(is_byz, plan.echo_ref[i], st.rmask)
+        echo_x = jnp.where(is_byz, plan.echo_x[i], dec.x)
+        ef_row = st.ef[i]
+        if codec.lossless:
+            raw_wire = raw_msg
+            echo_k = jnp.where(is_byz, plan.echo_k[i], dec.k)
         else:
-            raw_wire = codec.roundtrip(raw_msg)
-        echo_x = codec.roundtrip(echo_x)
-        # Honest senders compute the norm ratio against the coefficients
-        # AS TRANSMITTED so ||g~|| == ||g|| survives quantization;
-        # Byzantine senders forge theirs freely.
-        k_honest = wire_norm_ratio(st.R, st.rmask, echo_x, raw_msg)
-        echo_k = codec.roundtrip(
-            jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
+            if use_ef:
+                # error feedback (comm.policy.feedback): compensate the raw
+                # payload with this worker's carried residual; what the codec
+                # loses this slot is carried to the next raw transmission.
+                compensated = raw_msg + ef_row
+                raw_wire = codec.roundtrip(compensated)
+                ef_row = compensated - raw_wire
+            else:
+                raw_wire = codec.roundtrip(raw_msg)
+            echo_x = codec.roundtrip(echo_x)
+            # Honest senders compute the norm ratio against the coefficients
+            # AS TRANSMITTED so ||g~|| == ||g|| survives quantization;
+            # Byzantine senders forge theirs freely.
+            k_honest = wire_norm_ratio(st.R, st.rmask, echo_x, raw_msg)
+            echo_k = codec.roundtrip(
+                jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
 
-    is_raw = mode == MSG_RAW
-    is_echo = mode == MSG_ECHO
+    with jax.named_scope(SLOT_SERVER):
+        is_raw = mode == MSG_RAW
+        is_echo = mode == MSG_ECHO
 
-    # --- Bit pricing + budget admission (Sec. 2.1 via the codec) ---------
-    rank = jnp.sum(echo_ref & st.received)
-    raw_cost = jnp.float32(codec.raw_msg_bits(d))
-    echo_cost = jnp.asarray(codec.echo_msg_bits(n, rank)).astype(jnp.float32)
-    attempt = jnp.where(
-        is_echo, echo_cost,
-        jnp.where(is_raw,
-                  jnp.where(fellback, echo_cost + raw_cost, raw_cost),
-                  0.0))
-    attempt = channel.price(attempt)   # relay fabrics multiply the copies
-    chan, ok = channel.admit(chan, attempt)
-    mode = jnp.where(ok, mode, MSG_SILENT)   # over budget: server times out
-    is_raw = is_raw & ok
-    is_echo = is_echo & ok
-    bits_i = jnp.where(ok, attempt, 0.0)
+        # --- Bit pricing + budget admission (Sec. 2.1 via the codec) ---------
+        rank = jnp.sum(echo_ref & st.received)
+        raw_cost = jnp.float32(codec.raw_msg_bits(d))
+        echo_cost = jnp.asarray(codec.echo_msg_bits(n, rank)).astype(
+            jnp.float32)
+        attempt = jnp.where(
+            is_echo, echo_cost,
+            jnp.where(is_raw,
+                      jnp.where(fellback, echo_cost + raw_cost, raw_cost),
+                      0.0))
+        attempt = channel.price(attempt)   # relay fabrics multiply the copies
+        chan, ok = channel.admit(chan, attempt)
+        mode = jnp.where(ok, mode, MSG_SILENT)  # over budget: server times out
+        is_raw = is_raw & ok
+        is_echo = is_echo & ok
+        bits_i = jnp.where(ok, attempt, 0.0)
 
-    # --- Server processes the message (lines 33-41) ----------------------
-    # Echo referencing an unheard worker == provable Byzantine (lines 36-37).
-    bad_ref = jnp.any(echo_ref & ~st.received)
-    detected_i = is_echo & bad_ref
-    g_echo = reconstruct_echo(st.G, echo_ref & st.received, echo_k, echo_x)
-    g_tilde = jnp.where(is_raw, raw_wire,
-                        jnp.where(is_echo & ~bad_ref, g_echo,
-                                  jnp.zeros((d,), grads.dtype)))
-    g_tilde = channel.deliver(st.chan, i, g_tilde)
-    G = st.G.at[i].set(g_tilde)
-    received = st.received.at[i].set(mode != MSG_SILENT)
-    detected = st.detected.at[i].set(detected_i)
+        # --- Server processes the message (lines 33-41) ----------------------
+        # Echo referencing an unheard worker == provable Byzantine (l. 36-37).
+        bad_ref = jnp.any(echo_ref & ~st.received)
+        detected_i = is_echo & bad_ref
+        g_echo = reconstruct_echo(st.G, echo_ref & st.received, echo_k, echo_x)
+        g_tilde = jnp.where(is_raw, raw_wire,
+                            jnp.where(is_echo & ~bad_ref, g_echo,
+                                      jnp.zeros((d,), grads.dtype)))
+        g_tilde = channel.deliver(st.chan, i, g_tilde)
+        G = st.G.at[i].set(g_tilde)
+        received = st.received.at[i].set(mode != MSG_SILENT)
+        detected = st.detected.at[i].set(detected_i)
 
-    # --- All later workers overhear raw broadcasts (lines 26-31) ---------
-    indep = independent_from_projection(proj, st.rmask, raw_msg,
-                                        cfg.indep_tol)
-    overheard = ~faded & ok
-    add = is_raw & indep & overheard
-    R = jnp.where(add, st.R.at[i].set(raw_wire), st.R)
-    rmask = st.rmask.at[i].set(add | st.rmask[i])
+    with jax.named_scope(SLOT_OVERHEAR):
+        # --- All later workers overhear raw broadcasts (lines 26-31) ---------
+        indep = independent_from_projection(proj, st.rmask, raw_msg,
+                                            cfg.indep_tol)
+        overheard = ~faded & ok
+        add = is_raw & indep & overheard
+        R = jnp.where(add, st.R.at[i].set(raw_wire), st.R)
+        rmask = st.rmask.at[i].set(add | st.rmask[i])
 
     bits = st.bits.at[i].set(bits_i)
     echoed = st.echoed.at[i].set(is_echo)
@@ -213,88 +221,92 @@ def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     codec, channel = comm.codec, comm.channel
     mask_i = st.rmask[i]                    # sender's own reference view
 
-    # --- Worker i decides what to broadcast (lines 14-24) ----------------
-    raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
-    x_proj, proj = project_onto_span(st.R, mask_i, raw_msg, cfg.ridge)
-    dec = echo_decision_from_projection(x_proj, proj, mask_i, raw_msg,
-                                        cfg.r)
-    honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
-    mode = jnp.where(is_byz, plan.mode[i], honest_mode).astype(jnp.int32)
+    with jax.named_scope(SLOT_DECIDE):
+        # --- Worker i decides what to broadcast (lines 14-24) ----------------
+        raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
+        x_proj, proj = project_onto_span(st.R, mask_i, raw_msg, cfg.ridge)
+        dec = echo_decision_from_projection(x_proj, proj, mask_i, raw_msg,
+                                            cfg.r)
+        honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
+        mode = jnp.where(is_byz, plan.mode[i], honest_mode).astype(jnp.int32)
 
-    # --- Channel: per-slot fading + jamming -------------------------------
-    chan, faded = channel.fade(st.chan, i)
-    jammed = jnp.any(plan.jam & byz_mask) & ~is_byz
-    faded = faded | jammed
-    fellback = (mode == MSG_ECHO) & faded
-    mode = jnp.where(fellback, MSG_RAW, mode)
+        # --- Channel: per-slot fading + jamming ------------------------------
+        chan, faded = channel.fade(st.chan, i)
+        jammed = jnp.any(plan.jam & byz_mask) & ~is_byz
+        faded = faded | jammed
+        fellback = (mode == MSG_ECHO) & faded
+        mode = jnp.where(fellback, MSG_RAW, mode)
 
-    # --- Wire coding ------------------------------------------------------
-    echo_ref = jnp.where(is_byz, plan.echo_ref[i], mask_i)
-    echo_x = jnp.where(is_byz, plan.echo_x[i], dec.x)
-    ef_row = st.ef[i]
-    if codec.lossless:
-        raw_wire = raw_msg
-        echo_k = jnp.where(is_byz, plan.echo_k[i], dec.k)
-    else:
-        if use_ef:
-            compensated = raw_msg + ef_row
-            raw_wire = codec.roundtrip(compensated)
-            ef_row = compensated - raw_wire
+        # --- Wire coding -----------------------------------------------------
+        echo_ref = jnp.where(is_byz, plan.echo_ref[i], mask_i)
+        echo_x = jnp.where(is_byz, plan.echo_x[i], dec.x)
+        ef_row = st.ef[i]
+        if codec.lossless:
+            raw_wire = raw_msg
+            echo_k = jnp.where(is_byz, plan.echo_k[i], dec.k)
         else:
-            raw_wire = codec.roundtrip(raw_msg)
-        echo_x = codec.roundtrip(echo_x)
-        k_honest = wire_norm_ratio(st.R, mask_i, echo_x, raw_msg)
-        echo_k = codec.roundtrip(
-            jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
+            if use_ef:
+                compensated = raw_msg + ef_row
+                raw_wire = codec.roundtrip(compensated)
+                ef_row = compensated - raw_wire
+            else:
+                raw_wire = codec.roundtrip(raw_msg)
+            echo_x = codec.roundtrip(echo_x)
+            k_honest = wire_norm_ratio(st.R, mask_i, echo_x, raw_msg)
+            echo_k = codec.roundtrip(
+                jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
 
-    is_raw = mode == MSG_RAW
-    is_echo = mode == MSG_ECHO
+    with jax.named_scope(SLOT_SERVER):
+        is_raw = mode == MSG_RAW
+        is_echo = mode == MSG_ECHO
 
-    # --- Bit pricing + budget admission -----------------------------------
-    rank = jnp.sum(echo_ref & st.received)
-    raw_cost = jnp.float32(codec.raw_msg_bits(d))
-    echo_cost = jnp.asarray(codec.echo_msg_bits(n, rank)).astype(jnp.float32)
-    attempt = jnp.where(
-        is_echo, echo_cost,
-        jnp.where(is_raw,
-                  jnp.where(fellback, echo_cost + raw_cost, raw_cost),
-                  0.0))
-    attempt = channel.price(attempt)
-    chan, ok = channel.admit(chan, attempt)
-    mode = jnp.where(ok, mode, MSG_SILENT)
-    is_raw = is_raw & ok
-    is_echo = is_echo & ok
-    bits_i = jnp.where(ok, attempt, 0.0)
+        # --- Bit pricing + budget admission ----------------------------------
+        rank = jnp.sum(echo_ref & st.received)
+        raw_cost = jnp.float32(codec.raw_msg_bits(d))
+        echo_cost = jnp.asarray(codec.echo_msg_bits(n, rank)).astype(
+            jnp.float32)
+        attempt = jnp.where(
+            is_echo, echo_cost,
+            jnp.where(is_raw,
+                      jnp.where(fellback, echo_cost + raw_cost, raw_cost),
+                      0.0))
+        attempt = channel.price(attempt)
+        chan, ok = channel.admit(chan, attempt)
+        mode = jnp.where(ok, mode, MSG_SILENT)
+        is_raw = is_raw & ok
+        is_echo = is_echo & ok
+        bits_i = jnp.where(ok, attempt, 0.0)
 
-    # --- Server processes the message -------------------------------------
-    # Topology-aware detection: an echo referencing a worker the sender
-    # could not have heard (graph edge absent OR slot not received) is
-    # provably Byzantine. Honest masks are built from overheard slots
-    # within hearing range, so they never trip this.
-    bad_ref = jnp.any(echo_ref & (~st.received | ~hear[i]))
-    detected_i = is_echo & bad_ref
-    g_echo = reconstruct_echo(st.G, echo_ref & st.received, echo_k, echo_x)
-    g_tilde = jnp.where(is_raw, raw_wire,
-                        jnp.where(is_echo & ~bad_ref, g_echo,
-                                  jnp.zeros((d,), grads.dtype)))
-    g_tilde = channel.deliver(st.chan, i, g_tilde)
-    G = st.G.at[i].set(g_tilde)
-    received = st.received.at[i].set(mode != MSG_SILENT)
-    detected = st.detected.at[i].set(detected_i)
+        # --- Server processes the message ------------------------------------
+        # Topology-aware detection: an echo referencing a worker the sender
+        # could not have heard (graph edge absent OR slot not received) is
+        # provably Byzantine. Honest masks are built from overheard slots
+        # within hearing range, so they never trip this.
+        bad_ref = jnp.any(echo_ref & (~st.received | ~hear[i]))
+        detected_i = is_echo & bad_ref
+        g_echo = reconstruct_echo(st.G, echo_ref & st.received, echo_k, echo_x)
+        g_tilde = jnp.where(is_raw, raw_wire,
+                            jnp.where(is_echo & ~bad_ref, g_echo,
+                                      jnp.zeros((d,), grads.dtype)))
+        g_tilde = channel.deliver(st.chan, i, g_tilde)
+        G = st.G.at[i].set(g_tilde)
+        received = st.received.at[i].set(mode != MSG_SILENT)
+        detected = st.detected.at[i].set(detected_i)
 
-    # --- Overhearing, per receiver (lines 26-31 under the graph) ----------
-    # Each worker j that hears i runs ITS OWN independence test against
-    # its own mask. The shared R buffer stores the wire payload once
-    # (row = sender ID, identical for all receivers); membership is the
-    # per-worker business, so it lives entirely in rmask[:, i].
-    indep = jax.vmap(
-        lambda m: independent_from_projection(
-            project_onto_span(st.R, m, raw_msg, cfg.ridge)[1],
-            m, raw_msg, cfg.indep_tol))(st.rmask)          # (n,)
-    on_air = is_raw & ~faded & ok
-    add = on_air & indep & hear[:, i]       # hear[j, i]: j overhears i
-    R = jnp.where(on_air, st.R.at[i].set(raw_wire), st.R)
-    rmask = st.rmask.at[:, i].set(add | st.rmask[:, i])
+    with jax.named_scope(SLOT_OVERHEAR):
+        # --- Overhearing, per receiver (lines 26-31 under the graph) ---------
+        # Each worker j that hears i runs ITS OWN independence test against
+        # its own mask. The shared R buffer stores the wire payload once
+        # (row = sender ID, identical for all receivers); membership is the
+        # per-worker business, so it lives entirely in rmask[:, i].
+        indep = jax.vmap(
+            lambda m: independent_from_projection(
+                project_onto_span(st.R, m, raw_msg, cfg.ridge)[1],
+                m, raw_msg, cfg.indep_tol))(st.rmask)          # (n,)
+        on_air = is_raw & ~faded & ok
+        add = on_air & indep & hear[:, i]       # hear[j, i]: j overhears i
+        R = jnp.where(on_air, st.R.at[i].set(raw_wire), st.R)
+        rmask = st.rmask.at[:, i].set(add | st.rmask[:, i])
 
     bits = st.bits.at[i].set(bits_i)
     echoed = st.echoed.at[i].set(is_echo)
@@ -352,12 +364,17 @@ def communication_phase(
         ef=ef if ef is not None else jnp.zeros((n, d), grads.dtype),
     )
     if shared:
-        body = partial(_slot, cfg=cfg, grads=grads, byz_mask=byz_mask,
+        slot = partial(_slot, cfg=cfg, grads=grads, byz_mask=byz_mask,
                        plan=plan, comm=comm, use_ef=ef is not None)
     else:
-        body = partial(_slot_net, cfg=cfg, grads=grads, byz_mask=byz_mask,
+        slot = partial(_slot_net, cfg=cfg, grads=grads, byz_mask=byz_mask,
                        plan=plan, comm=comm, hear=net.matrix(),
                        use_ef=ef is not None)
+
+    def body(i, st):
+        with jax.named_scope(PROTOCOL_SLOT):
+            return slot(i, st)
+
     st = jax.lax.fori_loop(0, n, body, st)
 
     server = ServerState(G=st.G, received=st.received, detected=st.detected)
@@ -388,11 +405,17 @@ def aggregate(server: ServerState, f: int, aggregator: str = "cgc"
     must not drag the clip threshold to 0 at the n = f + 1 crash edge.
     Clean rounds take the untouched fused-kernel branch.
     """
-    G = jnp.where(server.received[:, None], server.G, 0.0)
-    if aggregator == "cgc":
-        bad = ~server.received | server.detected
-        return cgc_aggregate_known_bad(G, f, bad)
-    return agg_lib.AGGREGATORS[aggregator](G, f)
+    with jax.named_scope(PROTOCOL_AGGREGATE):
+        G = jnp.where(server.received[:, None], server.G, 0.0)
+        if aggregator == "cgc":
+            bad = ~server.received | server.detected
+            return cgc_aggregate_known_bad(G, f, bad)
+        return agg_lib.AGGREGATORS[aggregator](G, f)
+
+
+def _update(w: jax.Array, eta: float, g: jax.Array) -> jax.Array:
+    with jax.named_scope(PROTOCOL_UPDATE):
+        return w - eta * g
 
 
 @partial(jax.jit, static_argnames=("cfg", "aggregator", "comm", "net"))
@@ -424,13 +447,12 @@ def echo_cgc_round(
             cfg, grads, byz_mask, plan, comm=comm, chan_key=chan_key, ef=ef,
             net=net)
         g_agg = aggregate(server, cfg.f, aggregator)
-        return w - cfg.eta * g_agg, server, stats, ef_next
+        return _update(w, cfg.eta, g_agg), server, stats, ef_next
     server, stats = communication_phase(cfg, grads, byz_mask, plan,
                                         comm=comm, chan_key=chan_key,
                                         net=net)
     g_agg = aggregate(server, cfg.f, aggregator)
-    w_next = w - cfg.eta * g_agg
-    return w_next, server, stats
+    return _update(w, cfg.eta, g_agg), server, stats
 
 
 @partial(jax.jit, static_argnames=("cfg", "aggregator", "comm"))
@@ -451,12 +473,12 @@ def pointwise_round(
     """
     n, d = grads.shape
     codec = (comm if comm is not None else DEFAULT_COMM).codec
-    G = jnp.where(byz_mask[:, None], plan.raw, grads)
-    g_agg = (cgc_aggregate(G, cfg.f) if aggregator == "cgc"
-             else agg_lib.AGGREGATORS[aggregator](G, cfg.f))
-    w_next = w - cfg.eta * g_agg
+    with jax.named_scope(PROTOCOL_AGGREGATE):
+        G = jnp.where(byz_mask[:, None], plan.raw, grads)
+        g_agg = (cgc_aggregate(G, cfg.f) if aggregator == "cgc"
+                 else agg_lib.AGGREGATORS[aggregator](G, cfg.f))
     bits = jnp.float32(n * codec.raw_msg_bits(d))
-    return w_next, bits
+    return _update(w, cfg.eta, g_agg), bits
 
 
 def run_training(
@@ -518,18 +540,20 @@ def run_training(
 
     def one_round(carry, key_t):
         w, ef = carry
-        keys = jax.random.split(key_t, n + 1)
-        grads = jax.vmap(lambda k: cost.stoch_grad(k, w))(keys[:n])
-        true_grad = cost.grad(w)
-        # fold_in (not a wider split) keeps grads/attack draws
-        # bitwise-identical to the pre-channel code path.
-        chan_key = jax.random.fold_in(key_t, n + 1)
-        extra = {}
-        if "channel" in attack_extra:
-            extra["channel"] = comm.channel
-        if "chan_key" in attack_extra:
-            extra["chan_key"] = chan_key
-        plan = attack_fn(keys[n], grads, byz_mask, w, true_grad, **extra)
+        with jax.named_scope(PROTOCOL_GRADS):
+            keys = jax.random.split(key_t, n + 1)
+            grads = jax.vmap(lambda k: cost.stoch_grad(k, w))(keys[:n])
+            true_grad = cost.grad(w)
+            # fold_in (not a wider split) keeps grads/attack draws
+            # bitwise-identical to the pre-channel code path.
+            chan_key = jax.random.fold_in(key_t, n + 1)
+            extra = {}
+            if "channel" in attack_extra:
+                extra["channel"] = comm.channel
+            if "chan_key" in attack_extra:
+                extra["chan_key"] = chan_key
+            plan = attack_fn(keys[n], grads, byz_mask, w, true_grad,
+                             **extra)
         if use_radio:
             if use_ef:
                 w_next, server, stats, ef = echo_cgc_round(
@@ -561,16 +585,28 @@ def run_training(
     # host-side spans only: the per-slot loop is jitted/scanned, so the
     # observable unit is the whole simulated trajectory (trace + block)
     # plus the ledger fold-in; per-round bit events come from the ledger.
-    with obs.span("protocol.rounds"):
+    # Under an outer jit (w0 a tracer) this body runs once per trace:
+    # the span would time tracing and the counter count traces, so
+    # neither runs there.
+    eager = not isinstance(w0, jax.core.Tracer)
+    with _host_span("protocol.rounds", eager):
         (w_final, _), trace = jax.lax.scan(one_round, (w0, ef0), keys)
-        jax.block_until_ready(w_final)
-    obs.counter("protocol.rounds_simulated", rounds)
+        if eager:
+            jax.block_until_ready(w_final)
+    if eager:
+        obs.counter("protocol.rounds_simulated", rounds)
     trace["w_final"] = w_final
     if ledger is not None:
         d = w0.shape[-1]
         with obs.span("protocol.ledger"):
             ledger.record_protocol_trace(trace, n, d, comm.codec)
     return trace
+
+
+def _host_span(name: str, eager: bool):
+    """``obs.span(name)`` where the caller runs eagerly; nothing under a
+    trace, where a host span would time tracing."""
+    return obs.span(name) if eager else contextlib.nullcontext()
 
 
 def _attack_kwargs(attack_fn) -> frozenset:
@@ -674,7 +710,8 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
     trace = {k: [] for k in
              ("dist2", "value", "bits", "n_echo", "n_detected")}
     keys = jax.random.split(key, rounds)
-    with obs.span("protocol.rounds"):
+    eager = not isinstance(w0, jax.core.Tracer)
+    with _host_span("protocol.rounds", eager):
         for t in range(rounds):
             dec = policy.observe(last_obs)
             obs.counter("comm.policy.decisions")
@@ -736,7 +773,8 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
             trace["n_echo"].append(jnp.int32(n_echo))
             trace["n_detected"].append(stats.n_detected)
             w = w_next
-    obs.counter("protocol.rounds_simulated", rounds)
+    if eager:
+        obs.counter("protocol.rounds_simulated", rounds)
     out = {k: jnp.stack(v) for k, v in trace.items()}
     out["w_final"] = w
     out["codec_switches"] = switches

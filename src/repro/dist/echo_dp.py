@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cgc import cgc_scales
+from repro.obs.scopes import ECHO_BASIS_GRAM
 from .collectives import _gather_scalar, tree_norm, worker_index
 
 F32 = jnp.float32
@@ -80,11 +81,12 @@ def tree_vdot(a: Any, b: Any) -> jax.Array:
 def basis_gram(basis: Sequence[Any]) -> jax.Array:
     """(K, K) Gram matrix of the reference pytrees."""
     k = len(basis)
-    rows = []
-    for i in range(k):
-        rows.append(jnp.stack([tree_vdot(basis[i], basis[j])
-                               for j in range(k)]))
-    return jnp.stack(rows)
+    with jax.named_scope(ECHO_BASIS_GRAM):
+        rows = []
+        for i in range(k):
+            rows.append(jnp.stack([tree_vdot(basis[i], basis[j])
+                                   for j in range(k)]))
+        return jnp.stack(rows)
 
 
 def _ridged(gram: jax.Array) -> jax.Array:

@@ -52,6 +52,7 @@ from repro.dist import (AGG_FNS, ShardCtx, inject_byzantine, make_shard_ctx,
 from repro.dist.echo_dp import (basis_gram, echo_dp_aggregate, init_basis,
                                 roll_basis, round_comm_bits)
 from repro.models import model as M
+from repro.obs.scopes import TRAIN_AGGREGATE, TRAIN_GRADS, TRAIN_OPTIMIZER
 from repro.optim import Optimizer, clip_by_global_norm
 
 F32 = jnp.float32
@@ -250,24 +251,28 @@ class _StrategyBase:
         ret_agg = self.needs_basis or settings.return_aggregate
 
         def worker_fn(values, opt_state, batch, step, *extra):
-            loss, metrics, grads = microbatched_grads(
-                loss_fn, values, batch, settings.microbatches)
+            with jax.named_scope(TRAIN_GRADS):
+                loss, metrics, grads = microbatched_grads(
+                    loss_fn, values, batch, settings.microbatches)
             if settings.n_byz and data_axes:
                 from repro.dist.collectives import worker_index
                 grads = inject_byzantine(grads, worker_index(data_axes),
                                          settings.n_byz, settings.byz_mode)
-            agg, diags = self.aggregate(env, grads, settings, data_axes,
-                                        extra)
-            if data_axes:
-                loss = jax.lax.pmean(loss, data_axes)
-                metrics = jax.tree.map(
-                    lambda m: jax.lax.pmean(m, data_axes), metrics)
-            if settings.clip_norm:
-                agg, gnorm = self.clip(env, agg, settings, data_axes)
-                diags = dict(diags, grad_global_norm=gnorm)
-            updates, opt_state = opt.update(agg, opt_state, values, step)
-            values = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                  values, updates)
+            with jax.named_scope(TRAIN_AGGREGATE):
+                agg, diags = self.aggregate(env, grads, settings,
+                                            data_axes, extra)
+                if data_axes:
+                    loss = jax.lax.pmean(loss, data_axes)
+                    metrics = jax.tree.map(
+                        lambda m: jax.lax.pmean(m, data_axes), metrics)
+                if settings.clip_norm:
+                    agg, gnorm = self.clip(env, agg, settings, data_axes)
+                    diags = dict(diags, grad_global_norm=gnorm)
+            with jax.named_scope(TRAIN_OPTIMIZER):
+                updates, opt_state = opt.update(agg, opt_state, values,
+                                                step)
+                values = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                      values, updates)
             metrics = dict(metrics, loss=loss, **diags)
             if ret_agg:
                 return values, opt_state, metrics, agg
@@ -611,9 +616,9 @@ class TrainerConfig:
     # the paper's reference set R holds overheard RAW gradients; echo
     # aggregates lie in span(basis) and add no information) or "always".
     roll_policy: str = "raw"
-    # jax.profiler trace window over the first ``profile_steps`` rounds
-    # of fit() (0 = off), written to ``profile_dir``. A profiler failure
-    # fails the run.
+    # jax.profiler trace window over ``profile_steps`` rounds of fit()
+    # from its second round on, after the first round has compiled (0 =
+    # off), written to ``profile_dir``. A profiler failure fails the run.
     profile_steps: int = 0
     profile_dir: Optional[str] = None
 
@@ -972,10 +977,13 @@ class Trainer:
                   ) -> Tuple[TrainState, Dict[str, Any]]:
         """One driver round; returns (new_state, metrics record).
 
-        The round is a ``train.round`` span with the optimistic /
-        fallback / plain step as child spans, and fires
-        ``hooks.on_round_start/end`` around it — host-side only, so
-        the jitted computation (and the trajectory) is untouched.
+        The round is a ``train.round`` span whose children are the
+        dispatch of the optimistic / fallback / plain step (each ends
+        when the dispatch returns), every host wait on the device
+        (``sync``) and the bookkeeping after it (``record``); each span is
+        a profiler annotation too. It fires ``hooks.on_round_start/end``
+        around the round — host-side only, so the jitted computation (and
+        the trajectory) is untouched.
         """
         self.hooks.on_round_start(state.step)
         with obs.span("train.round"):
@@ -1023,6 +1031,7 @@ class Trainer:
                                           state.opt_state,
                                           batch, step_arr,
                                           extras)
+                with obs.span("sync"):
                     all_echo = bool(m["all_echo"])
             echoed = attempted and all_echo and drops == 0
             if echoed:
@@ -1061,38 +1070,52 @@ class Trainer:
             bits = raw_round
             new_state = TrainState(v, o, state.step + 1, None)
 
-        loss = float(m["loss"])
-        if self._first_loss is None:
-            self._first_loss = loss
-        self._last_loss = loss
-        led = self.ledger.record_round(bits=bits, baseline=raw_round,
-                                       echoed=echoed)
-        record.update(loss=loss, **led)
-        for k in ("echo_frac", "grad_global_norm", "cgc_threshold",
-                  "cgc_clipped_frac", "ef_residual_norm"):
-            if k in m:
-                record[k] = float(m[k])
-        if self._policy_dynamic:
-            record["codec"] = codec.name
-            record["echo_r"] = echo_r
-        if self.policy is not None:
-            self._observe_round(state, codec, echo_r, bits, raw_round,
-                                loss, echoed, attempted, drops, led)
-        self.sink.emit(record)
+        # each host wait on the device is a span of its own: the
+        # dispatch spans above end when the dispatch returns
+        with obs.span("sync"):
+            loss = float(m["loss"])
+        with obs.span("record"):
+            if self._first_loss is None:
+                self._first_loss = loss
+            self._last_loss = loss
+            led = self.ledger.record_round(bits=bits, baseline=raw_round,
+                                           echoed=echoed)
+            record.update(loss=loss, **led)
+            for k in ("echo_frac", "grad_global_norm", "cgc_threshold",
+                      "cgc_clipped_frac", "ef_residual_norm"):
+                if k in m:
+                    record[k] = float(m[k])
+            if self._policy_dynamic:
+                record["codec"] = codec.name
+                record["echo_r"] = echo_r
+            if self.policy is not None:
+                self._observe_round(state, codec, echo_r, bits, raw_round,
+                                    loss, echoed, attempted, drops, led)
+            self.sink.emit(record)
         return new_state, record
 
-    def _profiler_window(self, steps_done: int):
-        """Start the jax.profiler trace around the first
-        ``profile_steps`` rounds of this fit(). A profile that was asked
-        for and cannot be taken fails the run: a run that silently
-        carries on unprofiled would look like one that was measured."""
+    def _profile_rounds(self, first: int, steps: int):
+        """The rounds ``[start, stop)`` of a fit() from round ``first`` to
+        ``steps`` that the profiler traces: ``profile_steps`` of them from
+        the fit's second round on, so that the first round's compilation
+        stays out of the trace. None when no profile is asked for. A
+        profile that was asked for and cannot be taken fails the run: a
+        run that silently carries on unprofiled would look like one that
+        was measured."""
         cfg = self.config
         if not cfg.profile_steps or not cfg.profile_dir:
-            return None, 0
-        jax.profiler.start_trace(cfg.profile_dir)
-        obs.event("train.profile_start", dir=cfg.profile_dir,
-                  steps=cfg.profile_steps)
-        return True, steps_done + cfg.profile_steps
+            return None
+        if steps - first < 2:
+            raise ValueError(
+                f"profile_steps={cfg.profile_steps} needs a fit of at least "
+                f"2 rounds (the first compiles and is not profiled); this "
+                f"one runs {max(steps - first, 0)}")
+        return first + 1, first + 1 + cfg.profile_steps
+
+    def _profiler_start(self) -> None:
+        jax.profiler.start_trace(self.config.profile_dir)
+        obs.event("train.profile_start", dir=self.config.profile_dir,
+                  steps=self.config.profile_steps)
 
     def _profiler_stop(self) -> None:
         jax.profiler.stop_trace()
@@ -1104,22 +1127,26 @@ class Trainer:
         a resumed state continues from its checkpointed step)."""
         cfg = self.config
         t0 = time.time()
-        profiling, profile_until = self._profiler_window(state.step)
+        window = self._profile_rounds(state.step, steps)
+        profiling = False
         try:
             while state.step < steps:
+                if window and state.step == window[0]:
+                    self._profiler_start()
+                    profiling = True
                 with obs.span("train.data"):
                     batch = next(batches)
                 state, _ = self.run_round(state, batch)
-                if profiling and state.step >= profile_until:
+                if profiling and state.step >= window[1]:
                     self._profiler_stop()
-                    profiling = None
+                    profiling = False
                 if cfg.ckpt_dir and cfg.ckpt_every \
                         and state.step % cfg.ckpt_every == 0 \
                         and state.step < steps:
                     with obs.span("train.checkpoint"):
                         self.save(state, wait=False)  # off the driver
         finally:
-            if profiling:        # steps < profile window (or a crash)
+            if profiling:        # fit ended inside the window (or crashed)
                 self._profiler_stop()
         if cfg.ckpt_dir:
             # the final snapshot is synchronous: fit() returning means it

@@ -3,13 +3,13 @@
 One measurement plane for the whole repo: trackers (pluggable sinks in
 the ``TRACKERS`` registry) receive structured events, counters, and
 nested wall-clock spans from instrumented code, which only ever calls
-the free functions here (``span``/``counter``/``event``/``metric``)
+the free functions here (``span``/``counter``/``event``)
 against the process-active tracker. Engines fire :class:`Hooks` at
 round/request lifecycle moments; ``report`` renders any finished run
 dir's ``summary.json``.
 """
-from .context import (counter, event, get_tracker, metric, set_tracker,
-                      span, tracing, use_tracker)
+from .context import (counter, event, get_tracker, set_tracker, span,
+                      tracing, use_tracker)
 from .hooks import HookList, Hooks, TrackerHook, as_hooks
 from .report import load_run, render, report
 from .tracker import (InMemoryTracker, JsonlTracker, RecordingTracker,
@@ -32,7 +32,6 @@ __all__ = [
     "get_tracker",
     "load_run",
     "make_tracker",
-    "metric",
     "render",
     "report",
     "set_tracker",

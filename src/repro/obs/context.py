@@ -4,22 +4,24 @@ Instrumentation sites (``Trainer.run_round``, the serve step loop, the
 ledger, kernel dispatch, the checkpoint writer thread) never hold a
 tracker reference — they call :func:`span` / :func:`counter` /
 :func:`event` here, which dispatch to whatever tracker is currently
-installed. The default is the shared noop tracker, so un-instrumented
-runs (and all pre-existing call sites) pay one attribute check per
-call and allocate nothing.
+installed. The default is the shared noop tracker: counters and events
+cost one attribute check, and a span allocates nothing unless a
+profiler trace is running, when it opens an annotation named by its
+path (``repro.obs.tracker._Span``), so a trace names the program's
+sections whatever tracker is installed.
 
 The active tracker is process-global rather than thread-local on
 purpose: background threads (checkpoint writer, metrics writer) must
 land their spans in the same breakdown as the driver loop. Span
-*nesting* stays thread-local inside each tracker, so cross-thread
-spans never corrupt each other's paths.
+*nesting* stays thread-local, so cross-thread spans never corrupt each
+other's paths.
 """
 from __future__ import annotations
 
 from contextlib import contextmanager
 from typing import Any
 
-from .tracker import _NOOP_SPAN, Tracker
+from .tracker import Tracker
 
 _NOOP = Tracker()
 _ACTIVE: Tracker = _NOOP
@@ -48,22 +50,15 @@ def use_tracker(tracker: "Tracker | None"):
 
 
 def span(name: str):
-    """Wall-clock timed section under the active tracker. Nesting
-    slash-joins the names: ``with span("serve.step"): with
-    span("prefill")`` records the path ``serve.step/prefill``."""
-    if not _ACTIVE.enabled:
-        return _NOOP_SPAN
+    """Timed section under the active tracker, and a profiler annotation
+    under any. Nesting slash-joins the names: ``with span("serve.step"):
+    with span("prefill")`` records and annotates ``serve.step/prefill``."""
     return _ACTIVE.span(name)
 
 
 def counter(name: str, n: int = 1) -> None:
     if _ACTIVE.enabled:
         _ACTIVE.counter(name, n)
-
-
-def metric(name: str, value: float) -> None:
-    if _ACTIVE.enabled:
-        _ACTIVE.metric(name, value)
 
 
 def event(kind: str, **fields: Any) -> None:

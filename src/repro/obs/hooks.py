@@ -33,10 +33,6 @@ class Hooks:
     def on_finish(self, req: Any) -> None:
         pass
 
-    # --- generic per-step ------------------------------------------------
-    def on_step(self, record: Mapping[str, Any]) -> None:
-        pass
-
 
 class HookList(Hooks):
     """Fans every callback out to a list of hooks, in order."""
@@ -63,10 +59,6 @@ class HookList(Hooks):
     def on_finish(self, req: Any) -> None:
         for h in self.hooks:
             h.on_finish(req)
-
-    def on_step(self, record: Mapping[str, Any]) -> None:
-        for h in self.hooks:
-            h.on_step(record)
 
 
 # Round-record fields worth echoing into the event stream; the full

@@ -1,7 +1,7 @@
 """``python -m repro report <run_dir>`` — render a finished run.
 
 Reads the ``summary.json`` the facades drop at the end of every run
-(``{"kind", "summary", "obs": {counters, metrics, spans}}``) plus the
+(``{"kind", "summary", "obs": {counters, spans}}``) plus the
 run dir's ``config.json``, and prints a human-readable digest:
 throughput, echo rate, bits-vs-baseline, and the per-subsystem span
 breakdown. Stdlib-only so reporting never imports jax.
@@ -242,11 +242,6 @@ def render(data: Dict[str, Any], run_dir: str = "") -> str:
         lines.append("-- counters --")
         cw = max(len(k) for k in counters)
         lines += [f"  {k:<{cw}}  {counters[k]}" for k in sorted(counters)]
-    metrics = obs.get("metrics") or {}
-    if metrics:
-        lines.append("-- metrics --")
-        mw = max(len(k) for k in metrics)
-        lines += [f"  {k:<{mw}}  {metrics[k]:.6g}" for k in sorted(metrics)]
     return "\n".join(lines)
 
 

@@ -6,15 +6,17 @@ A tracker receives three kinds of signals from instrumented code:
   one ``comm.round`` event per protocol round with its bit accounting;
 * **counters** — monotonically increasing named integers, e.g. per
   kernel-backend dispatch counts;
-* **spans** — wall-clock timed sections with thread-local nesting;
-  nested spans produce slash-joined paths (``serve.step/prefill``), and
-  every tracker keeps a per-path ``{count, total_s}`` aggregate that
-  becomes the per-subsystem timing breakdown in ``summary.json``.
+* **spans** — timed sections with thread-local nesting; nested spans
+  produce slash-joined paths (``serve.step/prefill``). Every span is a
+  ``jax.profiler.TraceAnnotation`` named by its path, under any tracker,
+  so a profiler trace shows it beside the device ops; a recording
+  tracker also keeps a per-path ``{count, total_s}`` wall-clock
+  aggregate that becomes the per-subsystem breakdown in ``summary.json``.
 
 Sinks live in the ``TRACKERS`` registry: ``noop`` (the default-off
-tracker — shared singleton spans, near-zero overhead), ``memory``
-(tests), ``jsonl`` (one JSON line per event/span via AsyncLineWriter),
-``stdout``. Code under instrumentation never talks to a sink class
+tracker — spans annotate a running profiler trace and record nothing),
+``memory`` (tests), ``jsonl`` (one JSON line per event/span via
+AsyncLineWriter), ``stdout``. Code under instrumentation never talks to a sink class
 directly — it calls the free functions in :mod:`repro.obs.context`,
 which dispatch to the active tracker (or to nothing).
 """
@@ -29,9 +31,33 @@ from ..run.registry import TRACKERS
 from .writer import AsyncLineWriter
 
 
+# Open span paths of this thread, innermost last: nesting is thread-local
+# and shared by every tracker, so the profiler annotation carries the same
+# slash path whichever tracker (the noop one included) is installed.
+_OPEN = threading.local()
+_annotation: Optional[Callable[[str], Any]] = None
+
+
+def _open_paths() -> List[str]:
+    stack = getattr(_OPEN, "paths", None)
+    if stack is None:
+        stack = _OPEN.paths = []
+    return stack
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    importing this package (``repro report``) never imports jax."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class _NoopSpan:
-    """Shared do-nothing context manager: the disabled-tracker hot path
-    allocates nothing per call."""
+    """Shared do-nothing context manager: a noop-tracker span while no
+    profiler trace is running allocates nothing."""
 
     __slots__ = ()
 
@@ -57,14 +83,13 @@ class Tracker:
     def counter(self, name: str, n: int = 1) -> None:
         pass
 
-    def metric(self, name: str, value: float) -> None:
-        pass
-
     def span(self, name: str):
-        return _NOOP_SPAN
+        if not _annotation_type().is_enabled():
+            return _NOOP_SPAN
+        return _Span(None, name)
 
     def snapshot(self) -> Dict[str, Any]:
-        return {"counters": {}, "metrics": {}, "spans": {}}
+        return {"counters": {}, "spans": {}}
 
     def flush(self) -> None:
         pass
@@ -74,36 +99,46 @@ class Tracker:
 
 
 class _Span:
-    """One live timed section; re-entrant per tracker via the
-    thread-local span stack (nesting = slash-joined path)."""
+    """One live section: a ``jax.profiler.TraceAnnotation`` named by the
+    span's slash path, so that it sits on the profiler's clock beside the
+    device ops, and, when ``tracker`` records, a wall-clock time folded
+    into its per-path breakdown. Under the noop tracker it times nothing,
+    and exists only while a profiler trace runs."""
 
-    __slots__ = ("tracker", "name", "path", "t0")
+    __slots__ = ("tracker", "name", "path", "t0", "annotation")
 
-    def __init__(self, tracker: "RecordingTracker", name: str):
+    def __init__(self, tracker: "Optional[RecordingTracker]", name: str):
         self.tracker = tracker
         self.name = name
         self.path = name
         self.t0 = 0.0
+        self.annotation = None
 
     def __enter__(self):
-        stack = self.tracker._stack()
+        stack = _open_paths()
         if stack:
             self.path = stack[-1] + "/" + self.name
         stack.append(self.path)
-        self.t0 = time.perf_counter()
+        self.annotation = _annotation_type()(self.path)
+        self.annotation.__enter__()
+        if self.tracker is not None:
+            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
-        stack = self.tracker._stack()
+        if self.tracker is not None:
+            dt = time.perf_counter() - self.t0
+        self.annotation.__exit__(None, None, None)
+        stack = _open_paths()
         if stack and stack[-1] == self.path:
             stack.pop()
-        self.tracker._record_span(self.path, dt)
+        if self.tracker is not None:
+            self.tracker._record_span(self.path, dt)
         return False
 
 
 class RecordingTracker(Tracker):
-    """Shared aggregation machinery: counter/metric/span bookkeeping is
+    """Shared aggregation machinery: counter/span bookkeeping is
     identical across sinks; subclasses only decide where each record
     line goes via ``_emit``."""
 
@@ -111,17 +146,9 @@ class RecordingTracker(Tracker):
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._local = threading.local()
         self.counters: Dict[str, int] = {}
-        self.metrics: Dict[str, float] = {}
         # span path -> [count, total seconds]
         self._spans: Dict[str, List[float]] = {}
-
-    def _stack(self) -> List[str]:
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
-            stack = self._local.stack = []
-        return stack
 
     def _emit(self, rec: Dict[str, Any]) -> None:
         pass
@@ -132,10 +159,6 @@ class RecordingTracker(Tracker):
     def counter(self, name: str, n: int = 1) -> None:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
-
-    def metric(self, name: str, value: float) -> None:
-        with self._lock:
-            self.metrics[name] = float(value)
 
     def span(self, name: str):
         return _Span(self, name)
@@ -153,7 +176,6 @@ class RecordingTracker(Tracker):
         with self._lock:
             return {
                 "counters": dict(self.counters),
-                "metrics": dict(self.metrics),
                 "spans": {
                     path: {"count": int(c), "total_s": t}
                     for path, (c, t) in sorted(self._spans.items())

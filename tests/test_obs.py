@@ -139,12 +139,16 @@ def test_tracker_registry_and_make_tracker(tmp_path, capsys):
     assert t.snapshot()["counters"] == {"hits": 3}
 
 
-def test_context_noop_until_tracker_set():
+def test_context_noop_until_tracker_set(tmp_path):
+    import jax
+
     assert not obs.tracing()
-    assert obs.span("x") is obs.tracker._NOOP_SPAN
+    assert obs.span("x") is obs.tracker._NOOP_SPAN   # no trace: free
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("x") as sp:              # annotates, records nothing
+            assert sp.tracker is None and sp.path == "x"
     obs.counter("x")                           # all silently dropped
     obs.event("x", a=1)
-    obs.metric("x", 1.0)
     t = obs.InMemoryTracker()
     with obs.use_tracker(t):
         assert obs.tracing()
@@ -191,6 +195,162 @@ def test_span_nesting_is_thread_local():
     assert set(t.snapshot()["spans"]) == {"main.outer", "worker.write"}
 
 
+def test_noop_span_is_cheap():
+    """Under the noop tracker, with no profiler trace running, a span
+    costs a check of the profiler's state: well under a microsecond."""
+    import timeit
+
+    def once():
+        with obs.span("train.round"):
+            with obs.span("sync"):
+                pass
+
+    once()
+    per_span = min(timeit.repeat(once, number=2000, repeat=5)) / 4000
+    assert per_span < 10e-6
+
+
+def _host_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.end_ns)
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events]
+
+
+def _tiny_trainer(**settings):
+    import jax.numpy as jnp
+
+    from repro.launch.engine import (ReplicatedStrategy, Trainer,
+                                     TrainerConfig, TrainSettings)
+    from repro.optim import sgd
+
+    def loss(values, batch):
+        w = values["w"]
+        return (0.5 * jnp.sum((w - 1.0) ** 2)
+                + w @ jnp.mean(batch["eps"], 0)), {}
+
+    tr = Trainer(ReplicatedStrategy(loss_fn=loss), None, sgd(0.1),
+                 TrainSettings(aggregator="mean", **settings), None, 4,
+                 TrainerConfig(), printer=lambda s: None)
+    state = tr.init_state({"w": jnp.zeros(16)})
+    return tr, state, {"eps": jnp.ones((4, 16))}
+
+
+def test_round_spans_are_profiler_annotations(tmp_path):
+    """With the noop tracker a Trainer round still puts its spans on the
+    profiler's clock, named by slash path and nested as in the code: the
+    dispatch, the wait on the device, the bookkeeping."""
+    import jax
+
+    tr, state, batch = _tiny_trainer()
+    state, _ = tr.run_round(state, batch)      # compiles outside the trace
+    assert not obs.tracing()
+    with jax.profiler.trace(str(tmp_path)):
+        state, _ = tr.run_round(state, batch)
+    got = {n: (s, e) for n, s, e in _host_events(str(tmp_path))
+           if n.startswith("train.")}
+    assert set(got) == {"train.round", "train.round/step",
+                        "train.round/sync", "train.round/record"}
+    lo, hi = got["train.round"]
+    order = [got[f"train.round/{c}"] for c in ("step", "sync", "record")]
+    assert lo <= order[0][0] and order[-1][1] <= hi
+    assert all(a[1] <= b[0] for a, b in zip(order, order[1:]))
+
+
+def test_scopes_reach_op_names():
+    """Each device scope lands in the op_name metadata of the lowered
+    protocol round, run_training's scan, the Trainer step and the echo
+    basis Gram: what a device trace reports as each op's tf_op."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import byzantine, costfns
+    from repro.core.protocol import echo_cgc_round, run_training
+    from repro.core.types import ProtocolConfig
+    from repro.dist.echo_dp import basis_gram
+    from repro.obs import scopes as sc
+
+    def op_names(lowered):
+        return set(re.findall(r'op_name="([^"]*)"',
+                              lowered.as_text(dialect="hlo",
+                                              debug_info=True)))
+
+    def has(names, scope):
+        return any(f"/{scope}/" in f"/{n}/" for n in names)
+
+    n, d = 5, 32
+    cfg = ProtocolConfig(n=n, f=1, r=0.5, eta=0.1)
+    key = jax.random.PRNGKey(0)
+    grads = jax.random.normal(key, (n, d))
+    byz = jnp.arange(n) < 1
+    plan = byzantine.ATTACKS["sign_flip"](key, grads, byz, jnp.zeros(d),
+                                          grads.mean(0))
+    names = op_names(echo_cgc_round.lower(cfg, jnp.zeros(d), grads, byz,
+                                          plan))
+    for scope in (f"{sc.PROTOCOL_SLOT}/{sc.SLOT_DECIDE}/{sc.ECHO_PROJECT}",
+                  f"{sc.PROTOCOL_SLOT}/{sc.SLOT_SERVER}",
+                  f"{sc.PROTOCOL_SLOT}/{sc.SLOT_OVERHEAR}",
+                  sc.PROTOCOL_AGGREGATE, sc.PROTOCOL_UPDATE):
+        assert has(names, scope), scope
+
+    cost = costfns.quadratic(key, d=d, mu=0.5, L=1.0, sigma=0.1)
+    names = op_names(jax.jit(lambda k, w: run_training(
+        cfg, cost, byzantine.ATTACKS["sign_flip"], byz, k, w,
+        rounds=2)["w_final"]).lower(key, jnp.ones(d)))
+    assert has(names, sc.PROTOCOL_GRADS)
+    assert has(names, f"{sc.PROTOCOL_SLOT}/{sc.SLOT_SERVER}")
+
+    tr, state, batch = _tiny_trainer(clip_norm=1.0)
+    names = op_names(tr.step_fn.lower(state.values, state.opt_state, batch,
+                                      jnp.asarray(0)))
+    for scope in (sc.TRAIN_GRADS, sc.TRAIN_AGGREGATE, sc.TRAIN_OPTIMIZER):
+        assert has(names, scope), scope
+
+    basis = [{"w": jnp.ones(8) * i} for i in range(3)]
+    assert has(op_names(jax.jit(basis_gram).lower(basis)),
+               sc.ECHO_BASIS_GRAM)
+
+
+def test_jitted_run_training_counts_no_rounds():
+    """Under an outer jit run_training's body runs once per trace: its
+    protocol.rounds span and rounds counter run only on the eager call,
+    so they neither time tracing nor count traces."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import byzantine, costfns
+    from repro.core.protocol import run_training
+    from repro.core.types import ProtocolConfig
+
+    n, d = 4, 16
+    cfg = ProtocolConfig(n=n, f=1, r=0.5, eta=0.1)
+    key = jax.random.PRNGKey(1)
+    cost = costfns.quadratic(key, d=d, mu=0.5, L=1.0, sigma=0.1)
+    byz = jnp.arange(n) < 1
+
+    def run(k, w0):
+        return run_training(cfg, cost, byzantine.ATTACKS["sign_flip"], byz,
+                            k, w0, rounds=2)
+
+    t = obs.InMemoryTracker()
+    with obs.use_tracker(t):
+        jitted = jax.jit(run)(key, jnp.ones(d))
+        assert "protocol.rounds_simulated" not in t.counters
+        assert "protocol.rounds" not in t.snapshot()["spans"]
+        eager = run(key, jnp.ones(d))
+    assert t.counters["protocol.rounds_simulated"] == 2
+    assert t.snapshot()["spans"]["protocol.rounds"]["count"] == 1
+    assert jnp.allclose(jitted["w_final"], eager["w_final"])
+
+
 # ---------------------------------------------------------------------------
 # Facade runs: summary.json + span/counter totals
 # ---------------------------------------------------------------------------
@@ -223,7 +383,8 @@ def test_train_run_writes_summary_with_span_breakdown(tmp_path):
     snap = data["obs"]
     assert snap["counters"]["train.rounds"] == 3
     assert snap["spans"]["train.round"]["count"] == 3
-    assert snap["spans"]["train.round/step"]["count"] == 3
+    for child in ("step", "sync", "record"):
+        assert snap["spans"][f"train.round/{child}"]["count"] == 3
     assert snap["spans"]["train.data"]["count"] >= 3
 
 
@@ -302,9 +463,10 @@ _GOLDEN_SUMMARY = {
                 "bits_sent": 1000.0, "bits_baseline": 4000.0,
                 "bits_saving": 0.75},
     "obs": {"counters": {"train.rounds": 2, "comm.rounds": 2},
-            "metrics": {"obs_overhead": 0.0125},
             "spans": {"train.round": {"count": 2, "total_s": 3.0},
-                      "train.round/step": {"count": 2, "total_s": 2.0},
+                      "train.round/step": {"count": 2, "total_s": 1.0},
+                      "train.round/sync": {"count": 2, "total_s": 1.5},
+                      "train.round/record": {"count": 2, "total_s": 0.5},
                       "train.data": {"count": 2, "total_s": 1.0}}},
 }
 
@@ -318,12 +480,12 @@ _GOLDEN_TEXT = """\
 -- span breakdown (share of root spans) --
   train.data    25.0%  total     1.00s  n=2      mean 500.00ms
   train.round   75.0%  total     3.00s  n=2      mean 1.50s
-    step        50.0%  total     2.00s  n=2      mean 1.00s
+    record      12.5%  total  500.00ms  n=2      mean 250.00ms
+    step        25.0%  total     1.00s  n=2      mean 500.00ms
+    sync        37.5%  total     1.50s  n=2      mean 750.00ms
 -- counters --
   comm.rounds   2
-  train.rounds  2
--- metrics --
-  obs_overhead  0.0125"""
+  train.rounds  2"""
 
 
 def _golden_run_dir(tmp_path):

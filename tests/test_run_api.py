@@ -99,21 +99,49 @@ def test_cpu_fallback_is_an_error(monkeypatch, tmp_path):
                                runs_root=str(tmp_path)))
 
 
+def _profiled(cfg, steps):
+    import dataclasses
+    return dataclasses.replace(
+        cfg, train=dataclasses.replace(cfg.train, profile_steps=steps))
+
+
 def test_profile_that_cannot_be_taken_fails_the_run(tmp_path):
     """A run that asked for a profile either writes one or fails: here
-    another trace already holds the profiler."""
-    import dataclasses
+    another trace already holds the profiler. The window opens after the
+    first round, so that round (and its compilation) ran first; a fit too
+    short to hold a profiled round fails before it starts."""
+    import glob
 
     import jax
-    cfg = _quad_cfg(tmp_path, steps=2)
-    cfg = dataclasses.replace(
-        cfg, train=dataclasses.replace(cfg.train, profile_steps=1))
+    cfg = _profiled(_quad_cfg(tmp_path, steps=2), 1)
     jax.profiler.start_trace(str(tmp_path / "outer"))
     try:
         with pytest.raises(RuntimeError, match="already been started"):
             facade.train(cfg)
     finally:
         jax.profiler.stop_trace()
+    (metrics,) = glob.glob(str(tmp_path / "runs" / "*" / "metrics.jsonl"))
+    assert [json.loads(l)["step"] for l in
+            open(metrics).read().splitlines()] == [0]
+    with pytest.raises(ValueError, match="at least 2 rounds"):
+        facade.train(_profiled(_quad_cfg(tmp_path, steps=1), 1))
+
+
+def test_profile_window_opens_after_the_first_round(tmp_path):
+    """train.profile_steps=N traces rounds 2..N+1 of fit(): the first
+    round compiles outside the window."""
+    import glob
+
+    result = facade.train(_profiled(_quad_cfg(tmp_path, steps=4), 2))
+    recs = [json.loads(l) for l in
+            open(os.path.join(result.run_dir, "events.jsonl"))]
+    marks = [r["kind"] if r["kind"] != "span" else r["path"] for r in recs
+             if r["kind"] in ("train.profile_start", "train.profile_stop")
+             or r.get("path") == "train.round"]
+    assert marks == ["train.round", "train.profile_start", "train.round",
+                     "train.round", "train.profile_stop", "train.round"]
+    assert glob.glob(os.path.join(result.run_dir, "profile", "**",
+                                  "*.xplane.pb"), recursive=True)
 
 
 @pytest.mark.parametrize("platforms,forced", [("cpu", True),
