@@ -23,7 +23,7 @@ magnitude while keeping the echo direction).
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,23 +55,68 @@ def masked_gram(R: jax.Array, mask: jax.Array, ridge: float) -> jax.Array:
     return G
 
 
+def row_buckets(n: int) -> Tuple[int, ...]:
+    """Static row counts a bounded projection may read: multiples of 8
+    (the fp32 sublane tile) that double, 8, 16, 32, ..., capped at n."""
+    out, b = [], 8
+    while b < n:
+        out.append(b)
+        b *= 2
+    return tuple(out) + (n,)
+
+
+def _bucket_index(n_live: jax.Array, buckets: Tuple[int, ...]) -> jax.Array:
+    """Index of the smallest bucket holding ``n_live`` rows."""
+    return jnp.sum(jnp.asarray(buckets[:-1]) < n_live).astype(jnp.int32)
+
+
+def rows_read(n_live: jax.Array, n: int) -> jax.Array:
+    """Rows a projection bounded by ``n_live`` reads from an (n, d) table."""
+    buckets = row_buckets(n)
+    return jnp.asarray(buckets, jnp.int32)[_bucket_index(n_live, buckets)]
+
+
+def _project(R: jax.Array, mask: jax.Array, g: jax.Array, ridge: float
+             ) -> Tuple[jax.Array, jax.Array]:
+    Rm = R * mask[:, None]
+    b = Rm @ g                                       # A^T g, (n,)
+    G = masked_gram(R, mask, ridge)
+    x = jnp.linalg.solve(G, b)
+    x = x * mask
+    echo = x @ Rm                                    # A x, (d,)
+    return x, echo
+
+
 def project_onto_span(
-    R: jax.Array, mask: jax.Array, g: jax.Array, ridge: float = 1e-8
+    R: jax.Array, mask: jax.Array, g: jax.Array, ridge: float = 1e-8,
+    n_live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Least-squares coefficients x and projection A x of g onto span(R[mask]).
 
     Equivalent to the paper's x = (A^T A)^{-1} A^T g with A the masked columns
     (we store gradients as rows, so A = R[mask].T). Returns (x, echo) with
     x zero outside the mask.
+
+    ``n_live`` (traced) promises that no row at or past it is in the mask.
+    The projection then reads only the smallest bucket of
+    :func:`row_buckets` that holds ``n_live`` rows, chosen on the device,
+    and pads x back to n: the same mathematics on the rows that can be
+    live, so a table that is mostly empty is not read whole.
     """
     with jax.named_scope(ECHO_PROJECT):
-        Rm = R * mask[:, None]
-        b = Rm @ g                                   # A^T g, (n,)
-        G = masked_gram(R, mask, ridge)
-        x = jnp.linalg.solve(G, b)
-        x = x * mask
-        echo = x @ Rm                                # A x, (d,)
-    return x, echo
+        if n_live is None:
+            return _project(R, mask, g, ridge)
+        n = R.shape[0]
+        buckets = row_buckets(n)
+
+        def branch(b):
+            def run(R, mask, g):
+                x, echo = _project(R[:b], mask[:b], g, ridge)
+                return jnp.pad(x, (0, n - b)), echo
+            return run
+
+        return jax.lax.switch(_bucket_index(n_live, buckets),
+                              [branch(b) for b in buckets], R, mask, g)
 
 
 def echo_decision_from_projection(

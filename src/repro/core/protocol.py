@@ -21,7 +21,11 @@ A note on the reference sets R_j: in the paper each worker keeps its own R_j,
 but every worker hears the same raw broadcasts in the same slot order and
 applies the same deterministic independence test — so R_j is exactly the
 shared in-order independent prefix known at slot j. We therefore keep ONE
-reference buffer keyed by broadcaster ID and snapshot its mask per slot.
+reference buffer and snapshot its mask per slot. The buffer is compact: the
+k-th raw admitted is row k, with its sender ID beside it, so a slot's
+projection reads only the rows that can be live (``echo.project_onto_span``
+with ``n_live``); the mask and the echo coefficients on the wire stay in
+sender-ID order.
 (On a lossy channel a faded raw broadcast is skipped by *every* overhearer,
 so the reference set stays shared — it just grows more slowly. The
 independence test runs on the sender-side projection; quantization noise is
@@ -43,22 +47,23 @@ from __future__ import annotations
 import contextlib
 import inspect
 from functools import partial
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
 from repro.comm import ChannelState, CommConfig, CommLedger, DEFAULT_COMM
-from repro.obs.scopes import (PROTOCOL_AGGREGATE, PROTOCOL_GRADS,
-                              PROTOCOL_SLOT, PROTOCOL_UPDATE, SLOT_DECIDE,
-                              SLOT_OVERHEAR, SLOT_SERVER)
+from repro.obs.scopes import (ECHO_PROJECT, PROTOCOL_AGGREGATE,
+                              PROTOCOL_GRADS, PROTOCOL_SLOT, PROTOCOL_UPDATE,
+                              SLOT_DECIDE, SLOT_OVERHEAR, SLOT_SERVER)
 
 from . import aggregators as agg_lib
 from .byzantine import AttackPlan
 from .cgc import cgc_aggregate, cgc_aggregate_known_bad
 from .echo import (echo_decision_from_projection, independent_from_projection,
-                   project_onto_span, reconstruct_echo, wire_norm_ratio)
+                   project_onto_span, reconstruct_echo, rows_read,
+                   wire_norm_ratio)
 from .types import (MSG_ECHO, MSG_RAW, MSG_SILENT, ProtocolConfig, RoundStats,
                     ServerState)
 
@@ -69,7 +74,9 @@ class CommState(NamedTuple):
     G: jax.Array          # (n, d) server gradient table
     received: jax.Array   # (n,) bool
     detected: jax.Array   # (n,) bool
-    R: jax.Array          # (n, d) overheard raw gradients (row = sender ID)
+    R: jax.Array          # (n, d) overheard raw gradients: row k = the k-th
+                          # admitted (shared mask); row = sender ID
+                          # (per-worker masks)
     rmask: jax.Array      # (n,) bool shared reference mask — or (n, n)
                           # per-worker masks (rmask[j] = worker j's view)
                           # when a partial hearing graph is threaded in
@@ -78,6 +85,10 @@ class CommState(NamedTuple):
     faded: jax.Array      # (n,) bool — the channel faded this worker's slot
     chan: ChannelState    # broadcast-channel carry (fading PRNG + budget)
     ef: jax.Array         # (n, d) error-feedback residuals (zeros when off)
+    # shared mask only (None under per-worker masks):
+    ref_id: Any = None    # (n,) int32 sender ID of each R row (n: empty row)
+    n_ref: Any = None     # () int32 rows of R admitted so far
+    proj_rows: Any = None  # () int32 rows the slot projections read so far
 
 
 def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
@@ -95,8 +106,17 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         # independence test (line 29): project the broadcast vector once.
         # For honest workers raw_msg == g_i, so the decision is the paper's;
         # for Byzantine workers every dec field is overridden by the plan.
+        # Only R's first n_ref rows are live, so the projection reads the
+        # smallest row bucket that holds them; its coefficients go back to
+        # sender-ID order, the order of the mask and of the wire.
         raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
-        x_proj, proj = project_onto_span(st.R, st.rmask, raw_msg, cfg.ridge)
+        live = jnp.arange(n) < st.n_ref
+        x_live, proj = project_onto_span(st.R, live, raw_msg, cfg.ridge,
+                                         n_live=st.n_ref)
+        with jax.named_scope(ECHO_PROJECT):
+            x_proj = jnp.zeros_like(x_live).at[st.ref_id].set(x_live,
+                                                              mode="drop")
+        proj_rows = st.proj_rows + rows_read(st.n_ref, n)
         dec = echo_decision_from_projection(x_proj, proj, st.rmask, raw_msg,
                                             cfg.r)
         honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
@@ -141,7 +161,8 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
             # Honest senders compute the norm ratio against the coefficients
             # AS TRANSMITTED so ||g~|| == ||g|| survives quantization;
             # Byzantine senders forge theirs freely.
-            k_honest = wire_norm_ratio(st.R, st.rmask, echo_x, raw_msg)
+            x_wire = echo_x.at[st.ref_id].get(mode="fill", fill_value=0)
+            k_honest = wire_norm_ratio(st.R, live, x_wire, raw_msg)
             echo_k = codec.roundtrip(
                 jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
 
@@ -185,7 +206,15 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
                                             cfg.indep_tol)
         overheard = ~faded & ok
         add = is_raw & indep & overheard
-        R = jnp.where(add, st.R.at[i].set(raw_wire), st.R)
+        # One row update at row n_ref, which is < n (a slot admits at most
+        # one raw): a plain dynamic update, with no bounds check that would
+        # read the old row. Rows from n_ref on are empty (zeros, ID n), so
+        # a slot that admits nothing rewrites an empty row and reads none.
+        k = st.n_ref
+        R = jax.lax.dynamic_update_index_in_dim(
+            st.R, jnp.where(add, raw_wire, jnp.zeros_like(raw_wire)), k, 0)
+        ref_id = st.ref_id.at[k].set(jnp.where(add, i, n))
+        n_ref = k + add.astype(jnp.int32)
         rmask = st.rmask.at[i].set(add | st.rmask[i])
 
     bits = st.bits.at[i].set(bits_i)
@@ -197,7 +226,7 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     ef = jnp.where(use_ef & is_raw, st.ef.at[i].set(ef_row), st.ef)
 
     return CommState(G, received, detected, R, rmask, bits, echoed,
-                     faded_acc, chan, ef)
+                     faded_acc, chan, ef, ref_id, n_ref, proj_rows)
 
 
 def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
@@ -364,6 +393,8 @@ def communication_phase(
         ef=ef if ef is not None else jnp.zeros((n, d), grads.dtype),
     )
     if shared:
+        st = st._replace(ref_id=jnp.full((n,), n, jnp.int32),
+                         n_ref=jnp.int32(0), proj_rows=jnp.int32(0))
         slot = partial(_slot, cfg=cfg, grads=grads, byz_mask=byz_mask,
                        plan=plan, comm=comm, use_ef=ef is not None)
     else:
@@ -388,6 +419,7 @@ def communication_phase(
         n_detected=jnp.sum(st.detected.astype(jnp.int32)),
         rank_R=jnp.sum(rmask_any.astype(jnp.int32)),
         n_faded=jnp.sum(st.faded.astype(jnp.int32)),
+        proj_rows=st.proj_rows,
     )
     if ef is not None:
         return server, stats, st.ef
@@ -500,9 +532,11 @@ def run_training(
     """Multi-round driver: Echo-CGC (use_radio) or point-to-point baseline.
 
     Returns a dict of per-round traces: dist2 (||w-w*||^2), value, bits,
-    n_echo, n_detected. A :class:`~repro.comm.CommLedger` passed as
-    ``ledger`` gets one record per simulated round (the simulation's
-    reporting hook into the shared accounting surface).
+    n_echo, n_detected, proj_rows (reference rows the slot projections
+    read; 0 on the point-to-point path). A
+    :class:`~repro.comm.CommLedger` passed as ``ledger`` gets one record
+    per simulated round (the simulation's reporting hook into the shared
+    accounting surface).
 
     ``policy`` (a :class:`~repro.comm.policy.CommPolicy`) closes the
     control loop: a *dynamic* policy moves the driver to a per-round
@@ -566,17 +600,20 @@ def run_training(
             bits = jnp.sum(stats.bits_sent)
             n_echo = stats.n_echo
             n_det = stats.n_detected
+            proj_rows = _proj_rows(stats, n)
         else:
             w_next, bits = pointwise_round(cfg, w, grads, byz_mask, plan,
                                            aggregator, comm)
             n_echo = jnp.int32(0)
             n_det = jnp.int32(0)
+            proj_rows = jnp.int32(0)
         out = dict(
             dist2=jnp.sum((w - cost.w_star) ** 2),
             value=cost.value(w),
             bits=bits,
             n_echo=n_echo,
             n_detected=n_det,
+            proj_rows=proj_rows,
         )
         return (w_next, ef), out
 
@@ -601,6 +638,15 @@ def run_training(
         with obs.span("protocol.ledger"):
             ledger.record_protocol_trace(trace, n, d, comm.codec)
     return trace
+
+
+def _proj_rows(stats: RoundStats, n: int) -> jax.Array:
+    """Reference rows a round's slot projections read. Under per-worker
+    masks every slot projects over all n rows once for its decision and
+    once for each receiver's independence test."""
+    if stats.proj_rows is not None:
+        return stats.proj_rows
+    return jnp.int32(n * (n + n * n))
 
 
 def _host_span(name: str, eager: bool):
@@ -708,7 +754,7 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
     ef = jnp.zeros((n, d), w0.dtype) if error_feedback else None
     last_obs = None
     trace = {k: [] for k in
-             ("dist2", "value", "bits", "n_echo", "n_detected")}
+             ("dist2", "value", "bits", "n_echo", "n_detected", "proj_rows")}
     keys = jax.random.split(key, rounds)
     eager = not isinstance(w0, jax.core.Tracer)
     with _host_span("protocol.rounds", eager):
@@ -772,6 +818,7 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
             trace["bits"].append(jnp.float32(bits))
             trace["n_echo"].append(jnp.int32(n_echo))
             trace["n_detected"].append(stats.n_detected)
+            trace["proj_rows"].append(_proj_rows(stats, n))
             w = w_next
     if eager:
         obs.counter("protocol.rounds_simulated", rounds)

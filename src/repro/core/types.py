@@ -50,6 +50,9 @@ class RoundStats(NamedTuple):
     rank_R: jax.Array            # () int32, final size of the reference set
     n_faded: Any = None          # () int32, slots the channel faded this round
                                  # (None from pre-channel call sites)
+    proj_rows: Any = None        # () int32, reference rows the round's slot
+                                 # projections read, summed over slots (None
+                                 # under per-worker masks)
 
 
 class ProtocolConfig(NamedTuple):

@@ -4,8 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.echo import (echo_decision, is_linearly_independent,
-                             project_onto_span, reconstruct_echo)
+from repro.core.echo import (echo_decision, echo_decision_from_projection,
+                             is_linearly_independent, project_onto_span,
+                             reconstruct_echo, rows_read)
 
 
 def _setup(n=6, d=40, k=3, seed=0):
@@ -86,3 +87,40 @@ def test_linear_independence_detection():
     assert bool(is_linearly_independent(R, mask, indep, tol=1e-4))
     # empty reference set accepts anything
     assert bool(is_linearly_independent(R, jnp.zeros(6, bool), dep))
+
+
+@pytest.mark.parametrize("k,bucket", [(0, 8), (1, 8), (7, 8), (8, 8),
+                                      (9, 16), (16, 16), (17, 32), (40, 40)])
+def test_bucketed_projection_matches_full_table(k, bucket):
+    """``n_live`` bounds the rows read to the bucket holding the live
+    rows (8, 16, 32, then n = 40): with the live rows stored compactly,
+    out of ID order, and junk past them, the projection equals the
+    unbounded one over the ID-ordered table, at every bucket edge and
+    at rank n, where the bucket is the whole table."""
+    n, d, r = 40, 256, 0.5
+    assert int(rows_read(jnp.int32(k), n)) == bucket
+    key = jax.random.PRNGKey(k)
+    k_tab, k_ids, k_junk, k_c, k_noise = jax.random.split(key, 5)
+    R_id = jax.random.normal(k_tab, (n, d))
+    ids = jax.random.permutation(k_ids, n)[:k]
+    mask_id = jnp.zeros(n, bool).at[ids].set(True)
+    R_c = jax.random.normal(k_junk, (n, d)).at[:k].set(R_id[ids])
+    live = jnp.arange(n) < k
+    g = (jax.random.normal(k_c, (k,)) @ R_id[ids]
+         + 0.05 * jax.random.normal(k_noise, (d,)))
+
+    x_ref, e_ref = project_onto_span(R_id, mask_id, g)
+    x_c, e_c = jax.jit(
+        lambda R, m, g, nl: project_onto_span(R, m, g, n_live=nl))(
+        R_c, live, g, jnp.int32(k))
+    assert np.all(np.asarray(x_c[k:]) == 0)
+    x_got = jnp.zeros(n).at[ids].set(x_c[:k])
+    np.testing.assert_allclose(np.asarray(x_got), np.asarray(x_ref),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(e_c), np.asarray(e_ref),
+                               rtol=1e-4, atol=1e-5)
+    ref = echo_decision_from_projection(x_ref, e_ref, mask_id, g, r)
+    got = echo_decision_from_projection(x_got, e_c, live, g, r)
+    np.testing.assert_allclose(float(got.residual), float(ref.residual),
+                               rtol=1e-4, atol=1e-5)
+    assert bool(got.send_echo) == bool(ref.send_echo) == (k > 0)

@@ -7,11 +7,15 @@ import pytest
 import repro.net  # noqa: F401  (registers the channel-aware attacks)
 from repro.comm import CommConfig
 from repro.comm.channel import LossyBroadcast
-from repro.comm.wire import FP32
+from repro.comm.policy import AdaptiveEchoPolicy
+from repro.comm.wire import FP32, Int8Codec
 from repro.core import byzantine, costfns, theory
+from repro.core.echo import row_buckets
 from repro.core.protocol import (communication_phase, echo_cgc_round,
                                  pointwise_round, run_training)
 from repro.core.types import MSG_RAW, ProtocolConfig, raw_bits
+from repro.net import HearingGraph, complete_graph
+from repro.obs.scopes import ECHO_PROJECT
 
 
 def _cfg(n=12, f=1, r=0.3, eta=0.01):
@@ -183,3 +187,109 @@ def test_echo_fraction_meets_theory_bound():
     p = theory.echo_probability(r, sigma)
     echo_frac = float(jnp.mean(trace["n_echo"] / (n - 1)))
     assert echo_frac >= p - 0.1, (echo_frac, p)
+
+
+def _rising_rank_round(n=40, d=256, n_byz=4):
+    """Sign-flippers in the first slots, then honest gradients that are
+    random (raw, admitted) except every sixth, a combination of the two
+    before it (echoed): R's rank climbs to 34 of 40, so every row
+    bucket (8, 16, 32, 40) serves some slot."""
+    key = jax.random.PRNGKey(11)
+    grads = jax.random.normal(key, (n, d))
+    for j in range(5, n, 6):
+        grads = grads.at[j].set(0.7 * grads[j - 1] - 1.3 * grads[j - 2])
+    byz = jnp.arange(n) < n_byz
+    plan = byzantine.sign_flip(jax.random.PRNGKey(1), grads, byz, None, None)
+    return ProtocolConfig(n=n, f=n_byz, r=0.5, eta=0.01), grads, byz, plan
+
+
+@pytest.mark.parametrize("codec", [FP32, Int8Codec()], ids=["fp32", "int8"])
+def test_compact_reference_matches_full_table_path(codec):
+    """The shared slot loop keeps R compact and projects over the live
+    row bucket; the strict complete-graph path keeps the ID-ordered
+    table and projects over all n rows. Same decisions, same bits, same
+    server table (int8: the norm ratio of the transmitted coefficients
+    reads the compact rows)."""
+    cfg, grads, byz, plan = _rising_rank_round()
+    n = cfg.n
+    comm = CommConfig(codec=codec)
+    srv_a, st_a = communication_phase(cfg, grads, byz, plan, comm=comm)
+    strict = HearingGraph(adj=complete_graph(n).adj, strict=True)
+    srv_b, st_b = communication_phase(cfg, grads, byz, plan, comm=comm,
+                                      net=strict)
+    assert int(st_a.rank_R) == int(st_b.rank_R) == 34
+    assert 0 < int(st_a.n_echo) < n
+    np.testing.assert_array_equal(np.asarray(st_a.echo_sent),
+                                  np.asarray(st_b.echo_sent))
+    np.testing.assert_array_equal(np.asarray(srv_a.received),
+                                  np.asarray(srv_b.received))
+    np.testing.assert_array_equal(np.asarray(st_a.bits_sent),
+                                  np.asarray(st_b.bits_sent))
+    np.testing.assert_allclose(np.asarray(srv_a.G), np.asarray(srv_b.G),
+                               rtol=1e-4, atol=1e-5)
+    # every bucket served some slot: slot i projects over the bucket of
+    # the rank before it, and the rank climbs past 32
+    assert int(st_a.proj_rows) > 8 * n
+    assert st_b.proj_rows is None
+
+
+def test_proj_rows_counts_bucket_rows():
+    """n=20 identical gradients: slot 0 raw, the rest echo, so R holds
+    one row and every slot reads the 8-row bucket: 8 x 20 = 160 rows, in
+    the round's stats and in run_training's per-round trace, scanned and
+    under a dynamic policy (0 point to point)."""
+    n, d = 20, 24
+    _, stats = communication_phase(_cfg(n=n), _identical_grads(n, d),
+                                   jnp.zeros(n, bool), _no_plan(n, d))
+    assert int(stats.rank_R) == 1
+    assert int(stats.proj_rows) == 160
+
+    key = jax.random.PRNGKey(0)
+    cost = costfns.quadratic(key, d=d, sigma=0.0)     # every worker equal
+    cfg = ProtocolConfig(n=n, f=0, r=0.3, eta=0.001)
+    args = (cfg, cost, byzantine.no_attack, jnp.zeros(n, bool), key,
+            jnp.ones(d))
+    for kw, want in (({}, 160), ({"policy": AdaptiveEchoPolicy()}, 160),
+                     ({"use_radio": False}, 0)):
+        trace = run_training(*args, rounds=3, **kw)
+        np.testing.assert_array_equal(np.asarray(trace["proj_rows"]),
+                                      [want] * 3, err_msg=str(kw))
+
+
+def _dot_reads(jaxpr, stack, last_bucket, n_buckets, out):
+    """(name stack, rows of each 2-D operand, in the widest bucket) of
+    every dot_general in ``jaxpr`` and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        here = "/".join(p for p in (stack, str(eqn.source_info.name_stack))
+                        if p)
+        if eqn.primitive.name == "dot_general":
+            rows = [v.aval.shape[0] for v in eqn.invars if v.aval.ndim == 2]
+            out.append((here, rows, last_bucket))
+        if eqn.primitive.name == "cond":
+            branches = eqn.params["branches"]
+            for b, br in enumerate(branches):
+                widest = (len(branches) == n_buckets
+                          and b == len(branches) - 1)
+                _dot_reads(br.jaxpr, here, last_bucket or widest,
+                           n_buckets, out)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _dot_reads(sub, here, last_bucket, n_buckets, out)
+
+
+def test_shared_slot_projection_reads_only_its_bucket():
+    """Structural guard: in the shared slot loop no dot_general under
+    the echo.project scope reads all n rows, except in the branch whose
+    bucket is the whole table."""
+    cfg, grads, byz, plan = _rising_rank_round(n=20, d=64, n_byz=2)
+    n = cfg.n
+    jaxpr = jax.make_jaxpr(
+        lambda g: communication_phase(cfg, g, byz, plan))(grads).jaxpr
+    reads = []
+    _dot_reads(jaxpr, "", False, len(row_buckets(n)), reads)
+    proj = [(rows, widest) for stack, rows, widest in reads
+            if ECHO_PROJECT in stack.split("/")]
+    assert any(8 in rows for rows, _ in proj)
+    assert any(widest for _, widest in proj)
+    full = [(rows, widest) for rows, widest in proj if n in rows]
+    assert full and all(widest for _, widest in full), full

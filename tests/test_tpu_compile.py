@@ -124,3 +124,31 @@ def test_paged_decode_attention_compiles(one_chip):
     lengths = _spec((B,), jnp.int32, one_chip)
     _compile(lambda *a: decode_attention.paged_decode_attention(
         *a, interpret=False), q, pages, pages, table, lengths)
+
+
+def test_shared_slot_loop_compiles_at_paper_width(one_chip):
+    """The paper headline's slot loop (n=100 workers, f=10 sign-flippers,
+    d = 2**21, an 839 MB table) on the shared reference set: the row
+    bucket switch of its projection compiles for the chip, and the
+    compact R adds no copy of the table to the plan (the server's G and
+    R are two tables; another would take the scratch past 2.25)."""
+    from repro.core import byzantine
+    from repro.core.protocol import communication_phase
+    from repro.core.types import ProtocolConfig
+
+    n, d, f = 100, 1 << 21, 10
+    cfg = ProtocolConfig(n=n, f=f, r=0.3, eta=0.1)
+    byz = jnp.arange(n) < f
+
+    def round_(grads):
+        plan = byzantine.sign_flip(jax.random.PRNGKey(0), grads, byz,
+                                   None, None)
+        server, stats = communication_phase(cfg, grads, byz, plan)
+        return server.G, stats.proj_rows
+
+    with jax.default_matmul_precision("high"):
+        compiled = jax.jit(round_).lower(
+            _spec((n, d), jnp.float32, one_chip)).compile()
+    assert "conditional" in compiled.as_text()
+    table = 4 * n * d
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.25 * table
