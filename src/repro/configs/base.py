@@ -32,6 +32,13 @@ class ModelConfig:
     head_dim: Optional[int] = None   # default d_model // num_heads
     qk_norm: bool = False
     rope_theta: float = 1e4
+    # YaRN RoPE scaling (DeepSeek-V2): 0 = plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     mrope: bool = False              # Qwen2-VL M-RoPE
     mrope_sections: Tuple[int, ...] = (16, 24, 24)
     sliding_window: Optional[int] = None
@@ -52,7 +59,13 @@ class ModelConfig:
     moe_d_ff: int = 0                # per-expert hidden size
     first_dense_layers: int = 0      # leading dense layers (deepseek-v2)
     router_aux_coef: float = 0.01    # load-balance loss weight
-    capacity_factor: float = 1.25
+    router_aux: str = "batch"        # batch (Switch) | seq (DeepSeek-V2)
+    norm_topk_prob: bool = True      # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0   # times un-normalised weights
+    # expert parallelism's share: this chip holds routed experts
+    # [expert_offset, expert_offset + experts_held); 0 = all of them
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # --- SSM (mamba2) ---
     ssm_state: int = 0
@@ -87,6 +100,11 @@ class ModelConfig:
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // self.num_heads
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -145,8 +163,8 @@ def reduced(cfg: ModelConfig, layers: int = 2, d_model: int = 256,
             moe_d_ff=min(cfg.moe_d_ff, d_model),
             num_shared_experts=min(cfg.num_shared_experts, 1),
             first_dense_layers=min(cfg.first_dense_layers, 1),
-            # dropless at smoke scale so decode == forward exactly
-            capacity_factor=float(min(cfg.num_experts, 4)),
+            experts_held=min(cfg.experts_held, 4),
+            expert_offset=0,
         )
     if cfg.attn_type == "mla":
         changes.update(
@@ -211,6 +229,7 @@ ARCH_IDS = [
     "qwen2-vl-72b",
     "qwen3-moe-30b-a3b",
     "qwen3-0.6b",
+    "deepseek-v2-lite",
 ]
 
 _MODULE_FOR = {a: "repro.configs." + a.replace("-", "_").replace(".", "p")

@@ -10,11 +10,12 @@ dispatches to when a ShardCtx with a mesh is supplied. Two layouts:
     partial outputs that one psum over the model axis completes. Robust
     default: no divisibility constraint on the expert count.
   * ``ep`` — experts themselves are sharded over the model axis
-    (EP_RULES: "expert" -> model, full d_ff per expert). Each shard
-    dispatches its local tokens into per-expert capacity buffers, an
-    all_to_all ships each buffer to the owning shard, experts run on the
-    union of all shards' tokens, and a second all_to_all returns the
-    outputs to the tokens' home shards.
+    (EP_RULES: "expert" -> model, full d_ff per expert). The tokens are
+    the same on every model shard, so each shard routes them over all
+    experts (the fp32 router is replicated) and computes, dropless, the
+    part of the output its own experts give (``models.moe.moe_held`` at
+    offset shard x E/M: the share a one-chip cut of the layer runs); one
+    psum over the model axis sums the shares.
 
 Both run as one fully-manual shard_map over the mesh. Inside the
 trainer's worker shard_map, which is manual over the data axes only,
@@ -29,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models import nn
 from .context import ShardCtx
 
 
@@ -55,16 +55,18 @@ def _weight_specs(p: Dict[str, Any], expert_entry, mlp_gate_entry,
     return specs
 
 
-def moe_sharded(p, cfg: ModelConfig, x_flat: jax.Array, ctx: ShardCtx
-                ) -> Tuple[jax.Array, Any]:
-    """Distributed MoE on flattened tokens (T, D); see module docstring."""
+def moe_sharded(p, cfg: ModelConfig, x_flat: jax.Array, ctx: ShardCtx,
+                seq_len=None) -> Tuple[jax.Array, Any]:
+    """Distributed MoE on flattened tokens (T, D); see module docstring.
+    ``seq_len`` splits the tokens into sequences for a sequence-wise aux
+    loss."""
     if ctx.moe_impl == "ep":
-        return _moe_ep(p, cfg, x_flat, ctx)
+        return _moe_ep(p, cfg, x_flat, ctx, seq_len)
     if ctx.moe_impl == "tp":
-        return _moe_tp(p, cfg, x_flat, ctx)
+        return _moe_tp(p, cfg, x_flat, ctx, seq_len)
     if ctx.moe_impl == "local":
         from repro.models.moe import moe_local
-        return moe_local(p, cfg, x_flat)
+        return moe_local(p, cfg, x_flat, seq_len=seq_len)
     raise ValueError(f"unknown moe_impl {ctx.moe_impl!r}; "
                      f"known: 'tp', 'ep', 'local'")
 
@@ -74,8 +76,8 @@ def moe_sharded(p, cfg: ModelConfig, x_flat: jax.Array, ctx: ShardCtx
 # ---------------------------------------------------------------------------
 
 
-def _moe_tp(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
-    from repro.models.moe import moe_local
+def _moe_tp(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx, seq_len):
+    from repro.models.moe import MoEStats, moe_local
 
     m_ax = ctx.model_axis
     sizes = ctx.mesh.shape
@@ -86,7 +88,7 @@ def _moe_tp(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
     bspec = _batch_spec(ctx)
 
     def fn(p_sh, x_loc):
-        y, stats = moe_local(p_sh, cfg, x_loc)
+        y, stats = moe_local(p_sh, cfg, x_loc, seq_len=seq_len)
         if m_ax and M > 1:
             y = jax.lax.psum(y, m_ax)
         return y, _pmean_stats(stats, ctx)
@@ -94,25 +96,25 @@ def _moe_tp(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
     in_specs = (_weight_specs(p, None, m_ax if M > 1 else None,
                               m_ax if M > 1 else None),
                 P(bspec, None))
-    out_specs = (P(bspec, None), jax.tree.map(lambda _: P(), _abs_stats()))
+    out_specs = (P(bspec, None),
+                 jax.tree.map(lambda _: P(), MoEStats(0, 0, 0, 0)))
     sm = jax.shard_map(fn, mesh=ctx.mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return sm(p, x)
 
 
 # ---------------------------------------------------------------------------
-# Expert parallelism (experts sharded, all_to_all token exchange)
+# Expert parallelism (experts sharded, each shard's share summed by psum)
 # ---------------------------------------------------------------------------
 
 
-def _moe_ep(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
-    from repro.models.moe import (MoEStats, dispatch_indices,
-                                  load_balance_loss, router_topk)
+def _moe_ep(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx, seq_len):
+    from repro.models.moe import MoEStats, moe_held, route
 
     m_ax = ctx.model_axis
     sizes = ctx.mesh.shape
     M = sizes.get(m_ax, 1) if m_ax else 1
-    E, k = cfg.num_experts, cfg.top_k
+    E = cfg.num_experts
     if M > 1 and E % M:
         raise ValueError(f"ep MoE needs num_experts % model axis == 0 "
                          f"(experts={E}, model={M})")
@@ -121,64 +123,21 @@ def _moe_ep(p, cfg: ModelConfig, x: jax.Array, ctx: ShardCtx):
 
     def fn(p_sh, x_loc):
         # p_sh: full router, (E_loc, D, F) expert slabs
-        T, D = x_loc.shape
-        C = int(max(8, round(T * k / E * cfg.capacity_factor)))
-
-        logits = x_loc.astype(jnp.float32) @ p_sh["router"]
-        top_w, top_i, probs = router_topk(logits, k)
-        aux = load_balance_loss(probs, top_i, E)
-
-        st, se, pos, keep, order = dispatch_indices(top_i, C, E)
-        flat_w = top_w.reshape(-1)[order]
-        idx = jnp.where(keep, se * C + pos, E * C)
-        buf = jnp.zeros((E * C + 1, D), x_loc.dtype).at[idx].set(x_loc[st])
-        buf = buf[:-1].reshape(E, C, D)
-
+        top_w, top_i, aux = route(p_sh, cfg, x_loc, seq_len)
+        offset = jax.lax.axis_index(m_ax) * E_loc if M > 1 else 0
+        y, copies, load = moe_held(p_sh, cfg, x_loc, top_w, top_i, offset)
         if M > 1:
-            # ship each expert's buffer to its owner shard; receive the
-            # buffers every shard built for *my* experts.
-            recv = jax.lax.all_to_all(buf, m_ax, split_axis=0,
-                                      concat_axis=0, tiled=True)
-        else:
-            recv = buf
-        # rows of recv: (source shard, local expert) -> regroup per expert
-        xe = recv.reshape(M, E_loc, C, D).transpose(1, 0, 2, 3)
-        xe = xe.reshape(E_loc, M * C, D)
-
-        wg = p_sh["w_gate"].astype(x_loc.dtype)
-        wu = p_sh["w_up"].astype(x_loc.dtype)
-        wd = p_sh["w_down"].astype(x_loc.dtype)
-        g = jnp.einsum("ecd,edf->ecf", xe, wg)
-        u = jnp.einsum("ecd,edf->ecf", xe, wu)
-        h = nn.swiglu(g, u)
-        out = jnp.einsum("ecf,efd->ecd", h, wd)           # (E_loc, M*C, D)
-
-        back = out.reshape(E_loc, M, C, D).transpose(1, 0, 2, 3)
-        back = back.reshape(E, C, D)
-        if M > 1:
-            out_buf = jax.lax.all_to_all(back, m_ax, split_axis=0,
-                                         concat_axis=0, tiled=True)
-        else:
-            out_buf = back
-        # rows back in global-expert order (owner-major == expert id)
-        out_flat = out_buf.reshape(E * C, D)
-        y_copies = jnp.where(
-            keep[:, None], out_flat[jnp.where(keep, se * C + pos, 0)], 0.0)
-        y_copies = y_copies * flat_w[:, None].astype(x_loc.dtype)
-        y = jnp.zeros((T, D), x_loc.dtype).at[st].add(y_copies)
-
-        dropped = 1.0 - jnp.sum(keep.astype(jnp.float32)) / (T * k)
-        stats = MoEStats(aux_loss=aux, dropped_frac=dropped)
+            y = jax.lax.psum(y, m_ax)
+            copies = jax.lax.psum(copies, m_ax)
+            load = jax.lax.pmax(load, m_ax)
+        stats = MoEStats(aux, jnp.zeros((), jnp.float32), copies, load)
         return y, _pmean_stats(stats, ctx)
 
     in_specs = (_weight_specs(p, m_ax if M > 1 else None, None, None),
                 P(bspec, None))
-    out_specs = (P(bspec, None), jax.tree.map(lambda _: P(), _abs_stats()))
+    out_specs = (P(bspec, None),
+                 jax.tree.map(lambda _: P(), MoEStats(0, 0, 0, 0)))
     sm = jax.shard_map(fn, mesh=ctx.mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return sm(p, x)
 
-
-def _abs_stats():
-    from repro.models.moe import MoEStats
-    return MoEStats(aux_loss=0, dropped_frac=0)

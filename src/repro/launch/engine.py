@@ -1082,9 +1082,12 @@ class Trainer:
                                            echoed=echoed)
             record.update(loss=loss, **led)
             for k in ("echo_frac", "grad_global_norm", "cgc_threshold",
-                      "cgc_clipped_frac", "ef_residual_norm"):
+                      "cgc_clipped_frac", "ef_residual_norm",
+                      "moe_held_copies", "moe_load_ratio"):
                 if k in m:
                     record[k] = float(m[k])
+            if "moe_held_copies" in m:
+                record["moe_dropped"] = float(m["moe_dropped"])
             if self._policy_dynamic:
                 record["codec"] = codec.name
                 record["echo_r"] = echo_r

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import nn
-from repro.models.rope import apply_mrope, apply_rope
+from repro.models.rope import apply_mrope, apply_rope, rope_spec, yarn_mscale
+from repro.obs.scopes import MLA_ATTENTION
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -57,17 +58,24 @@ def _softmax_attend(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def chunked_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
                 q_pos: jax.Array, k_pos: jax.Array, *, causal: bool,
-                window: Optional[int], q_chunk: int = 512) -> jax.Array:
+                window: Optional[int], q_chunk: int = 512,
+                scale: Optional[float] = None, remat: bool = False
+                ) -> jax.Array:
     """Query-chunked GQA core.
 
     q: (B, S, H, hd); k/v: (B, T, K, hd) with H = K*G; positions (B, S)/(B, T).
     Scans over query chunks so peak score memory is (B, K, G, qc, T).
+    ``scale`` multiplies the scores (default ``hd ** -0.5``). With
+    ``remat`` each chunk's scores are recomputed in the backward pass
+    instead of saved for every chunk, which would hold the whole
+    (B, K, G, S, T) again.
     """
     B, S, H, hd = q.shape
     K = k.shape[2]
     G = H // K
     vd = v.shape[-1]                    # may differ from hd (MLA)
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
     qg = q.reshape(B, S, K, G, hd)
 
     if S <= q_chunk or S % q_chunk != 0:
@@ -84,7 +92,7 @@ def chunked_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
         mask = _band_mask(qpc, k_pos, causal, window)
         return None, _softmax_attend(qc, k, v, mask, scale)
 
-    _, outs = jax.lax.scan(body, None,
+    _, outs = jax.lax.scan(jax.checkpoint(body) if remat else body, None,
                            (jnp.moveaxis(qs, 1, 0), jnp.moveaxis(qp, 1, 0)))
     out = jnp.moveaxis(outs, 0, 1).reshape(B, S, K, G, vd)
     return out.reshape(B, S, H, vd)
@@ -301,6 +309,20 @@ def _mla_q(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     return q
 
 
+def mla_scale(cfg: ModelConfig) -> Optional[float]:
+    """MLA's softmax scale under YaRN, (nd + rd)^-0.5 times the squared
+    ``mscale_all_dim`` factor; None (chunked_gqa's default) without it."""
+    if not (cfg.yarn_factor and cfg.yarn_mscale_all_dim):
+        return None
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _mla_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array):
+    inv, mscale = rope_spec(cfg, cfg.qk_rope_head_dim)
+    return apply_rope(x, positions, cfg.rope_theta, inv, mscale)
+
+
 def mla_forward(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
                 q_chunk: int = 512) -> jax.Array:
     """Train/prefill MLA: materialise per-head K/V from the latent."""
@@ -309,26 +331,26 @@ def mla_forward(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     lora = cfg.kv_lora_rank
 
-    q = _mla_q(p, cfg, x)                              # (B,S,H,nd+rd)
-    q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    with jax.named_scope(MLA_ATTENTION):
+        q = _mla_q(p, cfg, x)                          # (B,S,H,nd+rd)
+        q_nope, q_rope = q[..., :nd], q[..., nd:]
+        q_rope = _mla_rope(cfg, q_rope, positions)
 
-    ckv_full = nn.dense(x, p["wkv_a"].astype(x.dtype))  # (B,S,lora+rd)
-    ckv, k_pe = ckv_full[..., :lora], ckv_full[..., lora:]
-    ckv = nn.rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions,
-                      cfg.rope_theta)                   # (B,S,1,rd)
+        ckv_full = nn.dense(x, p["wkv_a"].astype(x.dtype))  # (B,S,lora+rd)
+        ckv, k_pe = ckv_full[..., :lora], ckv_full[..., lora:]
+        ckv = nn.rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
+        k_pe = _mla_rope(cfg, k_pe[:, :, None, :], positions)  # (B,S,1,rd)
 
-    k_nope = jnp.einsum("bsl,lhn->bshn", ckv, p["wk_b"].astype(x.dtype))
-    v = jnp.einsum("bsl,lhv->bshv", ckv, p["wv_b"].astype(x.dtype))
+        k_nope = jnp.einsum("bsl,lhn->bshn", ckv, p["wk_b"].astype(x.dtype))
+        v = jnp.einsum("bsl,lhv->bshv", ckv, p["wv_b"].astype(x.dtype))
 
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k_full = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_pe, (B, S, H, rd))], axis=-1)
-    out = chunked_gqa(q_full, k_full, v, positions, positions,
-                      causal=cfg.causal, window=cfg.sliding_window,
-                      q_chunk=q_chunk)
-    return jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(x.dtype))
+        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k_full = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (B, S, H, rd))], axis=-1)
+        out = chunked_gqa(q_full, k_full, v, positions, positions,
+                          causal=cfg.causal, window=cfg.sliding_window,
+                          q_chunk=q_chunk, scale=mla_scale(cfg), remat=True)
+        return jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(x.dtype))
 
 
 def mla_init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype
@@ -350,16 +372,16 @@ def mla_decode(p, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     H = cfg.num_heads
     nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     lora = cfg.kv_lora_rank
-    scale = (nd + rd) ** -0.5
+    scale = mla_scale(cfg) or (nd + rd) ** -0.5
 
     q = _mla_q(p, cfg, x)                               # (B,1,H,nd+rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, pos[:, None], cfg.rope_theta)
+    q_rope = _mla_rope(cfg, q_rope, pos[:, None])
 
     ckv_full = nn.dense(x, p["wkv_a"].astype(x.dtype))
     ckv_new = nn.rms_norm(ckv_full[..., :lora], p["kv_norm"], cfg.norm_eps)
-    kpe_new = apply_rope(ckv_full[:, :, None, lora:], pos[:, None],
-                         cfg.rope_theta)[:, :, 0, :]    # (B,1,rd)
+    kpe_new = _mla_rope(cfg, ckv_full[:, :, None, lora:],
+                        pos[:, None])[:, :, 0, :]       # (B,1,rd)
 
     T = cache["ckv"].shape[1]
     slot = jnp.mod(pos, T) if cfg.sliding_window else jnp.minimum(pos, T - 1)

@@ -176,6 +176,9 @@ def forward(v, cfg: ModelConfig, inputs: Dict[str, jax.Array],
         x, sts = jax.lax.scan(ckpt(body), x, v["layers"])
         stats = MoEStats(stats.aux_loss + jnp.sum(sts.aux_loss),
                          stats.dropped_frac + jnp.mean(sts.dropped_frac))
+        if sts.held_copies is not None:
+            stats = stats._replace(held_copies=jnp.sum(sts.held_copies),
+                                   load_ratio=jnp.max(sts.load_ratio))
     elif fam == "hybrid":
         k = cfg.shared_attn_every
         L = cfg.num_layers
@@ -248,6 +251,11 @@ def train_loss(v, cfg: ModelConfig, batch: Dict[str, jax.Array],
     aux = cfg.router_aux_coef * stats.aux_loss
     metrics = {"ce_loss": loss, "moe_aux": stats.aux_loss,
                "moe_dropped": stats.dropped_frac}
+    if stats.held_copies is not None:
+        # dropless held experts: copies computed (summed over the layers)
+        # and the worst layer's largest expert load over its mean
+        metrics.update(moe_held_copies=stats.held_copies,
+                       moe_load_ratio=stats.load_ratio)
     return loss + aux, metrics
 
 

@@ -1,10 +1,13 @@
-"""Mixture-of-Experts layer: top-k token-choice routing, capacity dispatch.
+"""Mixture-of-Experts layer: top-k token-choice routing, dropless over the
+held experts.
 
-Dispatch is **sort-based** (dropless up to the capacity factor): token copies
-are ordered by expert id and scattered into an (E, C, D) buffer, so compute
-is a clean grouped matmul whose FLOPs are proportional to tokens x top_k —
-no (T, E, C) one-hot einsum blow-up (that would dominate cost_analysis and
-wreck the roofline's useful-FLOP ratio).
+The layer holds routed experts ``[offset, offset + held)`` of the
+``num_experts`` its router scores (expert parallelism's share of the
+layer; all of them by default). Every copy routed to a held expert is
+computed and copies routed elsewhere add nothing (:func:`moe_held`): the
+held copies are sorted by expert and run through ``jax.lax.ragged_dot``
+a chunk of rows at a time, the chunks past the held count skipped, so the
+work and the memory follow the held copies, with no capacity and no drop.
 
 Sharding modes (DESIGN.md §3):
   * ``local``  — single-device; used by smoke tests and inside shard_map.
@@ -12,7 +15,8 @@ Sharding modes (DESIGN.md §3):
     device holds all experts with a 1/M slice of d_ff); dispatch stays local
     to the device's tokens, one psum over 'model' combines. Robust default.
   * ``ep``     — expert-parallel: experts sharded over the model axis,
-    token copies exchanged with all_to_all. Implemented in
+    each shard computing its own experts' share with :func:`moe_held` and
+    one psum over 'model' summing the shares. Implemented in
     ``repro.dist.moe_sharding`` (``moe_sharded`` dispatches on
     ``ShardCtx.moe_impl``) and enabled per-config for the §Perf hillclimb.
 
@@ -28,18 +32,26 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import nn
 from repro.models.mlp import mlp_forward, mlp_params
+from repro.obs.scopes import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED
+
+F32 = jnp.float32
 
 
 class MoEStats(NamedTuple):
     aux_loss: jax.Array        # load-balance loss (scalar)
-    dropped_frac: jax.Array    # fraction of token-copies over capacity
+    dropped_frac: jax.Array    # fraction of token-copies dropped (0)
+    # MoE layers only (None in dense ones): token copies the held experts
+    # computed, and the largest held expert's count over the held mean
+    held_copies: Optional[jax.Array] = None
+    load_ratio: Optional[jax.Array] = None
 
 
 def moe_params(cfg: ModelConfig, kg: nn.KeyGen, pdtype) -> Dict[str, Any]:
-    D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    D, E, F = cfg.d_model, cfg.held_experts, cfg.moe_d_ff
     p: Dict[str, Any] = {
-        "router": nn.param(kg(), (D, E), ("embed", None), jnp.float32,
-                           stddev=D ** -0.5),
+        # the router scores every routed expert, held here or not
+        "router": nn.param(kg(), (D, cfg.num_experts), ("embed", None),
+                           jnp.float32, stddev=D ** -0.5),
         "w_gate": nn.param(kg(), (E, D, F), ("expert", "embed", "mlp"),
                            pdtype),
         "w_up": nn.param(kg(), (E, D, F), ("expert", "embed", "mlp"),
@@ -53,12 +65,20 @@ def moe_params(cfg: ModelConfig, kg: nn.KeyGen, pdtype) -> Dict[str, Any]:
     return p
 
 
-def router_topk(logits: jax.Array, top_k: int
+def router_topk(logits: jax.Array, top_k: int, normalise: bool = True,
+                scaling: float = 1.0
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """fp32 softmax -> top-k (renormalised). Returns (weights, ids, probs)."""
+    """fp32 softmax -> greedy top-k. Returns (weights, ids, probs).
+
+    The weights are renormalised to sum to 1 (``normalise``) or kept as
+    the softmax gave them, times ``scaling`` (DeepSeek-V2's
+    ``norm_topk_prob`` false, ``routed_scaling_factor``)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_w, top_i = jax.lax.top_k(probs, top_k)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    if normalise:
+        top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    elif scaling != 1.0:
+        top_w = top_w * scaling
     return top_w, top_i, probs
 
 
@@ -72,70 +92,127 @@ def load_balance_loss(probs: jax.Array, top_i: jax.Array, E: int
     return E * jnp.sum(f * P)
 
 
-def dispatch_indices(top_i: jax.Array, capacity: int, E: int):
-    """Sort token copies by expert; compute each copy's slot in its expert.
-
-    Returns (token index, expert id, slot position, keep mask, sort order)
-    per sorted copy.
-    """
+def seq_aux_loss(probs: jax.Array, top_i: jax.Array, E: int, seq_len: int
+                 ) -> jax.Array:
+    """DeepSeek-V2's sequence-wise aux loss: per sequence, E / (k S) times
+    each expert's copy count dotted with the sequence's mean router
+    probability, averaged over the sequences. probs (T, E), top_i (T, k)
+    with T = B * seq_len, tokens in sequence order."""
     T, k = top_i.shape
-    TK = T * k
-    flat_e = top_i.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    order = jnp.argsort(flat_e, stable=True)
-    se = flat_e[order]
-    st = flat_t[order]
-    counts = jnp.zeros((E,), jnp.int32).at[se].add(1)
-    starts = jnp.cumsum(counts) - counts
-    pos = jnp.arange(TK) - starts[se]
-    keep = pos < capacity
-    return st, se, pos, keep, order
+    B = T // seq_len
+    counts = jnp.sum(jax.nn.one_hot(top_i.reshape(B, seq_len * k), E,
+                                    dtype=F32), axis=1)          # (B, E)
+    ce = counts * (E / (seq_len * k))
+    p_mean = jnp.mean(probs.reshape(B, seq_len, E), axis=1)
+    return jnp.mean(jnp.sum(ce * p_mean, axis=-1))
+
+
+def route(p, cfg: ModelConfig, x: jax.Array, seq_len: Optional[int] = None
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router over all ``num_experts`` on flattened tokens x (T, D):
+    (top-k weights, top-k ids, aux loss). ``seq_len`` splits T into
+    sequences for the sequence-wise aux loss."""
+    E, k = cfg.num_experts, cfg.top_k
+    logits = x.astype(jnp.float32) @ p["router"]
+    top_w, top_i, probs = router_topk(logits, k, cfg.norm_topk_prob,
+                                      cfg.routed_scaling_factor)
+    if cfg.router_aux == "seq":
+        aux = seq_aux_loss(probs, top_i, E, seq_len or x.shape[0])
+    else:
+        aux = load_balance_loss(probs, top_i, E)
+    return top_w, top_i, aux
+
+
+# rows of held copies computed at a time. Every step of the scan, run or
+# skipped, carries the (T, D) float32 sum, so fewer and larger chunks cost
+# less: on a TPU v5e, 2 x 4096 tokens of DeepSeek-V2-Lite's share train in
+# 588 ms a round with 4096 rows against 671 ms with 2048
+HELD_CHUNK = 4096
+
+
+def moe_held(p, cfg: ModelConfig, x: jax.Array, top_w: jax.Array,
+             top_i: jax.Array, offset=None
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless output of the held experts on flattened tokens x (T, D).
+
+    ``p`` holds ``held`` expert slabs (E_held, D, F) for the global
+    experts ``[offset, offset + held)`` (``offset`` may be traced, as
+    under shard_map); copies routed to other experts add nothing.
+    Returns (y (T, D), held copy count, largest held expert's count over
+    the held mean).
+
+    The held copies, sorted by expert, are computed ``HELD_CHUNK`` rows at
+    a time by a scan whose steps past the held count do nothing, so the
+    work and the live memory follow the held copies: no capacity, no drop,
+    and no buffer sized to every copy. Each step is rematerialised in the
+    backward pass.
+    """
+    T, D = x.shape
+    k = top_i.shape[1]
+    H = p["w_gate"].shape[0]
+    C = min(HELD_CHUNK, T * min(k, H))
+    n_chunks = -(-T * min(k, H) // C)       # every copy that can be held
+    offset = cfg.expert_offset if offset is None else offset
+    with jax.named_scope(MOE_ROUTE):
+        local = top_i.reshape(-1) - offset
+        key = jnp.where((local >= 0) & (local < H), local, H)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.zeros((H + 1,), jnp.int32).at[key].add(1)[:H]
+        ends = jnp.cumsum(group_sizes)
+        starts = ends - group_sizes
+        n_held = ends[-1]
+        pad = n_chunks * C - T * k
+        rows_all = order // k
+        w_all = top_w.reshape(-1)[order]
+        if pad > 0:
+            rows_all = jnp.pad(rows_all, (0, pad))
+            w_all = jnp.pad(w_all, (0, pad))
+    wg = p["w_gate"].astype(x.dtype)
+    wu = p["w_up"].astype(x.dtype)
+    wd = p["w_down"].astype(x.dtype)
+
+    def compute(a, acc):
+        with jax.named_scope(MOE_ROUTE):
+            rows = jax.lax.dynamic_slice_in_dim(rows_all, a, C)
+            w = jax.lax.dynamic_slice_in_dim(w_all, a, C)
+            # the rows of each held expert that fall in [a, a + C)
+            gs = (jnp.clip(ends, a, a + C) - jnp.clip(starts, a, a + C))
+            # rows past the last group: the TPU's ragged_dot leaves them
+            # uninitialised in its output and in the cotangent it sends
+            # back, so they are masked on the way in and on the way out
+            live = (a + jnp.arange(C) < n_held)[:, None]
+            xs = jnp.where(live, x[rows], 0)                   # (C, D)
+        with jax.named_scope(MOE_EXPERTS):
+            g = jnp.where(live, jax.lax.ragged_dot(
+                xs, wg, gs, preferred_element_type=F32), 0.0)
+            u = jnp.where(live, jax.lax.ragged_dot(
+                xs, wu, gs, preferred_element_type=F32), 0.0)
+            h = nn.swiglu(g, u).astype(x.dtype)
+            out = jnp.where(live, jax.lax.ragged_dot(
+                h, wd, gs, preferred_element_type=F32), 0.0)
+        with jax.named_scope(MOE_ROUTE):
+            return acc.at[rows].add(out * w[:, None])
+
+    @jax.checkpoint
+    def step(acc, c):
+        a = c * C
+        return jax.lax.cond(a < n_held, compute, lambda a, acc: acc,
+                            a, acc), None
+
+    y, _ = jax.lax.scan(step, jnp.zeros((T, D), F32), jnp.arange(n_chunks))
+    load = jnp.max(group_sizes) * H / jnp.maximum(n_held, 1)
+    return y.astype(x.dtype), n_held.astype(F32), load.astype(F32)
 
 
 def moe_local(p, cfg: ModelConfig, x: jax.Array,
-              f_slice: Optional[Tuple[int, int]] = None
-              ) -> Tuple[jax.Array, MoEStats]:
-    """Single-device MoE on flattened tokens x: (T, D) -> (T, D).
-
-    ``f_slice=(start, size)`` restricts expert hidden dims to a d_ff slice —
-    used by the tensor-parallel wrapper (caller psums the partial output).
-    """
-    T, D = x.shape
-    E, k = cfg.num_experts, cfg.top_k
-    C = int(max(8, round(T * k / E * cfg.capacity_factor)))
-
-    logits = x.astype(jnp.float32) @ p["router"]
-    top_w, top_i, probs = router_topk(logits, k)
-    aux = load_balance_loss(probs, top_i, E)
-
-    st, se, pos, keep, order = dispatch_indices(top_i, C, E)
-    flat_w = top_w.reshape(-1)[order]
-
-    # Scatter kept copies into the (E*C, D) buffer (dummy row E*C for drops).
-    idx = jnp.where(keep, se * C + pos, E * C)
-    buf = jnp.zeros((E * C + 1, D), x.dtype).at[idx].set(x[st])
-    buf = buf[:-1].reshape(E, C, D)
-
-    wg, wu, wd = p["w_gate"], p["w_up"], p["w_down"]
-    if f_slice is not None:
-        s0, sz = f_slice
-        wg = jax.lax.dynamic_slice_in_dim(wg, s0, sz, 2)
-        wu = jax.lax.dynamic_slice_in_dim(wu, s0, sz, 2)
-        wd = jax.lax.dynamic_slice_in_dim(wd, s0, sz, 1)
-    g = jnp.einsum("ecd,edf->ecf", buf, wg.astype(x.dtype))
-    u = jnp.einsum("ecd,edf->ecf", buf, wu.astype(x.dtype))
-    h = nn.swiglu(g, u)
-    out_buf = jnp.einsum("ecf,efd->ecd", h, wd.astype(x.dtype))
-
-    # Gather copies back, weight, and combine per token.
-    out_flat = out_buf.reshape(E * C, D)
-    y_copies = jnp.where(keep[:, None], out_flat[jnp.where(
-        keep, se * C + pos, 0)], 0.0)
-    y_copies = y_copies * flat_w[:, None].astype(x.dtype)
-    y = jnp.zeros((T, D), x.dtype).at[st].add(y_copies)
-
-    dropped = 1.0 - jnp.sum(keep.astype(jnp.float32)) / (T * k)
-    return y, MoEStats(aux_loss=aux, dropped_frac=dropped)
+              seq_len: Optional[int] = None) -> Tuple[jax.Array, MoEStats]:
+    """Single-device MoE on flattened tokens x: (T, D) -> (T, D), over
+    the experts ``p`` holds. ``seq_len`` splits T into sequences for the
+    sequence-wise aux loss."""
+    with jax.named_scope(MOE_ROUTE):
+        top_w, top_i, aux = route(p, cfg, x, seq_len)
+    y, copies, load = moe_held(p, cfg, x, top_w, top_i)
+    return y, MoEStats(aux, jnp.zeros((), F32), copies, load)
 
 
 def moe_forward(p, cfg: ModelConfig, x: jax.Array, shard_ctx=None
@@ -148,11 +225,12 @@ def moe_forward(p, cfg: ModelConfig, x: jax.Array, shard_ctx=None
     B, S, D = x.shape
     x_flat = x.reshape(B * S, D)
     if shard_ctx is None or shard_ctx.mesh is None:
-        y_flat, stats = moe_local(p, cfg, x_flat)
+        y_flat, stats = moe_local(p, cfg, x_flat, seq_len=S)
     else:
         from repro.dist import moe_sharded  # local import: avoid cycle
-        y_flat, stats = moe_sharded(p, cfg, x_flat, shard_ctx)
+        y_flat, stats = moe_sharded(p, cfg, x_flat, shard_ctx, seq_len=S)
     y = y_flat.reshape(B, S, D)
     if cfg.num_shared_experts:
-        y = y + mlp_forward(p["shared"], x)
+        with jax.named_scope(MOE_SHARED):
+            y = y + mlp_forward(p["shared"], x)
     return y, stats

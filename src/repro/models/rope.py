@@ -1,10 +1,12 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL M-RoPE."""
+"""Rotary position embeddings: standard RoPE, YaRN and Qwen2-VL M-RoPE."""
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_freqs(head_dim: int, theta: float = 1e4) -> jax.Array:
@@ -13,17 +15,65 @@ def rope_freqs(head_dim: int, theta: float = 1e4) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 1e4
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor, 0.1 m ln(s) + 1 (1 for s <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_max_pos: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``).
+
+    Frequencies that turn fewer than ``beta_slow`` times over the original
+    context are interpolated (divided by ``factor``); those that turn more
+    than ``beta_fast`` times are kept; a linear ramp blends the band in
+    between. A numpy constant of shape (dim // 2,).
+    """
+    half = dim // 2
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / factor
+
+    def corr(rotations):
+        return dim * math.log(original_max_pos / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_spec(cfg, dim: int) -> Tuple[Optional[np.ndarray], float]:
+    """(inverse frequencies, cos/sin scale) of ``cfg``'s rotary embedding
+    over ``dim`` columns: (None, 1.0) for plain RoPE."""
+    if not cfg.yarn_factor:
+        return None, 1.0
+    inv = yarn_freqs(dim, cfg.rope_theta, cfg.yarn_factor,
+                     cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                     cfg.yarn_beta_slow)
+    return inv, (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+                 / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 1e4,
+               inv_freq: Optional[np.ndarray] = None, mscale: float = 1.0
                ) -> jax.Array:
     """Standard RoPE. x: (..., S, H, hd); positions: broadcastable to (..., S).
 
     Uses the "rotate half" convention: pairs (x[..., :half], x[..., half:]).
+    ``inv_freq`` replaces the plain frequencies (YaRN) and ``mscale``
+    multiplies cos and sin.
     """
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)                       # (half,)
+    inv = rope_freqs(hd, theta) if inv_freq is None \
+        else jnp.asarray(inv_freq)                    # (half,)
     angles = positions[..., None].astype(jnp.float32) * inv  # (..., S, half)
     cos = jnp.cos(angles)[..., None, :]               # (..., S, 1, half)
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

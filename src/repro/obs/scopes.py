@@ -30,3 +30,9 @@ PROTOCOL_UPDATE = "protocol.update"      # w - eta * g
 TRAIN_GRADS = "train.grads"
 TRAIN_AGGREGATE = "train.aggregate"
 TRAIN_OPTIMIZER = "train.optimizer"
+
+# models/: the layers the MoE + MLA configurations force
+MOE_ROUTE = "moe.route"        # router, top-k, aux, sort, gather, combine
+MOE_EXPERTS = "moe.experts"    # the held experts' grouped products
+MOE_SHARED = "moe.shared"      # the shared experts
+MLA_ATTENTION = "mla.attention"  # MLA from the projections through wo

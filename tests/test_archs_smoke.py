@@ -112,3 +112,20 @@ def test_exact_assigned_configs():
     assert (q6.num_layers, q6.d_model, q6.num_heads, q6.num_kv_heads,
             q6.d_ff, q6.vocab_size) == (28, 1024, 16, 8, 3072, 151936)
     assert q6.qk_norm
+
+
+def test_deepseek_v2_lite_published_config():
+    """DeepSeek-V2-Lite as its config.json publishes it."""
+    d = get_config("deepseek-v2-lite")
+    assert (d.num_layers, d.d_model, d.num_heads, d.vocab_size, d.d_ff) == \
+        (27, 2048, 16, 102400, 10944)
+    assert (d.num_experts, d.top_k, d.num_shared_experts, d.moe_d_ff,
+            d.first_dense_layers) == (64, 6, 2, 1408, 1)
+    assert (d.kv_lora_rank, d.q_lora_rank, d.qk_nope_head_dim,
+            d.qk_rope_head_dim, d.v_head_dim) == (512, 0, 128, 64, 128)
+    assert (d.yarn_factor, d.yarn_original_max_pos, d.yarn_beta_fast,
+            d.yarn_beta_slow, d.yarn_mscale, d.yarn_mscale_all_dim) == \
+        (40.0, 4096, 32.0, 1.0, 0.707, 0.707)
+    assert not d.norm_topk_prob and d.routed_scaling_factor == 1.0
+    assert d.router_aux == "seq"
+    assert d.held_experts == 64 and not d.tie_embeddings

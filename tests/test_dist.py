@@ -208,7 +208,7 @@ def test_moe_sharded_matches_local():
 
 
 def test_expert_parallel_matches_local():
-    """EP all-to-all dispatch == local MoE oracle (dropless capacity)."""
+    """EP dispatch == local MoE oracle (both dropless)."""
     _run_subprocess("""
         import dataclasses as dc
         import jax, jax.numpy as jnp, numpy as np
@@ -218,7 +218,7 @@ def test_expert_parallel_matches_local():
         from repro.models.nn import split_params
 
         cfg = reduced(get_config("qwen3-moe-30b-a3b"))
-        cfg = dc.replace(cfg, num_experts=4, top_k=2, capacity_factor=8.0)
+        cfg = dc.replace(cfg, num_experts=4, top_k=2)
         values, _ = split_params(M.init_params(cfg, jax.random.PRNGKey(0)))
         p = jax.tree.map(lambda a: a[0], values["layers"])["moe"]
         x = jax.random.normal(jax.random.PRNGKey(2), (8, 16, cfg.d_model))
