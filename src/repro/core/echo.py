@@ -203,17 +203,65 @@ def wire_norm_ratio(
                      g_norm / jnp.maximum(echo_norm, 1e-30), 0.0)
 
 
+def _recon_buckets(n: int) -> Tuple[int, ...]:
+    """Row counts a bounded reconstruction may read: none, then the
+    projection's :func:`row_buckets`."""
+    return (0,) + row_buckets(n)
+
+
+def _ref_extent(ref_mask: jax.Array, used: jax.Array) -> jax.Array:
+    """Rows from the table's top that hold all of I: one past I's highest
+    sender ID when the reconstruction is used, 0 otherwise."""
+    top = jnp.max(jnp.where(ref_mask, jnp.arange(1, ref_mask.shape[0] + 1), 0))
+    return jnp.where(used, top, 0)
+
+
+def recon_rows_read(ref_mask: jax.Array, used: jax.Array) -> jax.Array:
+    """Rows ``reconstruct_echo(..., used=used)`` reads from the (n, d)
+    table: the smallest of :func:`_recon_buckets` holding I."""
+    buckets = _recon_buckets(ref_mask.shape[0])
+    return jnp.asarray(buckets, jnp.int32)[
+        _bucket_index(_ref_extent(ref_mask, used), buckets)]
+
+
 def reconstruct_echo(
     G_server: jax.Array,
     ref_mask: jax.Array,
     k: jax.Array,
     x: jax.Array,
+    used: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Server-side g~ = k * A_I x (paper line 39).
 
     ``G_server`` is the server's (n, d) gradient table; ``ref_mask`` marks I.
     Coefficients outside I are zeroed defensively (a Byzantine echo may ship
     junk there).
+
+    ``used`` (traced bool) says whether the caller will use the result.
+    The reconstruction then reads only the table's first b rows, b the
+    smallest of :func:`_recon_buckets` that holds I (chosen on the
+    device), and none when the result is not used: zeros come back. The
+    same sum over fewer zero terms. The rows are a static slice from the
+    top: on a TPU v5e it streams at the rate of a whole-table pass, where
+    rows picked by ID cost a whole 8-row tile group each at a dynamic
+    offset and a gather copies the table. An honest I is the admitted
+    raws, which begin with slot 0, so its highest ID bounds it closely.
     """
     xm = x * ref_mask
-    return k * (xm @ (G_server * ref_mask[:, None]))
+    if used is None:
+        return k * (xm @ (G_server * ref_mask[:, None]))
+    buckets = _recon_buckets(G_server.shape[0])
+
+    def branch(b):
+        def run(G, ref_mask, k, xm):
+            if b == 0:
+                return jnp.zeros(G.shape[1:], G.dtype)
+            if b == G.shape[0]:
+                return k * (xm @ (G * ref_mask[:, None]))
+            # k folded into the b coefficients: no second pass over g~
+            return (k * xm[:b]) @ (G[:b] * ref_mask[:b, None])
+        return run
+
+    return jax.lax.switch(_bucket_index(_ref_extent(ref_mask, used), buckets),
+                          [branch(b) for b in buckets], G_server, ref_mask, k,
+                          xm)

@@ -62,8 +62,8 @@ from . import aggregators as agg_lib
 from .byzantine import AttackPlan
 from .cgc import cgc_aggregate, cgc_aggregate_known_bad
 from .echo import (echo_decision_from_projection, independent_from_projection,
-                   project_onto_span, reconstruct_echo, rows_read,
-                   wire_norm_ratio)
+                   project_onto_span, reconstruct_echo, recon_rows_read,
+                   rows_read, wire_norm_ratio)
 from .types import (MSG_ECHO, MSG_RAW, MSG_SILENT, ProtocolConfig, RoundStats,
                     ServerState)
 
@@ -89,6 +89,8 @@ class CommState(NamedTuple):
     ref_id: Any = None    # (n,) int32 sender ID of each R row (n: empty row)
     n_ref: Any = None     # () int32 rows of R admitted so far
     proj_rows: Any = None  # () int32 rows the slot projections read so far
+    # both slot bodies:
+    recon_rows: Any = None  # () int32 rows the server's reconstructions read
 
 
 def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
@@ -189,12 +191,17 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
 
         # --- Server processes the message (lines 33-41) ----------------------
         # Echo referencing an unheard worker == provable Byzantine (l. 36-37).
+        # The reconstruction reads the table's rows up to I's highest ID,
+        # and none in a slot whose echo is not used (raw, silent or
+        # detected), where g_echo is zero.
         bad_ref = jnp.any(echo_ref & ~st.received)
         detected_i = is_echo & bad_ref
-        g_echo = reconstruct_echo(st.G, echo_ref & st.received, echo_k, echo_x)
-        g_tilde = jnp.where(is_raw, raw_wire,
-                            jnp.where(is_echo & ~bad_ref, g_echo,
-                                      jnp.zeros((d,), grads.dtype)))
+        use_echo = is_echo & ~bad_ref
+        ref_rows = echo_ref & st.received
+        g_echo = reconstruct_echo(st.G, ref_rows, echo_k, echo_x,
+                                  used=use_echo)
+        recon_rows = st.recon_rows + recon_rows_read(ref_rows, use_echo)
+        g_tilde = jnp.where(is_raw, raw_wire, g_echo)
         g_tilde = channel.deliver(st.chan, i, g_tilde)
         G = st.G.at[i].set(g_tilde)
         received = st.received.at[i].set(mode != MSG_SILENT)
@@ -226,7 +233,8 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     ef = jnp.where(use_ef & is_raw, st.ef.at[i].set(ef_row), st.ef)
 
     return CommState(G, received, detected, R, rmask, bits, echoed,
-                     faded_acc, chan, ef, ref_id, n_ref, proj_rows)
+                     faded_acc, chan, ef, ref_id, n_ref, proj_rows,
+                     recon_rows)
 
 
 def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
@@ -313,10 +321,12 @@ def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         # within hearing range, so they never trip this.
         bad_ref = jnp.any(echo_ref & (~st.received | ~hear[i]))
         detected_i = is_echo & bad_ref
-        g_echo = reconstruct_echo(st.G, echo_ref & st.received, echo_k, echo_x)
-        g_tilde = jnp.where(is_raw, raw_wire,
-                            jnp.where(is_echo & ~bad_ref, g_echo,
-                                      jnp.zeros((d,), grads.dtype)))
+        use_echo = is_echo & ~bad_ref
+        ref_rows = echo_ref & st.received
+        g_echo = reconstruct_echo(st.G, ref_rows, echo_k, echo_x,
+                                  used=use_echo)
+        recon_rows = st.recon_rows + recon_rows_read(ref_rows, use_echo)
+        g_tilde = jnp.where(is_raw, raw_wire, g_echo)
         g_tilde = channel.deliver(st.chan, i, g_tilde)
         G = st.G.at[i].set(g_tilde)
         received = st.received.at[i].set(mode != MSG_SILENT)
@@ -343,7 +353,7 @@ def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     ef = jnp.where(use_ef & is_raw, st.ef.at[i].set(ef_row), st.ef)
 
     return CommState(G, received, detected, R, rmask, bits, echoed,
-                     faded_acc, chan, ef)
+                     faded_acc, chan, ef, recon_rows=recon_rows)
 
 
 def communication_phase(
@@ -391,6 +401,7 @@ def communication_phase(
         faded=jnp.zeros((n,), bool),
         chan=comm.channel.init(chan_key),
         ef=ef if ef is not None else jnp.zeros((n, d), grads.dtype),
+        recon_rows=jnp.int32(0),
     )
     if shared:
         st = st._replace(ref_id=jnp.full((n,), n, jnp.int32),
@@ -420,6 +431,7 @@ def communication_phase(
         rank_R=jnp.sum(rmask_any.astype(jnp.int32)),
         n_faded=jnp.sum(st.faded.astype(jnp.int32)),
         proj_rows=st.proj_rows,
+        recon_rows=st.recon_rows,
     )
     if ef is not None:
         return server, stats, st.ef
@@ -533,7 +545,8 @@ def run_training(
 
     Returns a dict of per-round traces: dist2 (||w-w*||^2), value, bits,
     n_echo, n_detected, proj_rows (reference rows the slot projections
-    read; 0 on the point-to-point path). A
+    read) and recon_rows (table rows the server's echo reconstructions
+    read), each 0 on the point-to-point path. A
     :class:`~repro.comm.CommLedger` passed as ``ledger`` gets one record
     per simulated round (the simulation's reporting hook into the shared
     accounting surface).
@@ -601,12 +614,14 @@ def run_training(
             n_echo = stats.n_echo
             n_det = stats.n_detected
             proj_rows = _proj_rows(stats, n)
+            recon_rows = stats.recon_rows
         else:
             w_next, bits = pointwise_round(cfg, w, grads, byz_mask, plan,
                                            aggregator, comm)
             n_echo = jnp.int32(0)
             n_det = jnp.int32(0)
             proj_rows = jnp.int32(0)
+            recon_rows = jnp.int32(0)
         out = dict(
             dist2=jnp.sum((w - cost.w_star) ** 2),
             value=cost.value(w),
@@ -614,6 +629,7 @@ def run_training(
             n_echo=n_echo,
             n_detected=n_det,
             proj_rows=proj_rows,
+            recon_rows=recon_rows,
         )
         return (w_next, ef), out
 
@@ -754,7 +770,8 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
     ef = jnp.zeros((n, d), w0.dtype) if error_feedback else None
     last_obs = None
     trace = {k: [] for k in
-             ("dist2", "value", "bits", "n_echo", "n_detected", "proj_rows")}
+             ("dist2", "value", "bits", "n_echo", "n_detected", "proj_rows",
+              "recon_rows")}
     keys = jax.random.split(key, rounds)
     eager = not isinstance(w0, jax.core.Tracer)
     with _host_span("protocol.rounds", eager):
@@ -819,6 +836,7 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
             trace["n_echo"].append(jnp.int32(n_echo))
             trace["n_detected"].append(stats.n_detected)
             trace["proj_rows"].append(_proj_rows(stats, n))
+            trace["recon_rows"].append(stats.recon_rows)
             w = w_next
     if eager:
         obs.counter("protocol.rounds_simulated", rounds)

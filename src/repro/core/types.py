@@ -53,6 +53,8 @@ class RoundStats(NamedTuple):
     proj_rows: Any = None        # () int32, reference rows the round's slot
                                  # projections read, summed over slots (None
                                  # under per-worker masks)
+    recon_rows: Any = None       # () int32, table rows the server's echo
+                                 # reconstructions read, summed over slots
 
 
 class ProtocolConfig(NamedTuple):

@@ -6,7 +6,7 @@ import pytest
 
 from repro.core.echo import (echo_decision, echo_decision_from_projection,
                              is_linearly_independent, project_onto_span,
-                             reconstruct_echo, rows_read)
+                             reconstruct_echo, recon_rows_read, rows_read)
 
 
 def _setup(n=6, d=40, k=3, seed=0):
@@ -61,21 +61,24 @@ def test_reconstruction_preserves_norm():
     R, mask, g = _setup(seed=2)
     dec = echo_decision(R, mask, g, r=10.0)   # force echo
     assert bool(dec.send_echo)
-    g_rec = reconstruct_echo(R, mask, dec.k, dec.x)
-    assert float(jnp.linalg.norm(g_rec)) == pytest.approx(
-        float(jnp.linalg.norm(g)), rel=1e-4)
-    # direction == echo direction
-    cos = float((g_rec @ dec.echo) /
-                (jnp.linalg.norm(g_rec) * jnp.linalg.norm(dec.echo)))
-    assert cos == pytest.approx(1.0, abs=1e-5)
+    # the whole-table expression and the row bucket the server runs
+    for used in (None, jnp.bool_(True)):
+        g_rec = reconstruct_echo(R, mask, dec.k, dec.x, used=used)
+        assert float(jnp.linalg.norm(g_rec)) == pytest.approx(
+            float(jnp.linalg.norm(g)), rel=1e-4)
+        # direction == echo direction
+        cos = float((g_rec @ dec.echo) /
+                    (jnp.linalg.norm(g_rec) * jnp.linalg.norm(dec.echo)))
+        assert cos == pytest.approx(1.0, abs=1e-5)
 
 
 def test_reconstruction_masks_extra_coefficients():
     R, mask, g = _setup(seed=3)
     x = jnp.ones((6,))                        # junk outside mask
-    g1 = reconstruct_echo(R, mask, 1.0, x)
-    g2 = reconstruct_echo(R, mask, 1.0, x * mask)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-6)
+    for used in (None, jnp.bool_(True)):
+        g1 = reconstruct_echo(R, mask, 1.0, x, used=used)
+        g2 = reconstruct_echo(R, mask, 1.0, x * mask, used=used)
+        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-6)
 
 
 def test_linear_independence_detection():
@@ -124,3 +127,45 @@ def test_bucketed_projection_matches_full_table(k, bucket):
     np.testing.assert_allclose(float(got.residual), float(ref.residual),
                                rtol=1e-4, atol=1e-5)
     assert bool(got.send_echo) == bool(ref.send_echo) == (k > 0)
+
+
+@pytest.mark.parametrize("m,top,bucket", [
+    (0, 0, 0), (1, 1, 8), (1, 8, 8), (8, 8, 8), (9, 9, 16), (5, 14, 16),
+    (16, 16, 16), (17, 17, 32), (9, 32, 32), (9, 33, 40), (40, 40, 40),
+    (2, 40, 40)])
+def test_bucketed_reconstruction_matches_full_table(m, top, bucket):
+    """A used echo reads the table's first rows up to the bucket (0, 8,
+    16, 32, then n = 40) that holds I's highest ID: m IDs out of order
+    below ``top``, junk coefficients outside I and finite junk in the
+    rows outside I give the whole-table expression's g~."""
+    n, d = 40, 256
+    key = jax.random.PRNGKey(100 * m + top)
+    k_tab, k_ids, k_x = jax.random.split(key, 3)
+    ids = (jax.random.permutation(k_ids, top - 1)[:m - 1] if m else
+           jnp.zeros(0, jnp.int32))
+    ids = jnp.concatenate([ids, jnp.full(min(m, 1), top - 1)])
+    mask = jnp.zeros(n, bool).at[ids].set(True)
+    assert int(mask.sum()) == m
+    G = 3.0 * jax.random.normal(k_tab, (n, d))      # junk outside I too
+    x = jax.random.normal(k_x, (n,))                # junk outside I too
+    k = jnp.float32(1.7)
+    used = jnp.bool_(True)
+    assert int(recon_rows_read(mask, used)) == bucket
+    want = reconstruct_echo(G, mask, k, x)
+    got = jax.jit(reconstruct_echo)(G, mask, k, x, used)
+    scale = float(jnp.max(jnp.abs(want))) if m else 1.0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6 * scale)
+
+
+def test_unused_reconstruction_reads_no_row():
+    """An echo the server will not use (a raw, silent or detected slot)
+    reads no row and returns zeros, whatever I holds."""
+    n, d = 40, 64
+    G = jax.random.normal(jax.random.PRNGKey(0), (n, d))
+    mask = jnp.arange(n) < 30
+    used = jnp.bool_(False)
+    assert int(recon_rows_read(mask, used)) == 0
+    got = jax.jit(reconstruct_echo)(G, mask, jnp.float32(2.0), jnp.ones(n),
+                                    used)
+    np.testing.assert_array_equal(np.asarray(got), np.zeros(d, np.float32))

@@ -1,4 +1,6 @@
 """End-to-end Echo-CGC protocol behaviour (Algorithm 1)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,9 +15,11 @@ from repro.core import byzantine, costfns, theory
 from repro.core.echo import row_buckets
 from repro.core.protocol import (communication_phase, echo_cgc_round,
                                  pointwise_round, run_training)
-from repro.core.types import MSG_RAW, ProtocolConfig, raw_bits
+import repro.core.protocol as protocol
+from repro.core.types import (MSG_ECHO, MSG_RAW, MSG_SILENT, ProtocolConfig,
+                              raw_bits)
 from repro.net import HearingGraph, complete_graph
-from repro.obs.scopes import ECHO_PROJECT
+from repro.obs.scopes import ECHO_PROJECT, PROTOCOL_SLOT, SLOT_SERVER
 
 
 def _cfg(n=12, f=1, r=0.3, eta=0.01):
@@ -258,7 +262,8 @@ def test_proj_rows_counts_bucket_rows():
 
 def _dot_reads(jaxpr, stack, last_bucket, n_buckets, out):
     """(name stack, rows of each 2-D operand, in the widest bucket) of
-    every dot_general in ``jaxpr`` and the jaxprs inside it."""
+    every dot_general in ``jaxpr`` and the jaxprs inside it; a switch
+    with one of the ``n_buckets`` branch counts is a bucket switch."""
     for eqn in jaxpr.eqns:
         here = "/".join(p for p in (stack, str(eqn.source_info.name_stack))
                         if p)
@@ -268,7 +273,7 @@ def _dot_reads(jaxpr, stack, last_bucket, n_buckets, out):
         if eqn.primitive.name == "cond":
             branches = eqn.params["branches"]
             for b, br in enumerate(branches):
-                widest = (len(branches) == n_buckets
+                widest = (len(branches) in n_buckets
                           and b == len(branches) - 1)
                 _dot_reads(br.jaxpr, here, last_bucket or widest,
                            n_buckets, out)
@@ -286,10 +291,108 @@ def test_shared_slot_projection_reads_only_its_bucket():
     jaxpr = jax.make_jaxpr(
         lambda g: communication_phase(cfg, g, byz, plan))(grads).jaxpr
     reads = []
-    _dot_reads(jaxpr, "", False, len(row_buckets(n)), reads)
+    _dot_reads(jaxpr, "", False, {len(row_buckets(n))}, reads)
     proj = [(rows, widest) for stack, rows, widest in reads
             if ECHO_PROJECT in stack.split("/")]
     assert any(8 in rows for rows, _ in proj)
     assert any(widest for _, widest in proj)
     full = [(rows, widest) for rows, widest in proj if n in rows]
     assert full and all(widest for _, widest in full), full
+
+
+@pytest.mark.parametrize("graph", ["shared", "per_worker"])
+def test_slot_reconstruction_reads_only_its_bucket(graph):
+    """Structural guard: in both slot bodies no dot_general under the
+    server scope reads all n rows of G, except in the branch whose
+    bucket is the whole table; the other buckets read the table's top
+    rows (8 of them in the smallest)."""
+    cfg, grads, byz, plan = _rising_rank_round(n=20, d=64, n_byz=2)
+    n = cfg.n
+    net = (None if graph == "shared" else
+           HearingGraph(adj=complete_graph(n).adj, strict=True))
+    jaxpr = jax.make_jaxpr(
+        lambda g: communication_phase(cfg, g, byz, plan, net=net))(grads).jaxpr
+    reads = []
+    _dot_reads(jaxpr, "", False, {len(row_buckets(n)) + 1}, reads)
+    server = [(rows, widest) for stack, rows, widest in reads
+              if f"{PROTOCOL_SLOT}/{SLOT_SERVER}" in stack]
+    assert any(8 in rows for rows, _ in server), server
+    assert any(widest for _, widest in server)
+    full = [(rows, widest) for rows, widest in server if n in rows]
+    assert full and all(widest for _, widest in full), full
+
+
+def _whole_table_reconstruction(monkeypatch):
+    """Make the slot loops reconstruct every echo from the whole table,
+    as before the row bucket, zero where it is not used (the slot loops
+    look the function up when they are traced)."""
+    whole = protocol.reconstruct_echo
+    monkeypatch.setattr(
+        protocol, "reconstruct_echo",
+        lambda G, ref, k, x, used: jnp.where(used, whole(G, ref, k, x), 0.0))
+
+
+@pytest.mark.parametrize("refs", ["every_received", "unheard"])
+def test_bucketed_reconstruction_matches_whole_table_round(refs, monkeypatch):
+    """A Byzantine echo in the last slot that references every received
+    row reads the widest bucket, all n rows; one that also references its
+    own, unheard slot is detected and reads none. The server's table,
+    detections and bits are those of the whole-table reconstruction."""
+    cfg, grads, byz, plan = _rising_rank_round()
+    n = cfg.n
+    last = n - 1
+    byz = byz.at[last].set(True)
+    ref = (jnp.arange(n) < last) | ((jnp.arange(n) == last)
+                                    & (refs == "unheard"))
+    plan = dataclasses.replace(
+        plan, mode=plan.mode.at[last].set(MSG_ECHO),
+        echo_ref=plan.echo_ref.at[last].set(ref),
+        echo_x=plan.echo_x.at[last].set(
+            jax.random.normal(jax.random.PRNGKey(7), (n,))),
+        echo_k=plan.echo_k.at[last].set(1.5))
+    silent = dataclasses.replace(plan, mode=plan.mode.at[last].set(MSG_SILENT))
+    srv, st = communication_phase(cfg, grads, byz, plan)
+    _, st_silent = communication_phase(cfg, grads, byz, silent)
+    assert bool(srv.detected[last]) == (refs == "unheard")
+    assert int(st.n_detected) == (refs == "unheard")
+    # the last slot's reconstruction read n rows, or none
+    assert (int(st.recon_rows) - int(st_silent.recon_rows)
+            == (n if refs == "every_received" else 0))
+
+    _whole_table_reconstruction(monkeypatch)
+    srv_w, st_w = communication_phase(cfg, grads, byz, plan)
+    np.testing.assert_array_equal(np.asarray(srv.detected),
+                                  np.asarray(srv_w.detected))
+    np.testing.assert_array_equal(np.asarray(srv.received),
+                                  np.asarray(srv_w.received))
+    np.testing.assert_array_equal(np.asarray(st.bits_sent),
+                                  np.asarray(st_w.bits_sent))
+    np.testing.assert_allclose(np.asarray(srv.G), np.asarray(srv_w.G),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_recon_rows_counts_bucket_rows():
+    """n=20 identical gradients: slot 0 raw, the other 19 echo against
+    rank 1 (ID 0), so each echo reads the 8-row bucket and the raw none:
+    19 x 8 = 152 rows, in both slot bodies, in the round's stats and in
+    run_training's per-round trace, scanned and under a dynamic policy
+    (0 point to point)."""
+    n, d = 20, 24
+    strict = HearingGraph(adj=complete_graph(n).adj, strict=True)
+    for net in (None, strict):
+        _, stats = communication_phase(_cfg(n=n), _identical_grads(n, d),
+                                       jnp.zeros(n, bool), _no_plan(n, d),
+                                       net=net)
+        assert int(stats.rank_R) == 1 and int(stats.n_echo) == n - 1
+        assert int(stats.recon_rows) == 152, net
+
+    key = jax.random.PRNGKey(0)
+    cost = costfns.quadratic(key, d=d, sigma=0.0)     # every worker equal
+    cfg = ProtocolConfig(n=n, f=0, r=0.3, eta=0.001)
+    args = (cfg, cost, byzantine.no_attack, jnp.zeros(n, bool), key,
+            jnp.ones(d))
+    for kw, want in (({}, 152), ({"policy": AdaptiveEchoPolicy()}, 152),
+                     ({"use_radio": False}, 0)):
+        trace = run_training(*args, rounds=3, **kw)
+        np.testing.assert_array_equal(np.asarray(trace["recon_rows"]),
+                                      [want] * 3, err_msg=str(kw))

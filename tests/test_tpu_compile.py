@@ -21,6 +21,7 @@ from repro.configs import get_config
 from repro.kernels import cgc_clip, codec_pack, decode_attention, ops
 from repro.models import model as M
 from repro.models.nn import split_params
+from repro.obs.scopes import ECHO_PROJECT, PROTOCOL_SLOT, SLOT_SERVER
 
 QWEN = get_config("qwen3-0.6b")
 
@@ -129,9 +130,11 @@ def test_paged_decode_attention_compiles(one_chip):
 def test_shared_slot_loop_compiles_at_paper_width(one_chip):
     """The paper headline's slot loop (n=100 workers, f=10 sign-flippers,
     d = 2**21, an 839 MB table) on the shared reference set: the row
-    bucket switch of its projection compiles for the chip, and the
-    compact R adds no copy of the table to the plan (the server's G and
-    R are two tables; another would take the scratch past 2.25)."""
+    bucket switches of its projection and of the server's reconstruction
+    compile for the chip, and neither the compact R nor the
+    reconstruction's row buckets add a copy of the table to the plan
+    (the server's G and R are two tables; another would take the scratch
+    past 2.25)."""
     from repro.core import byzantine
     from repro.core.protocol import communication_phase
     from repro.core.types import ProtocolConfig
@@ -144,11 +147,15 @@ def test_shared_slot_loop_compiles_at_paper_width(one_chip):
         plan = byzantine.sign_flip(jax.random.PRNGKey(0), grads, byz,
                                    None, None)
         server, stats = communication_phase(cfg, grads, byz, plan)
-        return server.G, stats.proj_rows
+        return server.G, stats.proj_rows, stats.recon_rows
 
     with jax.default_matmul_precision("high"):
         compiled = jax.jit(round_).lower(
             _spec((n, d), jnp.float32, one_chip)).compile()
-    assert "conditional" in compiled.as_text()
+    switches = [line for line in compiled.as_text().splitlines()
+                if " conditional(" in line]
+    assert any(f"{ECHO_PROJECT}/cond" in line for line in switches)
+    assert any(f"{PROTOCOL_SLOT}/{SLOT_SERVER}/cond" in line
+               for line in switches)
     table = 4 * n * d
     assert compiled.memory_analysis().temp_size_in_bytes < 2.25 * table
