@@ -20,50 +20,49 @@ count is bit-for-bit the paper's closed-form accounting.
 A note on the reference sets R_j: in the paper each worker keeps its own R_j,
 but every worker hears the same raw broadcasts in the same slot order and
 applies the same deterministic independence test — so R_j is exactly the
-shared in-order independent prefix known at slot j. We therefore keep ONE
-reference buffer and snapshot its mask per slot. The buffer is compact: the
-k-th raw admitted is row k, with its sender ID beside it, so a slot's
-projection reads only the rows that can be live (``echo.project_onto_span``
-with ``n_live``); the mask and the echo coefficients on the wire stay in
-sender-ID order.
+shared in-order independent prefix known at slot j. The slot loop therefore
+stores each admitted raw once and keeps *views* of it (``core/refset.py``,
+which alone knows the storage order): one view shared by every worker,
+whose projection reads only the rows that can be live, its coefficients
+in sender-ID order on the wire.
 (On a lossy channel a faded raw broadcast is skipped by *every* overhearer,
 so the reference set stays shared — it just grows more slowly. The
 independence test runs on the sender-side projection; quantization noise is
 treated as preserving independence.)
 
-That shared-mask argument holds exactly when the hearing graph is complete.
+That shared-view argument holds exactly when the hearing graph is complete.
 A partial topology (``repro.net``, DESIGN.md §15) breaks it: worker j only
 overhears the raws its radio reaches, so R_j really is per-worker. Passing
-``net=`` (a :class:`repro.net.HearingGraph`) switches the slot loop to an
-(n, n) per-worker mask table — each sender decides and echoes against its
-own mask, each receiver runs its own independence test, and the server
-(which hears every uplink slot regardless of worker-to-worker reach)
-additionally detects echoes referencing workers outside the sender's
-hearing set. ``net=None`` or a complete graph keeps the exact shared-mask
-code path, jaxpr and all.
+``net=`` (a :class:`repro.net.HearingGraph`) gives the same slot body n
+views — each sender decides and echoes against its own view, each receiver
+runs its own independence test, and the server (which hears every uplink
+slot regardless of worker-to-worker reach) detects echoes referencing
+workers outside the sender's hearing set, which on the complete graph
+flags nothing more. ``net=None`` or a complete graph keeps the one view.
 """
 from __future__ import annotations
 
 import contextlib
 import inspect
 from functools import partial
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
 from repro.comm import ChannelState, CommConfig, CommLedger, DEFAULT_COMM
-from repro.obs.scopes import (ECHO_PROJECT, PROTOCOL_AGGREGATE,
-                              PROTOCOL_GRADS, PROTOCOL_SLOT, PROTOCOL_UPDATE,
-                              SLOT_DECIDE, SLOT_OVERHEAR, SLOT_SERVER)
+from repro.obs.scopes import (PROTOCOL_AGGREGATE, PROTOCOL_GRADS,
+                              PROTOCOL_SLOT, PROTOCOL_UPDATE, SLOT_DECIDE,
+                              SLOT_OVERHEAR, SLOT_SERVER)
 
 from . import aggregators as agg_lib
+from . import refset
 from .byzantine import AttackPlan
 from .cgc import cgc_aggregate, cgc_aggregate_known_bad
 from .echo import (echo_decision_from_projection, independent_from_projection,
-                   project_onto_span, reconstruct_echo, recon_rows_read,
-                   rows_read, wire_norm_ratio)
+                   reconstruct_echo, recon_rows_read)
+from .refset import RefSet
 from .types import (MSG_ECHO, MSG_RAW, MSG_SILENT, ProtocolConfig, RoundStats,
                     ServerState)
 
@@ -74,29 +73,28 @@ class CommState(NamedTuple):
     G: jax.Array          # (n, d) server gradient table
     received: jax.Array   # (n,) bool
     detected: jax.Array   # (n,) bool
-    R: jax.Array          # (n, d) overheard raw gradients: row k = the k-th
-                          # admitted (shared mask); row = sender ID
-                          # (per-worker masks)
-    rmask: jax.Array      # (n,) bool shared reference mask — or (n, n)
-                          # per-worker masks (rmask[j] = worker j's view)
-                          # when a partial hearing graph is threaded in
+    ref: RefSet           # overheard raws and each worker's view of them
     bits: jax.Array       # (n,) float bits transmitted per worker
     echoed: jax.Array     # (n,) bool — worker sent an echo message
     faded: jax.Array      # (n,) bool — the channel faded this worker's slot
     chan: ChannelState    # broadcast-channel carry (fading PRNG + budget)
     ef: jax.Array         # (n, d) error-feedback residuals (zeros when off)
-    # shared mask only (None under per-worker masks):
-    ref_id: Any = None    # (n,) int32 sender ID of each R row (n: empty row)
-    n_ref: Any = None     # () int32 rows of R admitted so far
-    proj_rows: Any = None  # () int32 rows the slot projections read so far
-    # both slot bodies:
-    recon_rows: Any = None  # () int32 rows the server's reconstructions read
+    proj_rows: jax.Array  # () int32 rows the slot projections read so far
+    recon_rows: jax.Array  # () int32 rows the server's reconstructions read
 
 
 def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
           grads: jax.Array, byz_mask: jax.Array, plan: AttackPlan,
-          comm: CommConfig, use_ef: bool = False) -> CommState:
-    """One TDMA slot: worker i broadcasts; server + all workers process."""
+          comm: CommConfig, hear: jax.Array,
+          use_ef: bool = False) -> CommState:
+    """One TDMA slot: worker i broadcasts; the server and every worker
+    whose radio reaches i (``hear[j, i]``) process it.
+
+    The sender decides and echoes against its own view of R. The server
+    flags an echo that references a worker the sender could not have
+    heard. Each receiver tests the raw against its own view: with one
+    view that is the sender's, so the decision's projection serves it.
+    """
     n, d = grads.shape
     g_i = grads[i]
     is_byz = byz_mask[i]
@@ -104,22 +102,16 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
 
     with jax.named_scope(SLOT_DECIDE):
         # --- Worker i decides what to broadcast (lines 14-24) ----------------
-        # One Gram solve serves both the echo decision (Eq. 7) and the
-        # independence test (line 29): project the broadcast vector once.
-        # For honest workers raw_msg == g_i, so the decision is the paper's;
-        # for Byzantine workers every dec field is overridden by the plan.
-        # Only R's first n_ref rows are live, so the projection reads the
-        # smallest row bucket that holds them; its coefficients go back to
-        # sender-ID order, the order of the mask and of the wire.
+        # One Gram solve serves both the echo decision (Eq. 7) and, with
+        # one view, the independence test (line 29): project the broadcast
+        # vector once. For honest workers raw_msg == g_i, so the decision
+        # is the paper's; for Byzantine workers every dec field is
+        # overridden by the plan.
         raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
-        live = jnp.arange(n) < st.n_ref
-        x_live, proj = project_onto_span(st.R, live, raw_msg, cfg.ridge,
-                                         n_live=st.n_ref)
-        with jax.named_scope(ECHO_PROJECT):
-            x_proj = jnp.zeros_like(x_live).at[st.ref_id].set(x_live,
-                                                              mode="drop")
-        proj_rows = st.proj_rows + rows_read(st.n_ref, n)
-        dec = echo_decision_from_projection(x_proj, proj, st.rmask, raw_msg,
+        view = refset.view(st.ref, i)
+        x_proj, proj, rows = refset.project(st.ref, view, raw_msg, cfg.ridge)
+        proj_rows = st.proj_rows + rows
+        dec = echo_decision_from_projection(x_proj, proj, view, raw_msg,
                                             cfg.r)
         honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
         mode = jnp.where(is_byz, plan.mode[i], honest_mode).astype(jnp.int32)
@@ -127,7 +119,7 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         # --- Channel: per-slot fading ----------------------------------------
         # A faded echo cannot be verified, so the sender retransmits raw
         # (the paper's reliability assumption); a faded raw still reaches the
-        # server but is NOT overheard, shrinking the shared reference set.
+        # server but is NOT overheard, shrinking the reference set.
         chan, faded = channel.fade(st.chan, i)
         # Jamming (net/attacks.echo_jam): a worker spending its radio on noise
         # blankets every *other* slot — same observable semantics as a fade
@@ -143,7 +135,7 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         # ``codec.lossless`` is trace-time static: the fp32 default skips the
         # roundtrips and the ratio recompute entirely, so its jaxpr (and every
         # value in it) is exactly the pre-comm slot loop.
-        echo_ref = jnp.where(is_byz, plan.echo_ref[i], st.rmask)
+        echo_ref = jnp.where(is_byz, plan.echo_ref[i], view)
         echo_x = jnp.where(is_byz, plan.echo_x[i], dec.x)
         ef_row = st.ef[i]
         if codec.lossless:
@@ -163,8 +155,7 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
             # Honest senders compute the norm ratio against the coefficients
             # AS TRANSMITTED so ||g~|| == ||g|| survives quantization;
             # Byzantine senders forge theirs freely.
-            x_wire = echo_x.at[st.ref_id].get(mode="fill", fill_value=0)
-            k_honest = wire_norm_ratio(st.R, live, x_wire, raw_msg)
+            k_honest = refset.norm_ratio(st.ref, view, echo_x, raw_msg)
             echo_k = codec.roundtrip(
                 jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
 
@@ -190,135 +181,13 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         bits_i = jnp.where(ok, attempt, 0.0)
 
         # --- Server processes the message (lines 33-41) ----------------------
-        # Echo referencing an unheard worker == provable Byzantine (l. 36-37).
+        # An echo referencing a worker the sender could not have heard —
+        # slot not received, or out of its radio's reach — is provably
+        # Byzantine (l. 36-37). Slot i itself is never received yet, so on
+        # the complete graph the reach term flags nothing more.
         # The reconstruction reads the table's rows up to I's highest ID,
         # and none in a slot whose echo is not used (raw, silent or
         # detected), where g_echo is zero.
-        bad_ref = jnp.any(echo_ref & ~st.received)
-        detected_i = is_echo & bad_ref
-        use_echo = is_echo & ~bad_ref
-        ref_rows = echo_ref & st.received
-        g_echo = reconstruct_echo(st.G, ref_rows, echo_k, echo_x,
-                                  used=use_echo)
-        recon_rows = st.recon_rows + recon_rows_read(ref_rows, use_echo)
-        g_tilde = jnp.where(is_raw, raw_wire, g_echo)
-        g_tilde = channel.deliver(st.chan, i, g_tilde)
-        G = st.G.at[i].set(g_tilde)
-        received = st.received.at[i].set(mode != MSG_SILENT)
-        detected = st.detected.at[i].set(detected_i)
-
-    with jax.named_scope(SLOT_OVERHEAR):
-        # --- All later workers overhear raw broadcasts (lines 26-31) ---------
-        indep = independent_from_projection(proj, st.rmask, raw_msg,
-                                            cfg.indep_tol)
-        overheard = ~faded & ok
-        add = is_raw & indep & overheard
-        # One row update at row n_ref, which is < n (a slot admits at most
-        # one raw): a plain dynamic update, with no bounds check that would
-        # read the old row. Rows from n_ref on are empty (zeros, ID n), so
-        # a slot that admits nothing rewrites an empty row and reads none.
-        k = st.n_ref
-        R = jax.lax.dynamic_update_index_in_dim(
-            st.R, jnp.where(add, raw_wire, jnp.zeros_like(raw_wire)), k, 0)
-        ref_id = st.ref_id.at[k].set(jnp.where(add, i, n))
-        n_ref = k + add.astype(jnp.int32)
-        rmask = st.rmask.at[i].set(add | st.rmask[i])
-
-    bits = st.bits.at[i].set(bits_i)
-    echoed = st.echoed.at[i].set(is_echo)
-    faded_acc = st.faded.at[i].set(faded)
-    # the residual commits only when the raw payload actually went on the
-    # air and was admitted — a slot that echoed (or was silenced by the
-    # meter) never transmitted it, so the carried state must not change
-    ef = jnp.where(use_ef & is_raw, st.ef.at[i].set(ef_row), st.ef)
-
-    return CommState(G, received, detected, R, rmask, bits, echoed,
-                     faded_acc, chan, ef, ref_id, n_ref, proj_rows,
-                     recon_rows)
-
-
-def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
-              grads: jax.Array, byz_mask: jax.Array, plan: AttackPlan,
-              comm: CommConfig, hear: jax.Array,
-              use_ef: bool = False) -> CommState:
-    """One TDMA slot under a partial hearing graph.
-
-    Same protocol as :func:`_slot` with per-worker reference sets:
-    ``st.rmask`` is (n, n) with row j = worker j's view, ``hear[j, i]``
-    says worker j's radio reaches worker i. The sender decides and
-    echoes against its OWN mask; every receiver runs its own
-    independence test on the raws it actually overhears; and the server
-    — which knows the topology — additionally flags echoes referencing
-    workers outside the sender's hearing set (the paper's lines 36-37
-    detection generalized to the graph).
-    """
-    n, d = grads.shape
-    g_i = grads[i]
-    is_byz = byz_mask[i]
-    codec, channel = comm.codec, comm.channel
-    mask_i = st.rmask[i]                    # sender's own reference view
-
-    with jax.named_scope(SLOT_DECIDE):
-        # --- Worker i decides what to broadcast (lines 14-24) ----------------
-        raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
-        x_proj, proj = project_onto_span(st.R, mask_i, raw_msg, cfg.ridge)
-        dec = echo_decision_from_projection(x_proj, proj, mask_i, raw_msg,
-                                            cfg.r)
-        honest_mode = jnp.where(dec.send_echo, MSG_ECHO, MSG_RAW)
-        mode = jnp.where(is_byz, plan.mode[i], honest_mode).astype(jnp.int32)
-
-        # --- Channel: per-slot fading + jamming ------------------------------
-        chan, faded = channel.fade(st.chan, i)
-        jammed = jnp.any(plan.jam & byz_mask) & ~is_byz
-        faded = faded | jammed
-        fellback = (mode == MSG_ECHO) & faded
-        mode = jnp.where(fellback, MSG_RAW, mode)
-
-        # --- Wire coding -----------------------------------------------------
-        echo_ref = jnp.where(is_byz, plan.echo_ref[i], mask_i)
-        echo_x = jnp.where(is_byz, plan.echo_x[i], dec.x)
-        ef_row = st.ef[i]
-        if codec.lossless:
-            raw_wire = raw_msg
-            echo_k = jnp.where(is_byz, plan.echo_k[i], dec.k)
-        else:
-            if use_ef:
-                compensated = raw_msg + ef_row
-                raw_wire = codec.roundtrip(compensated)
-                ef_row = compensated - raw_wire
-            else:
-                raw_wire = codec.roundtrip(raw_msg)
-            echo_x = codec.roundtrip(echo_x)
-            k_honest = wire_norm_ratio(st.R, mask_i, echo_x, raw_msg)
-            echo_k = codec.roundtrip(
-                jnp.where(is_byz, plan.echo_k[i], k_honest)[None])[0]
-
-    with jax.named_scope(SLOT_SERVER):
-        is_raw = mode == MSG_RAW
-        is_echo = mode == MSG_ECHO
-
-        # --- Bit pricing + budget admission ----------------------------------
-        rank = jnp.sum(echo_ref & st.received)
-        raw_cost = jnp.float32(codec.raw_msg_bits(d))
-        echo_cost = jnp.asarray(codec.echo_msg_bits(n, rank)).astype(
-            jnp.float32)
-        attempt = jnp.where(
-            is_echo, echo_cost,
-            jnp.where(is_raw,
-                      jnp.where(fellback, echo_cost + raw_cost, raw_cost),
-                      0.0))
-        attempt = channel.price(attempt)
-        chan, ok = channel.admit(chan, attempt)
-        mode = jnp.where(ok, mode, MSG_SILENT)
-        is_raw = is_raw & ok
-        is_echo = is_echo & ok
-        bits_i = jnp.where(ok, attempt, 0.0)
-
-        # --- Server processes the message ------------------------------------
-        # Topology-aware detection: an echo referencing a worker the sender
-        # could not have heard (graph edge absent OR slot not received) is
-        # provably Byzantine. Honest masks are built from overheard slots
-        # within hearing range, so they never trip this.
         bad_ref = jnp.any(echo_ref & (~st.received | ~hear[i]))
         detected_i = is_echo & bad_ref
         use_echo = is_echo & ~bad_ref
@@ -333,27 +202,34 @@ def _slot_net(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         detected = st.detected.at[i].set(detected_i)
 
     with jax.named_scope(SLOT_OVERHEAR):
-        # --- Overhearing, per receiver (lines 26-31 under the graph) ---------
-        # Each worker j that hears i runs ITS OWN independence test against
-        # its own mask. The shared R buffer stores the wire payload once
-        # (row = sender ID, identical for all receivers); membership is the
-        # per-worker business, so it lives entirely in rmask[:, i].
-        indep = jax.vmap(
-            lambda m: independent_from_projection(
-                project_onto_span(st.R, m, raw_msg, cfg.ridge)[1],
-                m, raw_msg, cfg.indep_tol))(st.rmask)          # (n,)
-        on_air = is_raw & ~faded & ok
-        add = on_air & indep & hear[:, i]       # hear[j, i]: j overhears i
-        R = jnp.where(on_air, st.R.at[i].set(raw_wire), st.R)
-        rmask = st.rmask.at[:, i].set(add | st.rmask[:, i])
+        # --- Workers overhear raw broadcasts (lines 26-31) -------------------
+        # Each view admits the raw if it is independent of that view; the
+        # reference set stores it once if any view does.
+        if st.ref.views.shape[0] == 1:
+            indep = independent_from_projection(proj, view, raw_msg,
+                                                cfg.indep_tol)[None]
+        else:
+            _, projs, rows = jax.vmap(
+                lambda v: refset.project(st.ref, v, raw_msg, cfg.ridge))(
+                    st.ref.views)
+            indep = jax.vmap(independent_from_projection,
+                             (0, 0, None, None))(projs, st.ref.views,
+                                                 raw_msg, cfg.indep_tol)
+            indep = indep & hear[:, i]      # hear[j, i]: j overhears i
+            proj_rows = proj_rows + jnp.sum(rows)
+        overheard = ~faded & ok
+        ref = refset.admit(st.ref, i, raw_wire, is_raw & indep & overheard)
 
     bits = st.bits.at[i].set(bits_i)
     echoed = st.echoed.at[i].set(is_echo)
     faded_acc = st.faded.at[i].set(faded)
+    # the residual commits only when the raw payload actually went on the
+    # air and was admitted — a slot that echoed (or was silenced by the
+    # meter) never transmitted it, so the carried state must not change
     ef = jnp.where(use_ef & is_raw, st.ef.at[i].set(ef_row), st.ef)
 
-    return CommState(G, received, detected, R, rmask, bits, echoed,
-                     faded_acc, chan, ef, recon_rows=recon_rows)
+    return CommState(G, received, detected, ref, bits, echoed, faded_acc,
+                     chan, ef, proj_rows, recon_rows)
 
 
 def communication_phase(
@@ -381,37 +257,31 @@ def communication_phase(
 
     ``net`` (a :class:`repro.net.HearingGraph`, trace-time static)
     restricts worker-to-worker overhearing. ``None`` or a complete graph
-    keeps the exact shared-reference-mask slot body; anything partial
-    switches to the per-worker (n, n) mask variant (:func:`_slot_net`).
+    keeps one view of the reference set, which every worker shares;
+    any other graph gives each worker its own.
     """
     comm = comm if comm is not None else DEFAULT_COMM
     n, d = grads.shape
-    shared = net is None or net.is_complete
     if net is not None and net.n != n:
         raise ValueError(f"hearing graph is for n={net.n} workers, "
                          f"round has n={n}")
+    n_views = 1 if net is None or net.is_complete else n
+    hear = ~jnp.eye(n, dtype=bool) if net is None else net.matrix()
     st = CommState(
         G=jnp.zeros((n, d), grads.dtype),
         received=jnp.zeros((n,), bool),
         detected=jnp.zeros((n,), bool),
-        R=jnp.zeros((n, d), grads.dtype),
-        rmask=jnp.zeros((n,) if shared else (n, n), bool),
+        ref=refset.empty(n, d, grads.dtype, n_views),
         bits=jnp.zeros((n,), jnp.float32),
         echoed=jnp.zeros((n,), bool),
         faded=jnp.zeros((n,), bool),
         chan=comm.channel.init(chan_key),
         ef=ef if ef is not None else jnp.zeros((n, d), grads.dtype),
+        proj_rows=jnp.int32(0),
         recon_rows=jnp.int32(0),
     )
-    if shared:
-        st = st._replace(ref_id=jnp.full((n,), n, jnp.int32),
-                         n_ref=jnp.int32(0), proj_rows=jnp.int32(0))
-        slot = partial(_slot, cfg=cfg, grads=grads, byz_mask=byz_mask,
-                       plan=plan, comm=comm, use_ef=ef is not None)
-    else:
-        slot = partial(_slot_net, cfg=cfg, grads=grads, byz_mask=byz_mask,
-                       plan=plan, comm=comm, hear=net.matrix(),
-                       use_ef=ef is not None)
+    slot = partial(_slot, cfg=cfg, grads=grads, byz_mask=byz_mask, plan=plan,
+                   comm=comm, hear=hear, use_ef=ef is not None)
 
     def body(i, st):
         with jax.named_scope(PROTOCOL_SLOT):
@@ -420,15 +290,12 @@ def communication_phase(
     st = jax.lax.fori_loop(0, n, body, st)
 
     server = ServerState(G=st.G, received=st.received, detected=st.detected)
-    # rank_R under per-worker masks: rows referenced by at least one view
-    # (the shared-path statistic is the same reduction on a 1-D mask).
-    rmask_any = st.rmask if shared else jnp.any(st.rmask, axis=0)
     stats = RoundStats(
         bits_sent=st.bits,
         echo_sent=st.echoed,
         n_echo=jnp.sum(st.echoed.astype(jnp.int32)),
         n_detected=jnp.sum(st.detected.astype(jnp.int32)),
-        rank_R=jnp.sum(rmask_any.astype(jnp.int32)),
+        rank_R=refset.rank(st.ref),
         n_faded=jnp.sum(st.faded.astype(jnp.int32)),
         proj_rows=st.proj_rows,
         recon_rows=st.recon_rows,
@@ -583,55 +450,21 @@ def run_training(
                   codec=dec.codec or comm.codec.name,
                   echo_r=dec.echo_r if dec.echo_r is not None else cfg.r)
     use_ef = bool(error_feedback) and use_radio
-    attack_extra = _attack_kwargs(attack_fn)
 
     def one_round(carry, key_t):
         w, ef = carry
-        with jax.named_scope(PROTOCOL_GRADS):
-            keys = jax.random.split(key_t, n + 1)
-            grads = jax.vmap(lambda k: cost.stoch_grad(k, w))(keys[:n])
-            true_grad = cost.grad(w)
-            # fold_in (not a wider split) keeps grads/attack draws
-            # bitwise-identical to the pre-channel code path.
-            chan_key = jax.random.fold_in(key_t, n + 1)
-            extra = {}
-            if "channel" in attack_extra:
-                extra["channel"] = comm.channel
-            if "chan_key" in attack_extra:
-                extra["chan_key"] = chan_key
-            plan = attack_fn(keys[n], grads, byz_mask, w, true_grad,
-                             **extra)
+        grads, plan, chan_key = _round_inputs(cost, attack_fn, byz_mask,
+                                              comm.channel, n, key_t, w)
         if use_radio:
-            if use_ef:
-                w_next, server, stats, ef = echo_cgc_round(
-                    cfg, w, grads, byz_mask, plan, aggregator, comm,
-                    chan_key, ef, net)
-            else:
-                w_next, server, stats = echo_cgc_round(
-                    cfg, w, grads, byz_mask, plan, aggregator, comm,
-                    chan_key, None, net)
-            bits = jnp.sum(stats.bits_sent)
-            n_echo = stats.n_echo
-            n_det = stats.n_detected
-            proj_rows = _proj_rows(stats, n)
-            recon_rows = stats.recon_rows
+            w_next, stats, ef = _radio_round(cfg, w, grads, byz_mask, plan,
+                                             aggregator, comm, chan_key, ef,
+                                             net)
+            row = _trace_row(cost, w, jnp.sum(stats.bits_sent), stats)
         else:
             w_next, bits = pointwise_round(cfg, w, grads, byz_mask, plan,
                                            aggregator, comm)
-            n_echo = jnp.int32(0)
-            n_det = jnp.int32(0)
-            proj_rows = jnp.int32(0)
-            recon_rows = jnp.int32(0)
-        out = dict(
-            dist2=jnp.sum((w - cost.w_star) ** 2),
-            value=cost.value(w),
-            bits=bits,
-            n_echo=n_echo,
-            n_detected=n_det,
-            proj_rows=proj_rows,
-            recon_rows=recon_rows,
-        )
-        return (w_next, ef), out
+            row = _trace_row(cost, w, bits, None)
+        return (w_next, ef), row
 
     ef0 = (jnp.zeros((n, w0.shape[-1]), w0.dtype) if use_ef else None)
     keys = jax.random.split(key, rounds)
@@ -656,13 +489,50 @@ def run_training(
     return trace
 
 
-def _proj_rows(stats: RoundStats, n: int) -> jax.Array:
-    """Reference rows a round's slot projections read. Under per-worker
-    masks every slot projects over all n rows once for its decision and
-    once for each receiver's independence test."""
-    if stats.proj_rows is not None:
-        return stats.proj_rows
-    return jnp.int32(n * (n + n * n))
+def _round_inputs(cost, attack_fn, byz_mask: jax.Array, channel, n: int,
+                  key_t: jax.Array, w: jax.Array):
+    """One round's worker gradients, attack plan and channel key, drawn
+    from the round's key alike by the scan and the per-round host loop."""
+    attack_extra = _attack_kwargs(attack_fn)
+    with jax.named_scope(PROTOCOL_GRADS):
+        keys = jax.random.split(key_t, n + 1)
+        grads = jax.vmap(lambda k: cost.stoch_grad(k, w))(keys[:n])
+        true_grad = cost.grad(w)
+        # fold_in (not a wider split) keeps grads/attack draws
+        # bitwise-identical to the pre-channel code path.
+        chan_key = jax.random.fold_in(key_t, n + 1)
+        extra = {}
+        if "channel" in attack_extra:
+            extra["channel"] = channel
+        if "chan_key" in attack_extra:
+            extra["chan_key"] = chan_key
+        plan = attack_fn(keys[n], grads, byz_mask, w, true_grad, **extra)
+    return grads, plan, chan_key
+
+
+def _radio_round(cfg, w, grads, byz_mask, plan, aggregator, comm, chan_key,
+                 ef, net):
+    """``echo_cgc_round`` as (w_next, stats, ef_next), ``ef_next`` None
+    when no residuals are threaded."""
+    out = echo_cgc_round(cfg, w, grads, byz_mask, plan, aggregator, comm,
+                         chan_key, ef, net)
+    return out[0], out[2], (out[3] if ef is not None else None)
+
+
+def _trace_row(cost, w: jax.Array, bits: jax.Array,
+               stats: Optional[RoundStats]) -> dict:
+    """One round's entry of ``run_training``'s trace; the slot loop's
+    counts are 0 point to point (``stats`` None)."""
+    zero = jnp.int32(0)
+    return dict(
+        dist2=jnp.sum((w - cost.w_star) ** 2),
+        value=cost.value(w),
+        bits=bits,
+        n_echo=zero if stats is None else stats.n_echo,
+        n_detected=zero if stats is None else stats.n_detected,
+        proj_rows=zero if stats is None else stats.proj_rows,
+        recon_rows=zero if stats is None else stats.recon_rows,
+    )
 
 
 def _host_span(name: str, eager: bool):
@@ -751,27 +621,13 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
     r_changes = 0
     bits_cum = 0
 
-    attack_extra = _attack_kwargs(attack_fn)
-
-    @jax.jit
-    def round_inputs(key_t, w):
-        keys = jax.random.split(key_t, n + 1)
-        grads = jax.vmap(lambda k: cost.stoch_grad(k, w))(keys[:n])
-        chan_key = jax.random.fold_in(key_t, n + 1)
-        extra = {}
-        if "channel" in attack_extra:
-            extra["channel"] = comm.channel
-        if "chan_key" in attack_extra:
-            extra["chan_key"] = chan_key
-        plan = attack_fn(keys[n], grads, byz_mask, w, cost.grad(w), **extra)
-        return grads, plan, chan_key
+    round_inputs = jax.jit(lambda key_t, w: _round_inputs(
+        cost, attack_fn, byz_mask, comm.channel, n, key_t, w))
 
     w = w0
     ef = jnp.zeros((n, d), w0.dtype) if error_feedback else None
     last_obs = None
-    trace = {k: [] for k in
-             ("dist2", "value", "bits", "n_echo", "n_detected", "proj_rows",
-              "recon_rows")}
+    trace = {}
     keys = jax.random.split(key, rounds)
     eager = not isinstance(w0, jax.core.Tracer)
     with _host_span("protocol.rounds", eager):
@@ -801,19 +657,14 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
             comm_t = _CC(channel=channel, codec=codec)
 
             grads, plan, chan_key = round_inputs(keys[t], w)
-            value = cost.value(w)
-            if ef is not None:
-                w_next, _, stats, ef = echo_cgc_round(
-                    cfg_t, w, grads, byz_mask, plan, aggregator, comm_t,
-                    chan_key, ef, net)
-            else:
-                w_next, _, stats = echo_cgc_round(
-                    cfg_t, w, grads, byz_mask, plan, aggregator, comm_t,
-                    chan_key, None, net)
-            bits = int(np.asarray(jnp.sum(stats.bits_sent)))
-            n_echo = int(np.asarray(stats.n_echo))
+            w_next, stats, ef = _radio_round(cfg_t, w, grads, byz_mask,
+                                             plan, aggregator, comm_t,
+                                             chan_key, ef, net)
+            row = _trace_row(cost, w, jnp.sum(stats.bits_sent), stats)
+            bits = int(np.asarray(row["bits"]))
+            n_echo = int(np.asarray(row["n_echo"]))
             n_faded = int(np.asarray(stats.n_faded))
-            loss = float(np.asarray(value))
+            loss = float(np.asarray(row["value"]))
             baseline = raw_round_bits(codec, n, d)
             bits_cum += bits
             last_obs = RoundObservation(
@@ -830,13 +681,8 @@ def _run_training_policy(cfg, cost, attack_fn, byz_mask, key, w0, rounds,
             if ledger is not None:
                 ledger.record_round(bits=bits, baseline=baseline,
                                     echoed=n_echo > 0)
-            trace["dist2"].append(jnp.sum((w - cost.w_star) ** 2))
-            trace["value"].append(value)
-            trace["bits"].append(jnp.float32(bits))
-            trace["n_echo"].append(jnp.int32(n_echo))
-            trace["n_detected"].append(stats.n_detected)
-            trace["proj_rows"].append(_proj_rows(stats, n))
-            trace["recon_rows"].append(stats.recon_rows)
+            for k, v in row.items():
+                trace.setdefault(k, []).append(v)
             w = w_next
     if eager:
         obs.counter("protocol.rounds_simulated", rounds)

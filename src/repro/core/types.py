@@ -51,8 +51,7 @@ class RoundStats(NamedTuple):
     n_faded: Any = None          # () int32, slots the channel faded this round
                                  # (None from pre-channel call sites)
     proj_rows: Any = None        # () int32, reference rows the round's slot
-                                 # projections read, summed over slots (None
-                                 # under per-worker masks)
+                                 # projections read, summed over slots
     recon_rows: Any = None       # () int32, table rows the server's echo
                                  # reconstructions read, summed over slots
 

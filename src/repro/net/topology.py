@@ -3,11 +3,11 @@
 The paper's single-hop radio assumption is that every worker overhears
 every other worker's slot. A :class:`HearingGraph` makes that assumption
 a swappable axis: ``adj[j][i]`` says whether worker j's radio hears
-worker i's broadcast. The protocol slot loop uses it to keep *per-worker*
-reference masks — worker j may only echo against raws it actually
-overheard, and the server (which hears every uplink slot regardless)
-provably detects echoes referencing gradients outside the sender's
-hearing set.
+worker i's broadcast. Under a partial graph the protocol slot loop keeps
+one view of the reference set per worker — worker j may only echo against
+raws it actually overheard, and the server (which hears every uplink slot
+regardless) provably detects echoes referencing gradients outside the
+sender's hearing set.
 
 Graphs are frozen, hashable (tuple-of-tuples adjacency) so they ride as
 jit static args next to ``ProtocolConfig``; :meth:`HearingGraph.matrix`
@@ -19,8 +19,8 @@ a builder takes ``(spec, n)`` where ``spec`` is the job's
 worker count.
 
     complete            the paper's all-hear set (the bitwise-identical
-                        default — the slot loop keeps its shared-mask
-                        fast path)
+                        default — the slot loop keeps one view, shared
+                        by every worker)
     ring                workers on a cycle hear ``degree // 2``
                         neighbours on each side
     random_geometric    seeded uniform placement on the unit square;
@@ -42,9 +42,10 @@ from repro.run.registry import TOPOLOGIES
 class HearingGraph:
     """Directed overhearing relation: ``adj[j][i]`` = j hears i's slot.
 
-    ``strict=True`` forces the protocol onto the per-worker-mask path
-    even when the adjacency is complete (tests use it to check the
-    masked path against the shared-mask fast path).
+    ``strict=True`` makes even a complete adjacency count as partial:
+    the slot loop then keeps n views of the reference set, one per
+    worker, instead of the one they share (tests use it to compare n
+    coinciding views against the one).
     """
 
     adj: Tuple[Tuple[bool, ...], ...]
@@ -67,7 +68,8 @@ class HearingGraph:
     @property
     def is_complete(self) -> bool:
         """All-hear set: every off-diagonal edge present (the paper's
-        assumption — the slot loop takes the shared-mask fast path)."""
+        assumption — the slot loop keeps one shared view); never under
+        ``strict``."""
         if self.strict:
             return False
         n = self.n

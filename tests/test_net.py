@@ -109,8 +109,9 @@ def test_complete_graph_is_bitwise_the_shared_path():
 
 
 def test_strict_complete_masked_path_matches_shared_path():
-    """strict=True forces the per-worker-mask slot body; on a complete
-    adjacency every worker's view coincides, so both paths agree."""
+    """strict=True gives the slot loop n views; on a complete adjacency
+    every worker's view coincides with the one shared view, so both
+    agree."""
     n, d = 10, 16
     grads = jax.vmap(lambda k: jax.random.normal(k, (d,)))(
         jax.random.split(jax.random.PRNGKey(2), n))
@@ -119,7 +120,7 @@ def test_strict_complete_masked_path_matches_shared_path():
     nb = jnp.zeros(n, bool)
     srv_a, st_a = protocol.communication_phase(cfg, grads, nb, plan)
     strict = HearingGraph(adj=complete_graph(n).adj, strict=True)
-    assert not strict.is_complete          # forced onto the masked path
+    assert not strict.is_complete          # n views, not one
     srv_b, st_b = protocol.communication_phase(cfg, grads, nb, plan,
                                               net=strict)
     np.testing.assert_allclose(np.asarray(srv_a.G), np.asarray(srv_b.G),

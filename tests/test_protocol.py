@@ -1,5 +1,6 @@
 """End-to-end Echo-CGC protocol behaviour (Algorithm 1)."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ from repro.core.protocol import (communication_phase, echo_cgc_round,
 import repro.core.protocol as protocol
 from repro.core.types import (MSG_ECHO, MSG_RAW, MSG_SILENT, ProtocolConfig,
                               raw_bits)
-from repro.net import HearingGraph, complete_graph
+from repro.net import HearingGraph, complete_graph, ring_graph
 from repro.obs.scopes import ECHO_PROJECT, PROTOCOL_SLOT, SLOT_SERVER
 
 
@@ -209,11 +210,11 @@ def _rising_rank_round(n=40, d=256, n_byz=4):
 
 @pytest.mark.parametrize("codec", [FP32, Int8Codec()], ids=["fp32", "int8"])
 def test_compact_reference_matches_full_table_path(codec):
-    """The shared slot loop keeps R compact and projects over the live
-    row bucket; the strict complete-graph path keeps the ID-ordered
-    table and projects over all n rows. Same decisions, same bits, same
-    server table (int8: the norm ratio of the transmitted coefficients
-    reads the compact rows)."""
+    """One view of the compact R against n coinciding views (the strict
+    complete graph): both project over the live row bucket, n views once
+    for the sender and once for each receiver. Same decisions, same bits,
+    same server table (int8: the norm ratio of the transmitted
+    coefficients reads the compact rows)."""
     cfg, grads, byz, plan = _rising_rank_round()
     n = cfg.n
     comm = CommConfig(codec=codec)
@@ -234,7 +235,103 @@ def test_compact_reference_matches_full_table_path(codec):
     # every bucket served some slot: slot i projects over the bucket of
     # the rank before it, and the rank climbs past 32
     assert int(st_a.proj_rows) > 8 * n
-    assert st_b.proj_rows is None
+    assert int(st_b.proj_rows) == (n + 1) * int(st_a.proj_rows)
+
+
+def _per_view_round(cfg, grads, byz, plan, hear):
+    """A plain per-view slot loop on the ideal fp32 channel: R in
+    sender-ID order, worker j's view a mask over it, and each projection
+    a masked least-squares solve over all n rows. Returns the server's
+    table, the round's flags and bits, and the rows stored before each
+    slot and after the last."""
+    n, d = grads.shape
+    grads, raws = np.asarray(grads), np.asarray(plan.raw)
+    byz, hear = np.asarray(byz), np.asarray(hear)
+    R, G = np.zeros((n, d), np.float32), np.zeros((n, d), np.float32)
+    views = np.zeros((n, n), bool)
+    received, detected, echoed = (np.zeros(n, bool) for _ in range(3))
+    bits = np.zeros(n, np.float32)
+    stored = []
+
+    def project(mask, g):
+        Rm = R * mask[:, None]
+        gram = Rm @ Rm.T
+        scale = max(np.abs(np.diag(gram)).max(), 1.0)
+        gram = gram + np.diag((~mask) * scale + cfg.ridge * scale)
+        x = np.linalg.solve(gram, Rm @ g).astype(np.float32) * mask
+        return x, x @ Rm
+
+    for i in range(n):
+        stored.append(int(views.any(axis=0).sum()))
+        g = raws[i] if byz[i] else grads[i]
+        x, echo = project(views[i], g)
+        g_norm, e_norm = np.linalg.norm(g), np.linalg.norm(echo)
+        if byz[i]:
+            mode = int(plan.mode[i])
+            ref, x, k = (np.asarray(plan.echo_ref[i]),
+                         np.asarray(plan.echo_x[i]), float(plan.echo_k[i]))
+        else:
+            ok = (np.linalg.norm(echo - g) <= cfg.r * g_norm
+                  and views[i].any() and e_norm > 0)
+            mode = MSG_ECHO if ok else MSG_RAW
+            ref, k = views[i], g_norm / e_norm if e_norm > 0 else 0.0
+        if mode == MSG_RAW:
+            bits[i] = FP32.raw_msg_bits(d)
+            G[i] = g
+            for j in np.flatnonzero(hear[:, i]):
+                _, p = project(views[j], g)
+                views[j, i] = (np.linalg.norm(p - g) > cfg.indep_tol * g_norm
+                               or not views[j].any())
+            R[i] = g
+        elif mode == MSG_ECHO:
+            echoed[i] = True
+            used = ref & received
+            bits[i] = FP32.echo_msg_bits(n, int(used.sum()))
+            detected[i] = bool(np.any(ref & (~received | ~hear[i])))
+            if not detected[i]:
+                G[i] = k * ((x * used) @ (G * used[:, None]))
+        received[i] = mode != MSG_SILENT
+    stored.append(int(views.any(axis=0).sum()))
+    return G, received, detected, echoed, bits, stored
+
+
+def test_partial_graph_views_match_plain_per_view_loop():
+    """A degree-4 ring at n = 24: each worker's view differs, and the
+    stored rows climb through every row bucket (8, 16, 24). Worker 20
+    forges an echo of two raws it heard, worker 21 one of a raw it never
+    heard (detected). The slot loop's decisions, detections and bits are
+    the plain per-view loop's, its server table is within float32
+    rounding of it, and it counts the rows its projections read: each
+    slot's bucket once for the sender and once for each of the n views."""
+    n, d = 24, 64
+    cfg, grads, _, _ = _rising_rank_round(n=n, d=d, n_byz=2)
+    byz = (jnp.arange(n) < 2) | (jnp.arange(n) == 20) | (jnp.arange(n) == 21)
+    plan = byzantine.sign_flip(jax.random.PRNGKey(1), grads, byz, None, None)
+    forged = jax.random.normal(jax.random.PRNGKey(7), (2, n))
+    plan = dataclasses.replace(
+        plan, mode=plan.mode.at[20:22].set(MSG_ECHO),
+        echo_ref=plan.echo_ref.at[20, 18:20].set(True).at[21, 10].set(True),
+        echo_x=plan.echo_x.at[20:22].set(forged),
+        echo_k=plan.echo_k.at[20:22].set(1.5))
+    graph = ring_graph(n, 4)
+    srv, st = communication_phase(cfg, grads, byz, plan, net=graph)
+    G, received, detected, echoed, bits, stored = _per_view_round(
+        cfg, grads, byz, plan, graph.matrix())
+
+    np.testing.assert_array_equal(np.asarray(st.echo_sent), echoed)
+    np.testing.assert_array_equal(np.asarray(srv.detected), detected)
+    np.testing.assert_array_equal(np.asarray(srv.received), received)
+    np.testing.assert_array_equal(np.asarray(st.bits_sent), bits)
+    np.testing.assert_allclose(np.asarray(srv.G), G, rtol=1e-4, atol=1e-5)
+    assert detected.tolist() == [j == 21 for j in range(n)]
+    assert echoed[[5, 11, 17, 20]].all()
+    assert len({frozenset(np.flatnonzero(graph.matrix()[j])) for j in
+                range(n)}) == n
+    buckets = [next(b for b in row_buckets(n) if b >= k)
+               for k in stored[:-1]]
+    assert set(buckets) == set(row_buckets(n))
+    assert int(st.rank_R) == stored[-1]
+    assert int(st.proj_rows) == (n + 1) * sum(buckets)
 
 
 def test_proj_rows_counts_bucket_rows():
@@ -268,7 +365,15 @@ def _dot_reads(jaxpr, stack, last_bucket, n_buckets, out):
         here = "/".join(p for p in (stack, str(eqn.source_info.name_stack))
                         if p)
         if eqn.primitive.name == "dot_general":
-            rows = [v.aval.shape[0] for v in eqn.invars if v.aval.ndim == 2]
+            # an operand's rows, its batch dimensions (a vmap over views)
+            # set aside
+            (_, _), batch = eqn.params["dimension_numbers"]
+            rows = []
+            for v, bdims in zip(eqn.invars, batch):
+                shape = [s for k, s in enumerate(v.aval.shape)
+                         if k not in bdims]
+                if len(shape) >= 2:
+                    rows.append(shape[-2])
             out.append((here, rows, last_bucket))
         if eqn.primitive.name == "cond":
             branches = eqn.params["branches"]
@@ -282,18 +387,33 @@ def _dot_reads(jaxpr, stack, last_bucket, n_buckets, out):
             _dot_reads(sub, here, last_bucket, n_buckets, out)
 
 
-def test_shared_slot_projection_reads_only_its_bucket():
-    """Structural guard: in the shared slot loop no dot_general under
-    the echo.project scope reads all n rows, except in the branch whose
+def _scopes(stack):
+    """The scopes of a name stack, each transform wrapper taken off
+    (``vmap(echo.project)`` is ``echo.project``)."""
+    out = []
+    for comp in stack.split("/"):
+        while (m := re.fullmatch(r"\w+\((.*)\)", comp)):
+            comp = m.group(1)
+        out.append(comp)
+    return out
+
+
+@pytest.mark.parametrize("graph", ["shared", "per_worker"])
+def test_shared_slot_projection_reads_only_its_bucket(graph):
+    """Structural guard: with one view and with n (the receivers'
+    projections vmapped over the views), no dot_general under the
+    echo.project scope reads all n rows, except in the branch whose
     bucket is the whole table."""
     cfg, grads, byz, plan = _rising_rank_round(n=20, d=64, n_byz=2)
     n = cfg.n
+    net = (None if graph == "shared" else
+           HearingGraph(adj=complete_graph(n).adj, strict=True))
     jaxpr = jax.make_jaxpr(
-        lambda g: communication_phase(cfg, g, byz, plan))(grads).jaxpr
+        lambda g: communication_phase(cfg, g, byz, plan, net=net))(grads).jaxpr
     reads = []
     _dot_reads(jaxpr, "", False, {len(row_buckets(n))}, reads)
     proj = [(rows, widest) for stack, rows, widest in reads
-            if ECHO_PROJECT in stack.split("/")]
+            if ECHO_PROJECT in _scopes(stack)]
     assert any(8 in rows for rows, _ in proj)
     assert any(widest for _, widest in proj)
     full = [(rows, widest) for rows, widest in proj if n in rows]
@@ -302,8 +422,8 @@ def test_shared_slot_projection_reads_only_its_bucket():
 
 @pytest.mark.parametrize("graph", ["shared", "per_worker"])
 def test_slot_reconstruction_reads_only_its_bucket(graph):
-    """Structural guard: in both slot bodies no dot_general under the
-    server scope reads all n rows of G, except in the branch whose
+    """Structural guard: with one view and with n, no dot_general under
+    the server scope reads all n rows of G, except in the branch whose
     bucket is the whole table; the other buckets read the table's top
     rows (8 of them in the smallest)."""
     cfg, grads, byz, plan = _rising_rank_round(n=20, d=64, n_byz=2)
@@ -323,9 +443,9 @@ def test_slot_reconstruction_reads_only_its_bucket(graph):
 
 
 def _whole_table_reconstruction(monkeypatch):
-    """Make the slot loops reconstruct every echo from the whole table,
-    as before the row bucket, zero where it is not used (the slot loops
-    look the function up when they are traced)."""
+    """Make the slot loop reconstruct every echo from the whole table,
+    as before the row bucket, zero where it is not used (the slot loop
+    looks the function up when it is traced)."""
     whole = protocol.reconstruct_echo
     monkeypatch.setattr(
         protocol, "reconstruct_echo",
@@ -374,7 +494,7 @@ def test_bucketed_reconstruction_matches_whole_table_round(refs, monkeypatch):
 def test_recon_rows_counts_bucket_rows():
     """n=20 identical gradients: slot 0 raw, the other 19 echo against
     rank 1 (ID 0), so each echo reads the 8-row bucket and the raw none:
-    19 x 8 = 152 rows, in both slot bodies, in the round's stats and in
+    19 x 8 = 152 rows, with one view and with n, in the round's stats and in
     run_training's per-round trace, scanned and under a dynamic policy
     (0 point to point)."""
     n, d = 20, 24
