@@ -39,15 +39,24 @@ class EchoDecision(NamedTuple):
     residual: jax.Array      # () ||Ax - g|| (diagnostic)
 
 
+class Projection(NamedTuple):
+    x: jax.Array             # (n,) coefficients, zero outside the mask
+    echo: jax.Array          # (d,) the projection A x
+    atg: jax.Array           # (n,) A^T g, zero outside the mask
+
+
 def masked_gram(R: jax.Array, mask: jax.Array, ridge: float) -> jax.Array:
     """Gram matrix A^T A of the masked reference rows, ridged for stability.
 
     Masked-out rows contribute identity rows/cols so the solve stays
     well-posed without affecting valid coefficients.
     """
-    n = R.shape[0]
     Rm = R * mask[:, None]
-    G = Rm @ Rm.T                                    # (n, n)
+    return _ridged(Rm @ Rm.T, mask, ridge)
+
+
+def _ridged(G: jax.Array, mask: jax.Array, ridge: float) -> jax.Array:
+    """:func:`masked_gram`'s ridge and identity on the masked Gram G."""
     diag_scale = jnp.maximum(jnp.max(jnp.abs(jnp.diag(G))), 1.0)
     # Identity on masked-out rows keeps the system invertible there.
     off = (~mask).astype(G.dtype)
@@ -76,15 +85,19 @@ def rows_read(n_live: jax.Array, n: int) -> jax.Array:
     return jnp.asarray(buckets, jnp.int32)[_bucket_index(n_live, buckets)]
 
 
-def _project(R: jax.Array, mask: jax.Array, g: jax.Array, ridge: float
-             ) -> Tuple[jax.Array, jax.Array]:
+def _project(R: jax.Array, mask: jax.Array, g: jax.Array, ridge: float,
+             gram: Optional[jax.Array] = None) -> Projection:
     Rm = R * mask[:, None]
     b = Rm @ g                                       # A^T g, (n,)
-    G = masked_gram(R, mask, ridge)
+    if gram is None:
+        G = masked_gram(R, mask, ridge)
+    else:
+        m = mask.astype(gram.dtype)
+        G = _ridged(gram * m[:, None] * m[None, :], mask, ridge)
     x = jnp.linalg.solve(G, b)
     x = x * mask
     echo = x @ Rm                                    # A x, (d,)
-    return x, echo
+    return Projection(x, echo, b)
 
 
 def project_onto_span(
@@ -95,28 +108,66 @@ def project_onto_span(
 
     Equivalent to the paper's x = (A^T A)^{-1} A^T g with A the masked columns
     (we store gradients as rows, so A = R[mask].T). Returns (x, echo) with
-    x zero outside the mask.
+    x zero outside the mask. :func:`projection` documents ``n_live``, and
+    also takes a carried Gram and returns A^T g.
+    """
+    return projection(R, mask, g, ridge, n_live)[:2]
+
+
+def projection(
+    R: jax.Array, mask: jax.Array, g: jax.Array, ridge: float = 1e-8,
+    n_live: Optional[jax.Array] = None, gram: Optional[jax.Array] = None,
+) -> Projection:
+    """:func:`project_onto_span`'s (x, A x), and A^T g, each (n,) vector
+    zero outside the mask.
 
     ``n_live`` (traced) promises that no row at or past it is in the mask.
     The projection then reads only the smallest bucket of
     :func:`row_buckets` that holds ``n_live`` rows, chosen on the device,
-    and pads x back to n: the same mathematics on the rows that can be
-    live, so a table that is mostly empty is not read whole.
+    and pads x and A^T g back to n: the same mathematics on the rows that
+    can be live, so a table that is mostly empty is not read whole.
+
+    ``gram`` (n, n), if given, is R R^T, carried by the caller: the
+    masked, ridged Gram is then made from it, as :func:`masked_gram`
+    makes it from the rows, and the rows are read twice over d, for
+    A^T g and for A x, and never multiplied with each other.
     """
     with jax.named_scope(ECHO_PROJECT):
         if n_live is None:
-            return _project(R, mask, g, ridge)
+            return _project(R, mask, g, ridge, gram)
         n = R.shape[0]
         buckets = row_buckets(n)
 
         def branch(b):
-            def run(R, mask, g):
-                x, echo = _project(R[:b], mask[:b], g, ridge)
-                return jnp.pad(x, (0, n - b)), echo
+            def run(R, mask, g, gram):
+                p = _project(R[:b], mask[:b], g, ridge,
+                             None if gram is None else gram[:b, :b])
+                return Projection(jnp.pad(p.x, (0, n - b)), p.echo,
+                                  jnp.pad(p.atg, (0, n - b)))
             return run
 
         return jax.lax.switch(_bucket_index(n_live, buckets),
-                              [branch(b) for b in buckets], R, mask, g)
+                              [branch(b) for b in buckets], R, mask, g, gram)
+
+
+def rows_dot(R: jax.Array, g: jax.Array, n_live: jax.Array,
+             used: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(R g, rows read): R g over the smallest bucket of
+    :func:`row_buckets` that holds ``n_live`` rows, zeros past it, and
+    no row read and zeros back where ``used`` (traced bool) is false."""
+    n = R.shape[0]
+    buckets = _recon_buckets(n)
+    idx = jnp.where(used, 1 + _bucket_index(n_live, row_buckets(n)), 0)
+
+    def branch(b):
+        def run(R, g):
+            if b == 0:
+                return jnp.zeros((n,), R.dtype)
+            return jnp.pad(R[:b] @ g, (0, n - b))
+        return run
+
+    return (jax.lax.switch(idx, [branch(b) for b in buckets], R, g),
+            jnp.asarray(buckets, jnp.int32)[idx])
 
 
 def echo_decision_from_projection(

@@ -81,6 +81,7 @@ class CommState(NamedTuple):
     ef: jax.Array         # (n, d) error-feedback residuals (zeros when off)
     proj_rows: jax.Array  # () int32 rows the slot projections read so far
     recon_rows: jax.Array  # () int32 rows the server's reconstructions read
+    gram_rows: jax.Array  # () int32 rows read over d for R's Gram entries
 
 
 def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
@@ -109,7 +110,8 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
         # overridden by the plan.
         raw_msg = jnp.where(is_byz, plan.raw[i], g_i)
         view = refset.view(st.ref, i)
-        x_proj, proj, rows = refset.project(st.ref, view, raw_msg, cfg.ridge)
+        x_proj, proj, atg, rows = refset.project(st.ref, view, raw_msg,
+                                                 cfg.ridge)
         proj_rows = st.proj_rows + rows
         dec = echo_decision_from_projection(x_proj, proj, view, raw_msg,
                                             cfg.r)
@@ -204,12 +206,15 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     with jax.named_scope(SLOT_OVERHEAR):
         # --- Workers overhear raw broadcasts (lines 26-31) -------------------
         # Each view admits the raw if it is independent of that view; the
-        # reference set stores it once if any view does.
-        if st.ref.views.shape[0] == 1:
+        # reference set stores it once if any view does. With one view and
+        # a lossless codec the sender projected the very raw stored onto
+        # every stored row: its R g extends R's Gram with no pass over R.
+        one_view = st.ref.views.shape[0] == 1
+        if one_view:
             indep = independent_from_projection(proj, view, raw_msg,
                                                 cfg.indep_tol)[None]
         else:
-            _, projs, rows = jax.vmap(
+            _, projs, _, rows = jax.vmap(
                 lambda v: refset.project(st.ref, v, raw_msg, cfg.ridge))(
                     st.ref.views)
             indep = jax.vmap(independent_from_projection,
@@ -218,7 +223,9 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
             indep = indep & hear[:, i]      # hear[j, i]: j overhears i
             proj_rows = proj_rows + jnp.sum(rows)
         overheard = ~faded & ok
-        ref = refset.admit(st.ref, i, raw_wire, is_raw & indep & overheard)
+        ref, gram_rows = refset.admit(
+            st.ref, i, raw_wire, is_raw & indep & overheard,
+            cross=atg if one_view and codec.lossless else None)
 
     bits = st.bits.at[i].set(bits_i)
     echoed = st.echoed.at[i].set(is_echo)
@@ -229,7 +236,8 @@ def _slot(i: jax.Array, st: CommState, *, cfg: ProtocolConfig,
     ef = jnp.where(use_ef & is_raw, st.ef.at[i].set(ef_row), st.ef)
 
     return CommState(G, received, detected, ref, bits, echoed, faded_acc,
-                     chan, ef, proj_rows, recon_rows)
+                     chan, ef, proj_rows, recon_rows,
+                     st.gram_rows + gram_rows)
 
 
 def communication_phase(
@@ -260,6 +268,29 @@ def communication_phase(
     keeps one view of the reference set, which every worker shares;
     any other graph gives each worker its own.
     """
+    st = _slot_loop(cfg, grads, byz_mask, plan, comm, chan_key, ef, net)
+    server = ServerState(G=st.G, received=st.received, detected=st.detected)
+    stats = RoundStats(
+        bits_sent=st.bits,
+        echo_sent=st.echoed,
+        n_echo=jnp.sum(st.echoed.astype(jnp.int32)),
+        n_detected=jnp.sum(st.detected.astype(jnp.int32)),
+        rank_R=refset.rank(st.ref),
+        n_faded=jnp.sum(st.faded.astype(jnp.int32)),
+        proj_rows=st.proj_rows,
+        recon_rows=st.recon_rows,
+        gram_rows=st.gram_rows,
+    )
+    if ef is not None:
+        return server, stats, st.ef
+    return server, stats
+
+
+def _slot_loop(cfg: ProtocolConfig, grads: jax.Array, byz_mask: jax.Array,
+               plan: AttackPlan, comm: Optional[CommConfig],
+               chan_key: Optional[jax.Array], ef: Optional[jax.Array],
+               net) -> CommState:
+    """``communication_phase``'s n slots: the slot loop's final carry."""
     comm = comm if comm is not None else DEFAULT_COMM
     n, d = grads.shape
     if net is not None and net.n != n:
@@ -279,6 +310,7 @@ def communication_phase(
         ef=ef if ef is not None else jnp.zeros((n, d), grads.dtype),
         proj_rows=jnp.int32(0),
         recon_rows=jnp.int32(0),
+        gram_rows=jnp.int32(0),
     )
     slot = partial(_slot, cfg=cfg, grads=grads, byz_mask=byz_mask, plan=plan,
                    comm=comm, hear=hear, use_ef=ef is not None)
@@ -287,22 +319,7 @@ def communication_phase(
         with jax.named_scope(PROTOCOL_SLOT):
             return slot(i, st)
 
-    st = jax.lax.fori_loop(0, n, body, st)
-
-    server = ServerState(G=st.G, received=st.received, detected=st.detected)
-    stats = RoundStats(
-        bits_sent=st.bits,
-        echo_sent=st.echoed,
-        n_echo=jnp.sum(st.echoed.astype(jnp.int32)),
-        n_detected=jnp.sum(st.detected.astype(jnp.int32)),
-        rank_R=refset.rank(st.ref),
-        n_faded=jnp.sum(st.faded.astype(jnp.int32)),
-        proj_rows=st.proj_rows,
-        recon_rows=st.recon_rows,
-    )
-    if ef is not None:
-        return server, stats, st.ef
-    return server, stats
+    return jax.lax.fori_loop(0, n, body, st)
 
 
 def aggregate(server: ServerState, f: int, aggregator: str = "cgc"
@@ -412,8 +429,9 @@ def run_training(
 
     Returns a dict of per-round traces: dist2 (||w-w*||^2), value, bits,
     n_echo, n_detected, proj_rows (reference rows the slot projections
-    read) and recon_rows (table rows the server's echo reconstructions
-    read), each 0 on the point-to-point path. A
+    read), recon_rows (table rows the server's echo reconstructions
+    read) and gram_rows (reference rows read to extend R's Gram), each
+    0 on the point-to-point path. A
     :class:`~repro.comm.CommLedger` passed as ``ledger`` gets one record
     per simulated round (the simulation's reporting hook into the shared
     accounting surface).
@@ -532,6 +550,7 @@ def _trace_row(cost, w: jax.Array, bits: jax.Array,
         n_detected=zero if stats is None else stats.n_detected,
         proj_rows=zero if stats is None else stats.proj_rows,
         recon_rows=zero if stats is None else stats.recon_rows,
+        gram_rows=zero if stats is None else stats.gram_rows,
     )
 
 

@@ -13,21 +13,29 @@ partial hearing graph V = n and row j of ``views`` is worker j's.
 Only this module knows the storage order. A projection reads the smallest
 row bucket (``echo.row_buckets``) that holds the ``n_ref`` stored rows and
 hands its coefficients back in sender-ID order.
+
+The Gram matrix K = R R^T of the stored rows is carried beside R, in the
+same order, and extended by one row and column when a raw is stored: a
+projection masks it to its view and never multiplies the rows with each
+other. The new row is R raw, which the sender's own projection has
+computed when it projected that raw onto the one view; otherwise
+admission reads the bucket once for it.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.obs.scopes import ECHO_PROJECT
 
-from .echo import project_onto_span, rows_read, wire_norm_ratio
+from .echo import projection, rows_dot, rows_read, wire_norm_ratio
 
 
 class RefSet(NamedTuple):
     R: jax.Array        # (n, d) row k = the k-th raw stored
+    K: jax.Array        # (n, n) R R^T; zero from row and column n_ref on
     ref_id: jax.Array   # (n,) int32 sender ID of each row (n: empty row)
     n_ref: jax.Array    # () int32 rows stored so far
     views: jax.Array    # (V, n) bool in sender-ID order, V = 1 or n
@@ -35,6 +43,7 @@ class RefSet(NamedTuple):
 
 def empty(n: int, d: int, dtype, n_views: int) -> RefSet:
     return RefSet(R=jnp.zeros((n, d), dtype),
+                  K=jnp.zeros((n, n), dtype),
                   ref_id=jnp.full((n,), n, jnp.int32),
                   n_ref=jnp.int32(0),
                   views=jnp.zeros((n_views, n), bool))
@@ -51,18 +60,21 @@ def _row_mask(ref: RefSet, view: jax.Array) -> jax.Array:
 
 
 def project(ref: RefSet, view: jax.Array, g: jax.Array, ridge: float
-            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+            ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Least-squares projection of g onto the span of the rows in ``view``.
 
-    Returns (x, A x, rows read): x in sender-ID order, zero outside the
-    view; the rows read are those of the bucket holding ``n_ref``.
+    Returns (x, A x, R g, rows read): x in sender-ID order, zero outside
+    the view; R g over the rows in the view in storage order, which
+    :func:`admit` takes as ``cross`` when g is the raw it stores and
+    every stored row is in the view; the rows read are those of the
+    bucket holding ``n_ref``.
     """
     n = ref.R.shape[0]
-    x_rows, proj = project_onto_span(ref.R, _row_mask(ref, view), g, ridge,
-                                     n_live=ref.n_ref)
+    p = projection(ref.R, _row_mask(ref, view), g, ridge, n_live=ref.n_ref,
+                   gram=ref.K)
     with jax.named_scope(ECHO_PROJECT):
-        x = jnp.zeros_like(x_rows).at[ref.ref_id].set(x_rows, mode="drop")
-    return x, proj, rows_read(ref.n_ref, n)
+        x = jnp.zeros_like(p.x).at[ref.ref_id].set(p.x, mode="drop")
+    return x, p.echo, p.atg, rows_read(ref.n_ref, n)
 
 
 def norm_ratio(ref: RefSet, view: jax.Array, x: jax.Array, g: jax.Array
@@ -73,23 +85,36 @@ def norm_ratio(ref: RefSet, view: jax.Array, x: jax.Array, g: jax.Array
     return wire_norm_ratio(ref.R, _row_mask(ref, view), x_rows, g)
 
 
-def admit(ref: RefSet, i: jax.Array, raw: jax.Array, adds: jax.Array
-          ) -> RefSet:
+def admit(ref: RefSet, i: jax.Array, raw: jax.Array, adds: jax.Array,
+          cross: Optional[jax.Array] = None) -> Tuple[RefSet, jax.Array]:
     """Slot i's raw into the views ``adds`` (V,) marks.
 
     The raw is stored once, at row ``n_ref`` (< n: a slot stores at most
     one raw), when any view admits it: a plain dynamic update, with no
     bounds check that would read the old row. Rows from ``n_ref`` on are
     empty, so a slot that stores nothing rewrites an empty row.
+
+    K gains row and column ``n_ref``: R raw, from ``cross`` (the raw's
+    projection's R g, :func:`project`) or else read from the bucket
+    holding the stored rows, in a slot that stores only, and ||raw||^2.
+    Returns the new set and the rows read for K.
     """
     n = ref.R.shape[0]
     k = ref.n_ref
     stored = jnp.any(adds)
     R = jax.lax.dynamic_update_index_in_dim(
         ref.R, jnp.where(stored, raw, jnp.zeros_like(raw)), k, 0)
+    if cross is None:
+        cross, gram_rows = rows_dot(ref.R, raw, k, stored)
+    else:
+        gram_rows = jnp.int32(0)
+    row = jnp.where(stored, cross.at[k].set(jnp.vdot(raw, raw)), 0.0)
+    K = jax.lax.dynamic_update_index_in_dim(ref.K, row, k, 0)
+    K = jax.lax.dynamic_update_index_in_dim(K, row, k, 1)
     ref_id = ref.ref_id.at[k].set(jnp.where(stored, i, n))
     views = ref.views.at[:, i].set(adds | ref.views[:, i])
-    return RefSet(R, ref_id, k + stored.astype(jnp.int32), views)
+    return (RefSet(R, K, ref_id, k + stored.astype(jnp.int32), views),
+            gram_rows)
 
 
 def rank(ref: RefSet) -> jax.Array:

@@ -54,6 +54,8 @@ class RoundStats(NamedTuple):
                                  # projections read, summed over slots
     recon_rows: Any = None       # () int32, table rows the server's echo
                                  # reconstructions read, summed over slots
+    gram_rows: Any = None        # () int32, reference rows read over d to
+                                 # extend R's Gram, summed over slots
 
 
 class ProtocolConfig(NamedTuple):

@@ -11,7 +11,8 @@ tests and trace readers that look for them.
 """
 from __future__ import annotations
 
-# core/echo.py: project_onto_span (masked Gram, A^T g, solve, echo)
+# core/echo.py: projection (A^T g, the masked Gram, given or built, the
+# solve, echo)
 ECHO_PROJECT = "echo.project"
 # dist/echo_dp.py: the (K, K) Gram of the echo-DP reference basis
 ECHO_BASIS_GRAM = "echo.basis_gram"

@@ -6,7 +6,8 @@ import pytest
 
 from repro.core.echo import (echo_decision, echo_decision_from_projection,
                              is_linearly_independent, project_onto_span,
-                             reconstruct_echo, recon_rows_read, rows_read)
+                             projection, reconstruct_echo, recon_rows_read,
+                             rows_dot, rows_read)
 
 
 def _setup(n=6, d=40, k=3, seed=0):
@@ -127,6 +128,43 @@ def test_bucketed_projection_matches_full_table(k, bucket):
     np.testing.assert_allclose(float(got.residual), float(ref.residual),
                                rtol=1e-4, atol=1e-5)
     assert bool(got.send_echo) == bool(ref.send_echo) == (k > 0)
+
+
+@pytest.mark.parametrize("k,bucket", [(0, 8), (1, 8), (8, 8), (9, 16),
+                                      (17, 32), (40, 40)])
+def test_projection_on_carried_gram_matches_rows(k, bucket):
+    """Given R R^T, the bucketed projection masks it to the view and
+    ridges it as ``masked_gram`` does from the rows: x, A x and A^T g
+    equal the projection that builds the Gram from the bucket, for a
+    view that holds every other live row and junk rows past them.
+    ``rows_dot`` gives the same R g over the bucket, and no row and
+    zeros when unused."""
+    n, d = 40, 256
+    key = jax.random.PRNGKey(100 + k)
+    k_tab, k_junk, k_g = jax.random.split(key, 3)
+    live = jnp.arange(n) < k
+    R = jnp.where(live[:, None], jax.random.normal(k_tab, (n, d)),
+                  jax.random.normal(k_junk, (n, d)))
+    view = live & (jnp.arange(n) % 2 == 0)
+    g = jax.random.normal(k_g, (d,))
+
+    run = jax.jit(lambda R, m, g, nl, K: projection(R, m, g, n_live=nl,
+                                                    gram=K))
+    want = run(R, view, g, jnp.int32(k), None)
+    got = run(R, view, g, jnp.int32(k),
+              jnp.where(live[:, None] & live[None, :], R @ R.T, 0.0))
+    for name in ("x", "echo", "atg"):
+        np.testing.assert_allclose(np.asarray(getattr(got, name)),
+                                   np.asarray(getattr(want, name)),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    assert not np.asarray(got.atg)[np.asarray(~view)].any()
+
+    dot, rows = rows_dot(R, g, jnp.int32(k), jnp.bool_(True))
+    assert int(rows) == bucket
+    np.testing.assert_allclose(np.asarray(dot[:k]), np.asarray(R[:k] @ g),
+                               rtol=1e-5, atol=1e-4)
+    dot, rows = rows_dot(R, g, jnp.int32(k), jnp.bool_(False))
+    assert int(rows) == 0 and not np.asarray(dot).any()
 
 
 @pytest.mark.parametrize("m,top,bucket", [

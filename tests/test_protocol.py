@@ -12,8 +12,8 @@ from repro.comm import CommConfig
 from repro.comm.channel import LossyBroadcast
 from repro.comm.policy import AdaptiveEchoPolicy
 from repro.comm.wire import FP32, Int8Codec
-from repro.core import byzantine, costfns, theory
-from repro.core.echo import row_buckets
+from repro.core import byzantine, costfns, refset, theory
+from repro.core.echo import row_buckets, rows_read
 from repro.core.protocol import (communication_phase, echo_cgc_round,
                                  pointwise_round, run_training)
 import repro.core.protocol as protocol
@@ -516,3 +516,119 @@ def test_recon_rows_counts_bucket_rows():
         trace = run_training(*args, rounds=3, **kw)
         np.testing.assert_array_equal(np.asarray(trace["recon_rows"]),
                                       [want] * 3, err_msg=str(kw))
+
+
+def _rebuilt_gram(monkeypatch):
+    """Make the slot loop's projections build the bucket's Gram from its
+    rows in every slot, as before R's Gram was carried (``refset``
+    looks the projection up when it is traced)."""
+    carried = refset.projection
+    monkeypatch.setattr(
+        refset, "projection",
+        lambda R, mask, g, ridge, n_live, gram: carried(R, mask, g, ridge,
+                                                        n_live))
+
+
+@pytest.mark.parametrize("codec", [FP32, Int8Codec()], ids=["fp32", "int8"])
+@pytest.mark.parametrize("graph", ["shared", "per_worker"])
+def test_carried_gram_matches_rebuilt_gram(graph, codec, monkeypatch):
+    """After the rising-rank round K is R R^T of the 34 stored rows and
+    zero from row and column n_ref on, whether its rows came from the
+    sender's A^T g (one view, fp32) or from the bucket read at admission
+    (n views, or int8). The round's decisions, counts and bits are those
+    of the round whose projections rebuild the Gram from the rows in
+    every slot, and its server table is within 1e-5 of it."""
+    cfg, grads, byz, plan = _rising_rank_round()
+    n = cfg.n
+    comm = CommConfig(codec=codec)
+    net = (None if graph == "shared" else
+           HearingGraph(adj=complete_graph(n).adj, strict=True))
+    st = protocol._slot_loop(cfg, grads, byz, plan, comm, None, None, net)
+    k = int(st.ref.n_ref)
+    assert k == 34
+    R = np.asarray(st.ref.R, np.float64)
+    K = np.asarray(st.ref.K)
+    want = R[:k] @ R[:k].T
+    np.testing.assert_allclose(K[:k, :k], want, rtol=1e-5,
+                               atol=1e-6 * np.abs(np.diag(want)).max())
+    assert not K[k:].any() and not K[:, k:].any()
+
+    srv, stats = communication_phase(cfg, grads, byz, plan, comm=comm,
+                                     net=net)
+    _rebuilt_gram(monkeypatch)
+    srv_r, stats_r = communication_phase(cfg, grads, byz, plan, comm=comm,
+                                         net=net)
+    for name in ("echo_sent", "bits_sent", "n_echo", "n_detected", "rank_R",
+                 "proj_rows", "recon_rows"):
+        np.testing.assert_array_equal(np.asarray(getattr(stats, name)),
+                                      np.asarray(getattr(stats_r, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(srv.received),
+                                  np.asarray(srv_r.received))
+    np.testing.assert_array_equal(np.asarray(srv.detected),
+                                  np.asarray(srv_r.detected))
+    np.testing.assert_allclose(np.asarray(srv.G), np.asarray(srv_r.G),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("graph", ["shared", "per_worker"])
+def test_slot_projection_builds_no_gram(graph, monkeypatch):
+    """Structural guard: with one view and with n, every dot_general
+    under the echo.project scope multiplies the bucket's rows with a
+    vector (A^T g, A x, the solve), never with the rows themselves:
+    the Gram is carried. The round that rebuilds the Gram in every slot
+    shows the guard would see it."""
+    cfg, grads, byz, plan = _rising_rank_round(n=20, d=64, n_byz=2)
+    n = cfg.n
+    net = (None if graph == "shared" else
+           HearingGraph(adj=complete_graph(n).adj, strict=True))
+
+    def products():
+        jaxpr = jax.make_jaxpr(lambda g: communication_phase(
+            cfg, g, byz, plan, net=net))(grads).jaxpr
+        reads = []
+        _dot_reads(jaxpr, "", False, {len(row_buckets(n))}, reads)
+        proj = [rows for stack, rows, _ in reads
+                if ECHO_PROJECT in _scopes(stack)]
+        assert any(8 in rows for rows in proj)
+        return [rows for rows in proj if len(rows) > 1]
+
+    assert products() == []
+    _rebuilt_gram(monkeypatch)
+    assert products()
+
+
+def test_gram_rows_counts_storing_buckets():
+    """Rows read to extend R's Gram: none with one view and fp32, where
+    the sender's A^T g is the new row; with int8 or n views, the bucket
+    of each slot that stores, the k-th stored raw's the bucket holding
+    k rows. In run_training's per-round trace, scanned and under a
+    dynamic policy (0 point to point): n=20 identical gradients store
+    slot 0's raw alone, 0 rows a round with one view, 8 with n."""
+    cfg, grads, byz, plan = _rising_rank_round()
+    n = cfg.n
+    strict = HearingGraph(adj=complete_graph(n).adj, strict=True)
+    for codec, net in ((FP32, None), (Int8Codec(), None), (FP32, strict),
+                       (Int8Codec(), strict)):
+        _, stats = communication_phase(cfg, grads, byz, plan,
+                                       comm=CommConfig(codec=codec), net=net)
+        rank = int(stats.rank_R)
+        want = (0 if codec.lossless and net is None else
+                sum(int(rows_read(jnp.int32(k), n)) for k in range(rank)))
+        assert rank == 34
+        assert int(stats.gram_rows) == want, (codec.name, net)
+
+    n, d = 20, 24
+    key = jax.random.PRNGKey(0)
+    cost = costfns.quadratic(key, d=d, sigma=0.0)     # every worker equal
+    cfg = ProtocolConfig(n=n, f=0, r=0.3, eta=0.001)
+    args = (cfg, cost, byzantine.no_attack, jnp.zeros(n, bool), key,
+            jnp.ones(d))
+    strict = HearingGraph(adj=complete_graph(n).adj, strict=True)
+    for net, per_round in ((None, 0), (strict, 8)):
+        for kw, want in (({}, per_round),
+                         ({"policy": AdaptiveEchoPolicy()}, per_round),
+                         ({"use_radio": False}, 0)):
+            trace = run_training(*args, rounds=3, net=net, **kw)
+            np.testing.assert_array_equal(np.asarray(trace["gram_rows"]),
+                                          [want] * 3, err_msg=str(kw))
